@@ -8,36 +8,6 @@ but the engine underneath is jax/XLA/PJRT, designed TPU-first (SURVEY.md §7).
 
 __version__ = "0.1.0"
 
-# jax compat: this codebase targets the top-level `jax.shard_map` (with the
-# `check_vma=` kwarg); on older jax (< 0.6, e.g. the baked-in 0.4.x
-# toolchain) that lives at jax.experimental.shard_map.shard_map with the
-# kwarg named `check_rep`. Install a translating alias BEFORE any submodule
-# (or test) touches jax.shard_map.
-import jax as _jax  # noqa: E402
-
-# True when running on the legacy (< 0.6) jax the compat aliases below
-# bridge; a few tests skip paths that hard-crash its jaxlib
-jax_compat_legacy = not hasattr(_jax, "shard_map")
-
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def _shard_map_compat(f, mesh=None, in_specs=None, out_specs=None,
-                          check_vma=None, check_rep=None, axis_names=None,
-                          **kw):
-        if check_rep is None and check_vma is not None:
-            check_rep = check_vma
-        if check_rep is not None:
-            kw["check_rep"] = check_rep
-        if axis_names is not None:
-            # new API: manual ONLY over axis_names; old API spells that
-            # as auto = the complement
-            kw["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-        return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, **kw)
-
-    _jax.shard_map = _shard_map_compat
-
 from .framework import (  # noqa: F401
     # dtypes
     DType,

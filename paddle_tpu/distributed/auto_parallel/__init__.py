@@ -117,11 +117,6 @@ class ProcessMesh:
     def jax_mesh(self) -> Mesh:
         if self._jax_mesh is None:
             devs = jax.devices()
-            total = int(np.prod(self._shape))
-            if len(devs) < total:
-                cpus = jax.devices("cpu")
-                if len(cpus) >= total:
-                    devs = cpus
             chosen = np.asarray([devs[pid % len(devs)]
                                  for pid in self._process_ids])
             self._jax_mesh = Mesh(chosen.reshape(self._shape),

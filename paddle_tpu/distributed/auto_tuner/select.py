@@ -7,9 +7,8 @@ device count, prunes infeasible ones with the reference pruning rules
 (`prune.prune_candidates` — divisibility + HBM-fit), ranks the
 survivors with `tuner.estimate_step_ms` under BACKEND-CALIBRATED
 collective constants, and returns the winner plus the scan-granularity
-knobs (`scan_unroll` / `layer_chunk` from the measured `bench.py
---sweep` grid when a code-current record exists, defaults otherwise)
-and the comm bucket size. `jit.select_train_step(auto=True)` consumes
+knobs (`scan_unroll` / `layer_chunk`, fixed defaults) and the comm
+bucket size. `jit.select_train_step(auto=True)` consumes
 this to build the mesh + hybrid step end-to-end.
 
 Env override (preserved per ISSUE 8): ``PADDLE_HYBRID_LAYOUT=
@@ -68,9 +67,9 @@ def calibrate_backend_cached(devices=None, cache_dir=None, refresh=False):
     """`tuner.calibrate_backend` behind a keyed on-disk cache.
 
     Key: (backend platform, device count); file:
-    ``.bench_live/backend_calib_<platform>_<n>.json``; entries carry the
-    invalidation hash from `_calib_hash` and are re-measured when it
-    mismatches (stale toolchain/code) or the file is unreadable.
+    ``backend_calib_<platform>_<n>.json`` under ``.bench_live/``; entries
+    carry the invalidation hash from `_calib_hash` and are re-measured
+    when it mismatches (stale toolchain/code) or the file is unreadable.
     """
     import jax
 
@@ -225,33 +224,11 @@ def _parse_env_layout(text):
     return out
 
 
-def _sweep_knobs(spec):
-    """scan_unroll / layer_chunk from the newest code-matching measured
-    sweep record (`bench.py --sweep` writes
-    .bench_live/scan_sweep_*.json); defaults otherwise. The sweep is the
-    planner's measured calibration grid for the in-scan knobs the cost
-    model does not capture."""
-    import glob
-
-    best = {"scan_unroll": 2, "layer_chunk": 1, "source": "default"}
-    pat = os.path.join(_repo_root(), ".bench_live", "scan_sweep_*.json")
-    recs = []
-    for p in glob.glob(pat):
-        try:
-            with open(p) as f:
-                recs.append((os.path.getmtime(p), json.load(f)))
-        except (OSError, ValueError):
-            continue
-    for _, rec in sorted(recs, reverse=True):
-        b = rec.get("best") or {}
-        if "scan_unroll" in b:
-            best.update({"scan_unroll": int(b["scan_unroll"]),
-                         "layer_chunk": int(b.get("layer_chunk", 1)),
-                         "source": "measured-sweep"})
-            break
-    if spec.num_layers % best["layer_chunk"]:
-        best["layer_chunk"] = 1
-    return best
+# scan_unroll / layer_chunk for the picked layout. `bench.py --sweep`
+# measures the grid on the chip, but nothing reads a record of it back:
+# a number taken on another machine or an older program never steers the
+# step's shape.
+_SCAN_KNOBS = {"scan_unroll": 2, "layer_chunk": 1, "source": "default"}
 
 
 def _rank_corr(xs, ys):
@@ -296,7 +273,7 @@ def pick_layout(spec, n_devices, hbm_gb=16.0, backend=None,
     from ...utils import flags as _flags
 
     bucket_mb = int(_flags.get_flag("FLAGS_comm_bucket_mb") or 25)
-    knobs = _sweep_knobs(spec)
+    knobs = _SCAN_KNOBS
 
     def finish(cand, source, ranking):
         return {

@@ -36,14 +36,6 @@ _state = {
 AXIS_ORDER = ("pp", "dp", "sharding", "sep", "mp")
 
 
-def _detect_devices():
-    devs = jax.devices()
-    if len(devs) == 1 and jax.default_backend() != "cpu":
-        # single accelerator; allow virtual CPU expansion for tests
-        return devs
-    return devs
-
-
 def init_parallel_env():
     """paddle.distributed.init_parallel_env parity (parallel.py:978).
 
@@ -55,17 +47,7 @@ def init_parallel_env():
         if _state["initialized"]:
             return
         n_hosts = int(os.environ.get("PADDLE_TRAINERS_NUM", "1"))
-        # jax < 0.6 has no jax.distributed.is_initialized — probe the
-        # coordination-service client directly there
-        def _dist_up():
-            probe = getattr(jax.distributed, "is_initialized", None)
-            if probe is not None:
-                return probe()
-            from jax._src import distributed as _dist
-
-            return _dist.global_state.client is not None
-
-        if n_hosts > 1 and not _dist_up():
+        if n_hosts > 1 and not jax.distributed.is_initialized():
             addr = os.environ.get("MASTER_ADDR")
             port = os.environ.get("MASTER_PORT")
             coord = (
@@ -77,7 +59,7 @@ def init_parallel_env():
                 num_processes=n_hosts,
                 process_id=int(os.environ.get("PADDLE_TRAINER_ID", "0")),
             )
-        devs = _detect_devices()
+        devs = jax.devices()
         _state["mesh"] = Mesh(np.asarray(devs), ("dp",))
         _state["axis_degrees"] = {"dp": len(devs)}
         _state["initialized"] = True
@@ -154,10 +136,6 @@ def build_mesh(degrees: dict, devices=None) -> Mesh:
     total = int(np.prod(sizes)) if sizes else 1
     if devices is None:
         devices = jax.devices()
-        if len(devices) < total:
-            cpus = jax.devices("cpu")
-            if len(cpus) >= total:
-                devices = cpus
     if len(devices) < total:
         raise ValueError(
             f"mesh {dict(zip(names, sizes))} needs {total} devices, "

@@ -74,10 +74,6 @@ class Fleet:
         import jax
 
         avail = len(jax.devices())
-        if avail == 1:
-            cpus = jax.devices("cpu")
-            if len(cpus) > 1:
-                avail = len(cpus)
         known = int(np.prod([d for d in dims if d > 0]))
         dims = [avail // known if d == -1 else d for d in dims]
         topology = CommunicateTopology(dims=dims)

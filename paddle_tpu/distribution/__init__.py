@@ -765,18 +765,8 @@ class Multinomial(Distribution):
 
         def f(p):
             out_shape = ext + p.shape[-1:] if ext else None
-            if hasattr(jax.random, "multinomial"):
-                return jax.random.multinomial(
-                    key, n, p, shape=out_shape).astype(jnp.float32)
-            # jax < 0.4.3x: no multinomial — n categorical draws,
-            # histogrammed over the category dim (same distribution)
-            base = jnp.broadcast_to(
-                p, out_shape if out_shape is not None else p.shape)
-            draws = jax.random.categorical(
-                key, jnp.log(base), axis=-1,
-                shape=(int(n),) + base.shape[:-1])
-            return jax.nn.one_hot(
-                draws, base.shape[-1]).sum(0).astype(jnp.float32)
+            return jax.random.multinomial(
+                key, n, p, shape=out_shape).astype(jnp.float32)
 
         out = _op(f, self.probs)
         out.stop_gradient = True

@@ -30,7 +30,6 @@ free continuous-batching bookkeeping on the host side).
 """
 from __future__ import annotations
 
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -99,11 +98,6 @@ class SelfDraftProposer:
         return []
 
 
-def _legacy_jax():
-    return getattr(sys.modules.get("paddle_tpu"), "jax_compat_legacy",
-                   False)
-
-
 def _split_state(kind, state):
     buf_keys = [k for k in _BUFFER_KEYS[kind] if k in state]
     return ({k: state[k] for k in buf_keys},
@@ -154,11 +148,7 @@ class _Step:
 
     def __init__(self, engine, donate_cache):
         self.engine = engine
-        # donation is a pure perf lever; the legacy jaxlib (0.4.x CPU)
-        # corrupts donated buffers under real program sizes (see
-        # TrainStep), so it is forced off there
-        self._donate = (donate_cache and engine.compiled
-                        and not _legacy_jax())
+        self._donate = donate_cache and engine.compiled
         self._jitted = None
         self.trace_count = 0   # traces when compiled, calls when eager
         self._sentinel = RetraceSentinel(type(self).__name__,
@@ -200,6 +190,18 @@ class _Step:
         saved = self.trace_count
         try:
             return jax.jit(self._fn).lower(*args).as_text()
+        finally:
+            self.trace_count = saved
+
+    def compiled_text(self, *args):
+        """Optimized HLO text of the step compiled for the given example
+        args with the real donation config (what `chip_smoke.py` greps
+        for the Mosaic calls). A fresh jit copy, like `lowered_text`."""
+        saved = self.trace_count
+        try:
+            return jax.jit(
+                self._fn, donate_argnums=(1,) if self._donate else ()
+            ).lower(*args).compile().as_text()
         finally:
             self.trace_count = saved
 

@@ -135,16 +135,7 @@ class TrainStep:
         # abstract signature; an unexpected executable-cache miss is
         # attributed to the argument leaf that changed
         self._sentinel = RetraceSentinel(type(self).__name__)
-        # donation is a pure perf lever (aliased state buffers) — on the
-        # legacy jaxlib (0.4.x CPU) it CORRUPTS memory under conv-sized
-        # programs on a host mesh (NaN losses, then hard aborts in later
-        # jits — measured via tests/test_vision.py), so it is forced off
-        # there
-        import sys as _sys
-
-        _legacy = getattr(_sys.modules.get("paddle_tpu"),
-                          "jax_compat_legacy", False)
-        self._donate = donate and not _legacy
+        self._donate = donate
         # gradient accumulation INSIDE the fused program (the reference's
         # no_sync/gradient-merge loop, compiled): the batch's dim 0 splits
         # into `accumulate_steps` micro-batches; micro backwards accumulate

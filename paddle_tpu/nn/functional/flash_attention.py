@@ -102,6 +102,7 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
     rng = next_key() if drop > 0.0 else None
 
     from ...ops.pallas import flash_attention as pallas_flash
+    from ...ops.pallas import routing
     from ...ops.pallas import splash_attention as pallas_splash
     from ...utils import flags as _flags
 
@@ -112,7 +113,6 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
     if segment_ids is None:
         segment_ids = current_segment_ids()
     splash_on = bool(_flags.get_flag("FLAGS_splash_attn"))
-    force_interp = bool(_flags.get_flag("FLAGS_pallas_force_interpret"))
     kvh = key_t.shape[2]
 
     if segment_ids is not None and attn_mask is not None:
@@ -129,14 +129,12 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
         seg = ensure_tensor(segment_ids)
         if splash_on and drop == 0.0:
             # splash owns the segment mask: fused into the score tiles
-            # on TPU (or interpret mode), dense-equivalent XLA fallback
+            # on TPU (or interpret mode), dense-equivalent XLA path
             # elsewhere — no [s, s] mask tensor either way
-            interp = True if force_interp else None
-
             def f_seg(q, k, v, s):
                 return pallas_splash.splash_attention(
                     q, k, v, causal=is_causal, segment_ids=s,
-                    scale=scale, interpret=interp)
+                    scale=scale)
 
             return nary(f_seg, [query, key_t, value, seg],
                         "splash_attention_segments")
@@ -150,37 +148,28 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
         return nary(f_mask, [query, key_t, value, segd],
                     "sdpa_segment_mask")
 
-    # splash takes the long-seq training slot ahead of flash: same
-    # routing conditions, tiled fwd + stats-recompute bwd, GQA-capable
-    use_splash = (
-        splash_on and seqlen >= min_seq and attn_mask is None
-        and drop == 0.0
-        and pallas_splash.supports(tuple(query.shape), kvh,
-                                   query._data.dtype)
-        and (force_interp or pallas_splash._on_tpu())
-    )
-    if use_splash:
-        interp = True if force_interp else None
-        return nary(
-            lambda q, k, v: pallas_splash.splash_attention(
-                q, k, v, causal=is_causal, scale=scale,
-                interpret=interp),
-            [query, key_t, value], "splash_attention")
-
-    use_pallas = (
-        seqlen >= min_seq and attn_mask is None and drop == 0.0
-        and query.shape == key_t.shape == value.shape
-        and pallas_flash.supports(tuple(query.shape), query._data.dtype,
-                                  is_causal)
-    )
-
-    if use_pallas:
-        inputs = [query, key_t, value]
-        return nary(
-            lambda q, k, v: pallas_flash.flash_attention(
-                q, k, v, causal=is_causal, scale=scale),
-            inputs, "flash_attention_pallas",
-        )
+    # long-sequence training slot: a Pallas kernel on TPU (splash first,
+    # flash for what it cannot take), XLA everywhere else. A TPU call in
+    # this slot that neither kernel supports is recorded, not silent.
+    if (seqlen >= min_seq and attn_mask is None and drop == 0.0
+            and routing.kernels_wanted()):
+        if splash_on and pallas_splash.supports(
+                tuple(query.shape), kvh, query._data.dtype):
+            return nary(
+                lambda q, k, v: pallas_splash.splash_attention(
+                    q, k, v, causal=is_causal, scale=scale),
+                [query, key_t, value], "splash_attention")
+        if (query.shape == key_t.shape == value.shape
+                and pallas_flash.supports(tuple(query.shape),
+                                          query._data.dtype, is_causal)):
+            return nary(
+                lambda q, k, v: pallas_flash.flash_attention(
+                    q, k, v, causal=is_causal, scale=scale),
+                [query, key_t, value], "flash_attention_pallas")
+        routing.note_fallback(
+            "training_attention",
+            (f"q{tuple(query.shape)}", f"kv_heads={kvh}",
+             str(query._data.dtype)))
 
     inputs = [query, key_t, value]
     if attn_mask is not None:

@@ -514,7 +514,6 @@ def fused_linear_cross_entropy(hidden, weight, labels, transpose_y=True,
     n_chunks = max(1, int(n_chunks))
     if vocab_tiled is None:
         vocab_tiled = bool(_flags.get_flag("FLAGS_fused_ce"))
-    force_interp = bool(_flags.get_flag("FLAGS_pallas_force_interpret"))
 
     def f(h, w, lbl):
         hsz = h.shape[-1]
@@ -527,8 +526,7 @@ def fused_linear_cross_entropy(hidden, weight, labels, transpose_y=True,
             # outside (AD routes dweight back through the transpose)
             w_vh = w if transpose_y else w.T
             losses = _fce.fused_cross_entropy(
-                flat_h, w_vh, flat_l, ignore_index=ignore_index,
-                interpret=True if force_interp else None)
+                flat_h, w_vh, flat_l, ignore_index=ignore_index)
         else:
             losses = _fused_linear_ce(flat_h, w, flat_l, transpose_y,
                                       ignore_index, n_chunks)

@@ -178,7 +178,7 @@ class StepTimeline:
 
     Usage::
 
-        tl = StepTimeline(sinks=[JsonlSink(".bench_live/tl.jsonl")])
+        tl = StepTimeline(sinks=[JsonlSink("steps.jsonl")])
         for i, batch in enumerate(loader):
             t0 = time.perf_counter()
             loss = step(*batch)

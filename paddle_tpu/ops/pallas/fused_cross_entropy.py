@@ -26,7 +26,7 @@ Two paths, one contract (the `paged_attention.py` routing pattern):
 
 * **Pallas kernel** — TPU (or `interpret=True` for hermetic CPU parity).
   Requires vocab % 128 == 0 (the bench vocab 50304 = 393 * 128).
-* **XLA fallback** (`impl="xla"`) — CPU / legacy jax / odd vocabs: a
+* **XLA path** (`impl="xla"`) — CPU / odd vocabs: a
   `lax.scan` over the same vocab tiles in the same order with the same
   fp32 accumulation, so kernel-vs-fallback parity is tight; handles
   arbitrary vocab sizes by padding the last tile (padded columns are
@@ -44,31 +44,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .flash_attention import (  # noqa: F401  (shared probes + helpers)
-    _HAS_PALLAS, _LANES, _REVISIT_MIN, _Z, _dot, _on_tpu, pl, pltpu,
+from . import routing
+from .flash_attention import (  # noqa: F401  (shared kernel helpers)
+    _LANES, _REVISIT_MIN, _Z, _dot, pl, pltpu,
 )
 
 __all__ = ["fused_cross_entropy", "sharded_fused_cross_entropy",
-           "supports", "kernel_active"]
+           "supports"]
 
 
 def supports(vocab, hidden, dtype) -> bool:
     """Whether the Pallas kernel can take this head (else XLA tiles)."""
-    if not _HAS_PALLAS:
-        return False
     if dtype not in (jnp.float32, jnp.bfloat16, jnp.float16):
         return False
     return vocab % _LANES == 0
-
-
-def kernel_active(vocab, hidden, dtype) -> bool:
-    """Would `fused_cross_entropy` run the compiled kernel here and now?
-    (Flag + geometry + on-TPU; the bench records this per config.)"""
-    from ...utils import flags as _flags
-
-    if not _flags.get_flag("FLAGS_fused_ce"):
-        return False
-    return supports(vocab, hidden, dtype) and _on_tpu()
 
 
 def _pick_block_v(vocab):
@@ -133,9 +122,10 @@ def _fwd_pallas(h, w, lbl_b, bn, bv, ignore_index, interpret):
     spec_h = pl.BlockSpec((1, bn, hidden), lambda i, j: (_Z, i, _Z))
     spec_w = pl.BlockSpec((1, bv, hidden), lambda i, j: (_Z, j, _Z))
     spec_r = pl.BlockSpec((1, bn, _LANES), lambda i, j: (_Z, i, _Z))
-    loss, lse = pl.pallas_call(
+    loss, lse = routing.pallas_call(
         functools.partial(_fwd_kernel, block_v=bv,
                           ignore_index=ignore_index),
+        name="fused_ce_fwd",
         grid=(n // bn, vocab // bv),
         in_specs=[spec_h, spec_w, spec_r],
         out_specs=[spec_r, spec_r],
@@ -195,8 +185,9 @@ def _bwd_call(h, w, lbl_b, lse_b, g_b, dw_acc, bn, bv, interpret):
     spec_h = pl.BlockSpec((1, bn, hidden), lambda i, j: (_Z, i, _Z))
     spec_w = pl.BlockSpec((1, bv, hidden), lambda i, j: (_Z, j, _Z))
     spec_r = pl.BlockSpec((1, bn, _LANES), lambda i, j: (_Z, i, _Z))
-    dh, dw = pl.pallas_call(
+    dh, dw = routing.pallas_call(
         functools.partial(_bwd_kernel, block_v=bv),
+        name="fused_ce_bwd",
         grid=(n // bn, vocab // bv),
         in_specs=[spec_h, spec_w, spec_r, spec_r, spec_r, spec_w],
         out_specs=[spec_h, spec_w],
@@ -430,22 +421,19 @@ def fused_cross_entropy(hidden, weight, labels, ignore_index=-100,
     interpret mode (hermetic CPU parity testing)."""
     n, h = hidden.shape
     vocab = weight.shape[0]
-    ok = supports(vocab, h, hidden.dtype)
-    if use_kernel is None:
-        use_kernel = ok and (interpret is True or _on_tpu())
-    if use_kernel and not ok:
-        raise ValueError(
-            f"fused CE kernel does not support vocab={vocab} "
-            f"dtype={hidden.dtype} (vocab must be a multiple of {_LANES})")
-    if block_v is None:
-        block_v = _pick_block_v(vocab) if use_kernel else _LANES
+    use_kernel, interpret = routing.route(
+        "fused_cross_entropy", supports(vocab, h, hidden.dtype),
+        (f"vocab={vocab}", f"hidden={h}", str(hidden.dtype)),
+        interpret, use_kernel)
     if use_kernel:
-        impl = ("interpret"
-                if (interpret if interpret is not None else not _on_tpu())
-                else "pallas")
+        impl = "interpret" if interpret else "pallas"
         bn = block_n if block_n is not None else _pick_block_n(n)
+        if block_v is None:
+            block_v = _pick_block_v(vocab)
     else:
         impl, bn = "xla", 1
+        if block_v is None:
+            block_v = _LANES
     return _fused_ce(hidden, weight, labels.astype(jnp.int32),
                      int(ignore_index), int(bn), int(block_v), impl)
 

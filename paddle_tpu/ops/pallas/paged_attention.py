@@ -29,7 +29,7 @@ Two paths, one contract:
   densely. Pages past a slot's length are skipped (``pl.when``), which
   is where the ragged win comes from: compute per slot is proportional
   to its own context length, not the batch max.
-* **XLA fallback** (CPU / legacy jax): one gather densifies each slot's
+* **XLA path** (CPU, unsupported geometry): one gather densifies each slot's
   pages to ``[batch, pages_per_seq * page_size, ...]`` followed by a
   masked attention. Same numerics, used for parity tests and
   non-TPU runs.
@@ -69,8 +69,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .flash_attention import (  # noqa: F401  (shared platform probes)
-    _HAS_PALLAS, _LANES, _on_tpu, pl, pltpu,
+from . import routing
+from .flash_attention import (  # noqa: F401  (shared kernel helpers)
+    _LANES, _dot, pl, pltpu,
 )
 
 __all__ = ["paged_attention", "paged_attention_xla",
@@ -80,8 +81,6 @@ __all__ = ["paged_attention", "paged_attention_xla",
 
 def supports(num_heads, num_kv_heads, head_dim, page_size) -> bool:
     """Whether the Pallas kernel can take this cache geometry."""
-    if not _HAS_PALLAS:
-        return False
     if num_heads % num_kv_heads:
         return False
     if head_dim > 256:
@@ -178,9 +177,7 @@ def _decode_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
         q = q_ref[0, 0]                                  # [grp, d]
         k = k_ref[0, 0]                                  # [ps, d]
         v = v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [grp, ps]
+        s = _dot(q, k, ((1,), (1,))) * scale             # [grp, ps]
         pos = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         s = jnp.where(pos < sl, s, -jnp.inf)
@@ -193,9 +190,7 @@ def _decode_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = corr * l_prev + jnp.broadcast_to(
             jnp.sum(e, axis=1, keepdims=True), l_prev.shape)
         m_ref[...] = m_new
-        pv = jax.lax.dot_general(
-            e.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [grp, d]
+        pv = _dot(e.astype(v.dtype), v, ((1,), (0,)))    # [grp, d]
         acc_ref[...] = acc_ref[...] * corr[:, :1] + pv
 
     @pl.when(p == num_p - 1)
@@ -233,9 +228,8 @@ def _decode_kernel_q(pt_ref, sl_ref, q_ref, k_ref, v_ref, ks_ref,
             kq, vq = _unpack_nib(kq), _unpack_nib(vq)    # [ps, d]
         k = kq.astype(jnp.float32) * ks_ref[0, 0]        # [ps, d]
         v = vq.astype(jnp.float32) * vs_ref[0, 0]
-        s = jax.lax.dot_general(
-            q.astype(jnp.float32), k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [grp, ps]
+        s = _dot(q.astype(jnp.float32), k,
+                 ((1,), (1,))) * scale                   # [grp, ps]
         pos = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         s = jnp.where(pos < sl, s, -jnp.inf)
@@ -248,9 +242,7 @@ def _decode_kernel_q(pt_ref, sl_ref, q_ref, k_ref, v_ref, ks_ref,
         l_ref[...] = corr * l_prev + jnp.broadcast_to(
             jnp.sum(e, axis=1, keepdims=True), l_prev.shape)
         m_ref[...] = m_new
-        pv = jax.lax.dot_general(
-            e, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [grp, d]
+        pv = _dot(e, v, ((1,), (0,)))                    # [grp, d]
         acc_ref[...] = acc_ref[...] * corr[:, :1] + pv
 
     @pl.when(p == num_p - 1)
@@ -311,8 +303,9 @@ def _paged_attention_pallas(q, k_pages, v_pages, page_tables, seq_lens,
                  v_scales.reshape(kvh, num_pages, page_size, 1))
     else:
         kernel, extra = _decode_kernel, ()
-    out = pl.pallas_call(
+    out = routing.pallas_call(
         functools.partial(kernel, scale=scale, page_size=page_size),
+        name="paged_attention_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, grp, d), q.dtype),
         interpret=interpret,
@@ -341,18 +334,14 @@ def paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
             f"per byte), got head_dim={d}")
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    ok = supports(nh, kvh, d, page_size)
-    if use_kernel is None:
-        use_kernel = ok and (interpret is True or _on_tpu())
-    if use_kernel and not ok:
-        raise ValueError(
-            f"paged_attention kernel does not support heads={nh}/"
-            f"kv_heads={kvh}, head_dim={d}, page_size={page_size}")
+    use_kernel, interpret = routing.route(
+        "paged_attention", supports(nh, kvh, d, page_size),
+        (f"heads={nh}/{kvh}", f"head_dim={d}", f"page_size={page_size}"),
+        interpret, use_kernel)
     if use_kernel:
         return _paged_attention_pallas(
             q, k_pages, v_pages, page_tables, seq_lens, float(scale),
-            bool(interpret) if interpret is not None else not _on_tpu(),
-            k_scales=k_scales, v_scales=v_scales)
+            interpret, k_scales=k_scales, v_scales=v_scales)
     return paged_attention_xla(q, k_pages, v_pages, page_tables,
                                seq_lens, scale=float(scale),
                                k_scales=k_scales, v_scales=v_scales)
@@ -433,10 +422,8 @@ def _chunk_kernel(pt_ref, st_ref, q_ref, k_ref, v_ref, *rest, scale,
         else:
             k = k_ref[0, 0]
             v = v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q.astype(jnp.float32), k.astype(jnp.float32),
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [grp*c, ps]
+        s = _dot(q.astype(jnp.float32), k.astype(jnp.float32),
+                 ((1,), (1,))) * scale                   # [grp*c, ps]
         kpos = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         qpos = st + jax.lax.broadcasted_iota(
@@ -451,10 +438,7 @@ def _chunk_kernel(pt_ref, st_ref, q_ref, k_ref, v_ref, *rest, scale,
         l_ref[...] = corr * l_prev + jnp.broadcast_to(
             jnp.sum(e, axis=1, keepdims=True), l_prev.shape)
         m_ref[...] = m_new
-        pv = jax.lax.dot_general(
-            e.astype(jnp.float32), v.astype(jnp.float32),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [grp*c, d]
+        pv = _dot(e, v.astype(jnp.float32), ((1,), (0,)))  # [grp*c, d]
         acc_ref[...] = acc_ref[...] * corr[:, :1] + pv
 
     @pl.when(p == num_p - 1)
@@ -496,10 +480,11 @@ def _paged_attention_chunk_pallas(q, k_pages, v_pages, page_tables,
     extra = ((k_scales.reshape(kvh, num_pages, page_size, 1),
               v_scales.reshape(kvh, num_pages, page_size, 1))
              if quant is not None else ())
-    out = pl.pallas_call(
+    out = routing.pallas_call(
         functools.partial(_chunk_kernel, scale=scale,
                           page_size=page_size, chunk=c,
                           quant=quant),
+        name="paged_attention_chunk",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, rows, d), q.dtype),
         interpret=interpret,
@@ -522,18 +507,14 @@ def paged_attention_chunk(q, k_pages, v_pages, page_tables, start,
             f"per byte), got head_dim={d}")
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    ok = supports(nh, kvh, d, page_size)
-    if use_kernel is None:
-        use_kernel = ok and (interpret is True or _on_tpu())
-    if use_kernel and not ok:
-        raise ValueError(
-            f"paged_attention_chunk kernel does not support heads={nh}/"
-            f"kv_heads={kvh}, head_dim={d}, page_size={page_size}")
+    use_kernel, interpret = routing.route(
+        "paged_attention_chunk", supports(nh, kvh, d, page_size),
+        (f"heads={nh}/{kvh}", f"head_dim={d}", f"page_size={page_size}"),
+        interpret, use_kernel)
     if use_kernel:
         return _paged_attention_chunk_pallas(
             q, k_pages, v_pages, page_tables, start, float(scale),
-            bool(interpret) if interpret is not None else not _on_tpu(),
-            k_scales=k_scales, v_scales=v_scales)
+            interpret, k_scales=k_scales, v_scales=v_scales)
     return paged_attention_chunk_xla(
         q, k_pages, v_pages, page_tables, start, scale=float(scale),
         k_scales=k_scales, v_scales=v_scales)

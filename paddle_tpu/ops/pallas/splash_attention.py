@@ -30,7 +30,7 @@ Two paths, one contract (the `paged_attention.py` pattern):
 
 * **Pallas kernel** — TPU (or `interpret=True` for hermetic CPU
   parity runs; see `paddle_tpu/ops/pallas/training_selftest.py`).
-* **XLA fallback** (`splash_attention_xla`) — CPU / legacy jax: one
+* **XLA path** (`splash_attention_xla`) — CPU, unsupported geometry: one
   dense masked attention with identical mask + empty-row semantics,
   parity-tested against the interpret-mode kernel.
 
@@ -48,8 +48,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .flash_attention import (  # noqa: F401  (shared probes + helpers)
-    _HAS_PALLAS, _LANES, _REVISIT_MIN, _Z, _causal_mask, _dot, _on_tpu,
+from . import routing
+from .flash_attention import (  # noqa: F401  (shared kernel helpers)
+    _LANES, _REVISIT_MIN, _Z, _causal_mask, _dot,
     _pick_block, pl, pltpu,
 )
 
@@ -61,8 +62,6 @@ _SUB = 8  # sublane replication of the kv-side segment-id plane
 
 def supports(q_shape, num_kv_heads, dtype, sk=None) -> bool:
     """Whether the Pallas kernel can take this problem (else XLA)."""
-    if not _HAS_PALLAS:
-        return False
     if dtype not in (jnp.float32, jnp.bfloat16, jnp.float16):
         return False
     b, sq, h, d = q_shape
@@ -80,7 +79,8 @@ def kernel_active(q_shape, num_kv_heads, dtype) -> bool:
 
     if not _flags.get_flag("FLAGS_splash_attn"):
         return False
-    return supports(tuple(q_shape), num_kv_heads, dtype) and _on_tpu()
+    return (supports(tuple(q_shape), num_kv_heads, dtype)
+            and routing.on_tpu())
 
 
 # ---------------------------------------------------------------------------
@@ -227,8 +227,9 @@ def _fwd(q, k, v, segq, segk, scale, causal, bq, bk, sq, kvh, with_seg,
         _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
         sq=sq, nqs=nqs, with_seg=with_seg)
     args = [q, k, v] + ([segq, segk] if with_seg else [])
-    out, lse = pl.pallas_call(
+    out, lse = routing.pallas_call(
         kern,
+        name="splash_fwd",
         grid=(bh, sq_all // bq, sk // bk),
         in_specs=[spec_q, spec_k, spec_k] + seg_specs,
         out_specs=[spec_q, spec_lse],
@@ -318,30 +319,20 @@ def _bwd_call(q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
     sk = k.shape[1]
     nqs = sq // bq
     # q-side operands arrive pre-sliced to the processed rows (the
-    # rowloop passes one q-row per call), so q-side specs index from 0;
+    # rowloop passes one q-row per call), so q-side specs index from 0
+    # (the rowloop's single segment block hits index 0 either way);
     # qi_base only offsets the causal/segment positions in the kernel.
-    spec_q = pl.BlockSpec((1, bq, d), lambda g, i, j: (g, i, _Z))
-    spec_k = pl.BlockSpec((1, bk, d), lambda g, i, j: (g, j, _Z))
-    spec_lse = pl.BlockSpec((1, bq, _LANES), lambda g, i, j: (g, i, _Z))
-    seg_specs = []
-    if with_seg:
-        # the q-side segment plane has only sq // bq position blocks:
-        # fold the GQA group dim out of the q-row block index (i % nqs);
-        # the rowloop's pre-sliced single block hits index 0 either way
-        seg_specs = [
-            pl.BlockSpec((1, bq, _LANES),
-                         lambda g, i, j: (g // kvh, i % nqs, _Z)),
-            pl.BlockSpec((1, _SUB, bk), lambda g, i, j: (g // kvh, _Z,
-                                                         j)),
-        ]
+    spec_q, spec_k, spec_lse, seg_specs = _specs(
+        bh, bq, bk, d, nqs, kvh, with_seg)
     kern = functools.partial(
         _bwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
         sq=sq, nqs=nqs, with_seg=with_seg, qi_base=qi_base)
     n_in = 6 + (2 if with_seg else 0)
     args = ([q, k, v, do, out, lse]
             + ([segq, segk] if with_seg else []) + [dk_acc, dv_acc])
-    return pl.pallas_call(
+    return routing.pallas_call(
         kern,
+        name="splash_bwd",
         grid=(bh, num_q, sk // bk),
         in_specs=[spec_q, spec_k, spec_k, spec_q, spec_q, spec_lse]
         + seg_specs + [spec_k, spec_k],
@@ -520,18 +511,13 @@ def splash_attention(q, k, v, causal=True, segment_ids=None, scale=None,
         raise ValueError(f"num_heads {h} not a multiple of kv heads {kvh}")
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    ok = supports((b, sq, h, d), kvh, q.dtype, sk=sk)
-    if use_kernel is None:
-        use_kernel = ok and (interpret is True or _on_tpu())
-    if use_kernel and not ok:
-        raise ValueError(
-            f"splash kernel does not support q{(b, sq, h, d)} with "
-            f"kv_heads={kvh} dtype={q.dtype}")
+    use_kernel, interpret = routing.route(
+        "splash_attention", supports((b, sq, h, d), kvh, q.dtype, sk=sk),
+        (f"q{(b, sq, h, d)}", f"kv_heads={kvh}", f"sk={sk}", str(q.dtype)),
+        interpret, use_kernel)
     if not use_kernel:
         return splash_attention_xla(q, k, v, causal=causal,
                                     segment_ids=segment_ids, scale=scale)
-    if interpret is None:
-        interpret = not _on_tpu()
     grp = h // kvh
     if block_q is None:
         block_q = _pick_block(sq)

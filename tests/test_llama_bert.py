@@ -98,10 +98,6 @@ class TestLlama:
         np.testing.assert_allclose(np.asarray(gqa(ids)._data),
                                    np.asarray(mha(ids)._data), atol=2e-5)
 
-    @pytest.mark.skipif(
-        paddle.jax_compat_legacy,
-        reason="old XLA: PartitionId unsupported under SPMD partitioning "
-               "(the pipeline shard_map path needs the new toolchain)")
     def test_config5_tp_pp_sp_slice(self):
         """BASELINE config 5 slice: LLaMA under a dp×pp... actually
         tp(mp)×sep hybrid mesh, TP-sharded weights, SP seq sharding,

@@ -146,7 +146,10 @@ class TestFunctionalIntegration:
             return orig(*a, **kw)
 
         monkeypatch.setattr(fa, "flash_attention", spy)
-        flags.set_flags({"FLAGS_pallas_flash_min_seqlen": 64})
+        # off TPU the kernel slot is reached only through the explicit
+        # interpret flag (ops/pallas/routing.py)
+        flags.set_flags({"FLAGS_pallas_flash_min_seqlen": 64,
+                         "FLAGS_pallas_force_interpret": True})
         try:
             q, k, v = _rand_qkv(b=1, s=64, h=2, d=32)
             qt, kt, vt = (paddle.to_tensor(np.asarray(x)) for x in (q, k, v))
@@ -156,14 +159,16 @@ class TestFunctionalIntegration:
             np.testing.assert_allclose(np.asarray(out._data), np.asarray(want),
                                        atol=2e-5)
         finally:
-            flags.set_flags({"FLAGS_pallas_flash_min_seqlen": 1024})
+            flags.set_flags({"FLAGS_pallas_flash_min_seqlen": 1024,
+                             "FLAGS_pallas_force_interpret": False})
 
     def test_sdpa_backward_through_pallas(self):
         import paddle_tpu as paddle
         import paddle_tpu.nn.functional as F
         from paddle_tpu.utils import flags
 
-        flags.set_flags({"FLAGS_pallas_flash_min_seqlen": 64})
+        flags.set_flags({"FLAGS_pallas_flash_min_seqlen": 64,
+                         "FLAGS_pallas_force_interpret": True})
         try:
             qn = np.random.default_rng(1).standard_normal(
                 (1, 64, 2, 32)).astype(np.float32)
@@ -175,4 +180,5 @@ class TestFunctionalIntegration:
             assert q.grad is not None and np.isfinite(
                 np.asarray(q.grad._data)).all()
         finally:
-            flags.set_flags({"FLAGS_pallas_flash_min_seqlen": 1024})
+            flags.set_flags({"FLAGS_pallas_flash_min_seqlen": 1024,
+                             "FLAGS_pallas_force_interpret": False})

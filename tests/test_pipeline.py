@@ -157,10 +157,6 @@ class TestGPTPipeParity:
         assert abs(float(crit(plain(ids), labels)) -
                    float(crit(pipe(ids), labels))) < 1e-5
 
-    @pytest.mark.skipif(
-        paddle.jax_compat_legacy,
-        reason="old XLA: PartitionId unsupported under SPMD partitioning "
-               "(the pipeline shard_map path needs the new toolchain)")
     def test_train_step_pp_dp_mesh(self):
         """Full fused TrainStep over a dp×pp mesh: loss decreases and the
         jitted step does not retrace."""

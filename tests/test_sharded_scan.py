@@ -246,15 +246,6 @@ def test_dropout_bwd_recompute_matches_jax_grad():
                                    err_msg=p.name or str(j))
 
 
-def test_donation_guard_inherited_on_legacy(mesh):
-    _, _, _, step = _build(mesh, "sharded", steps=1)
-    if paddle.jax_compat_legacy:
-        # 0.4.x CPU corrupts donated buffers (the TrainStep guard);
-        # the params must still be alive after a step
-        for p in step._s_params:
-            _ = np.asarray(p._data)   # would raise on a donated buffer
-
-
 def test_hlo_reduce_scatter_per_chunk_and_no_full_grads(mesh):
     """HLO asserts: >= 1 reduce-scatter per unrolled layer chunk in the
     backward while-body, the param all-gather present, and NO

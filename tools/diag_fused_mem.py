@@ -25,17 +25,13 @@ def main():
     seq = int(os.environ.get("SEQ", "1024"))
     top_k = int(os.environ.get("TOP_K", "8"))
 
-    import jax
-
-    cache = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), ".jax_cache")
-    jax.config.update("jax_compilation_cache_dir", cache)
-
     import paddle_tpu as paddle
     import paddle_tpu.optimizer as popt
     from paddle_tpu.jit import FusedScanTrainStep
     from paddle_tpu.models import GPTForCausalLM, gpt_config
+    from paddle_tpu.utils.compile_cache_dir import use_compile_cache
 
+    use_compile_cache()
     cfg = gpt_config(model_name, max_position_embeddings=seq,
                      hidden_dropout_prob=0.0, attention_dropout_prob=0.0,
                      scan_layers=True)
