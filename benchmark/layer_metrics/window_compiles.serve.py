@@ -1,0 +1,5 @@
+"""Executables the engine's steps gained inside the window (should be 0)."""
+
+
+def read(ctx):
+    return ctx["counters"]["window_compiles"]
