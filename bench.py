@@ -180,15 +180,6 @@ def run_config(model_name, batch, seq, steps, recompute, remat_policy,
     # dispatch interval (dispatch is async; the aggregate wall time
     # below is the throughput truth, the timeline shows its shape)
     from paddle_tpu.observability import JsonlSink, StepTimeline
-    from paddle_tpu.observability.goodput import (
-        goodput_baseline, goodput_breakdown,
-    )
-
-    # snapshot cumulative instruments BEFORE the measured loop so an
-    # earlier run in this process (primary before secondary) cannot
-    # charge its costs to this config's steps
-    gp_base = goodput_baseline()
-
     os.makedirs(_LIVE_DIR, exist_ok=True)
     tl_path = os.path.join(_LIVE_DIR, f"timeline_{model_name}.jsonl")
     open(tl_path, "w").close()          # fresh artifact per run
@@ -210,11 +201,6 @@ def run_config(model_name, batch, seq, steps, recompute, remat_policy,
     pf_stats = pf.get_stats()
 
     tokens_per_sec = batch * seq * steps / dt
-
-    # goodput attribution (ISSUE 13): fold the registry's stall/bubble/
-    # comm gauges into one per-step goodput.* breakdown for the record
-    goodput = goodput_breakdown(step_ms=dt / steps * 1e3, steps=steps,
-                                baseline=gp_base)
 
     # HLO-derived accounting (ISSUE 12): ask the COMPILER what the step
     # actually executes — cost-analysis flops (vs the analytic 6N
@@ -313,7 +299,6 @@ def run_config(model_name, batch, seq, steps, recompute, remat_policy,
         "timeline": {"path": os.path.relpath(
             tl_path, os.path.dirname(os.path.abspath(__file__))),
             "steps": steps},
-        "goodput": goodput,
         "input_pipeline": {
             "input_stall_ms": pf_stats["input_stall_ms"]["mean"],
             "h2d_ms": pf_stats["h2d_ms"]["mean"],

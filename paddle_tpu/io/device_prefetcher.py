@@ -24,7 +24,7 @@ Instrumented end to end: per-step `input_stall_ms` (how long `next()`
 blocked waiting for data — ≈0 when the pipeline keeps up) and `h2d_ms`
 (host→device transfer time on the producer thread), exposed via
 `get_stats()` and as profiler `RecordEvent` spans
-("DevicePrefetcher.h2d" / "DevicePrefetcher.wait").
+("paddle_tpu.input.h2d" / "paddle_tpu.input.wait").
 
 Usage::
 
@@ -95,7 +95,7 @@ class _Epoch:
                 if self._stop.is_set():
                     return
                 t0 = time.perf_counter()
-                with RecordEvent("DevicePrefetcher.h2d"):
+                with RecordEvent("paddle_tpu.input.h2d"):
                     staged = _tree_map(pf._stage_leaf, batch)
                     # block here (on the PRODUCER thread, never the step
                     # loop) so h2d_ms is the true transfer time and the
@@ -338,12 +338,17 @@ class DevicePrefetcher:
         return self
 
     def __next__(self):
+        # the span is the whole call (the layer's boundary); the stall
+        # counter inside it is the wait on the ring alone
+        with RecordEvent("paddle_tpu.input.wait"):
+            return self._next()
+
+    def _next(self):
         ep = self._epoch
         if ep is None:
             raise StopIteration
         t0 = time.perf_counter()
-        with RecordEvent("DevicePrefetcher.wait"):
-            item = ep._q.get()
+        item = ep._q.get()
         if item is _SENTINEL:
             self._epoch = None
             ep._thread.join(timeout=10)

@@ -279,6 +279,7 @@ class FusedScanTrainStep:
         self._sentinel = RetraceSentinel(type(self).__name__,
                                          optional=("segment_ids",))
         self._canon_done = False   # one-time layout canon at first call
+        self._calls = 0     # host-side: `_step_count` lives on the device
         # adopt the optimizer's existing step count: continuing a run
         # that already trained under TrainStep must not reset the Adam
         # bias corrections to t=1 (r5 review finding)
@@ -571,8 +572,10 @@ class FusedScanTrainStep:
 
                 # ---- forward: embed + scan over chunks of K layers,
                 # saving only each CHUNK's input
-                x0 = self._embed_fn(o["p"], ids, pos,
-                                    rng_off=self._rng_base(t32, n_layers))
+                with jax.named_scope("forward"):
+                    x0 = self._embed_fn(
+                        o["p"], ids, pos,
+                        rng_off=self._rng_base(t32, n_layers))
                 sp_c = tuple(a.reshape((a.shape[0] // K, K)
                                        + tuple(a.shape[1:]))
                              for a in s["p"])
@@ -606,19 +609,23 @@ class FusedScanTrainStep:
                     return (h2, out_fin), ys
 
                 fwd0 = ((x0, jnp.isfinite(x0).all()) if nm else x0)
-                fwd_c, ys = lax.scan(
-                    fwd_body, fwd0, (sp_c, jnp.arange(C)),
-                    unroll=self._scan_unroll)
+                with jax.named_scope("forward"):
+                    fwd_c, ys = lax.scan(
+                        fwd_body, fwd0, (sp_c, jnp.arange(C)),
+                        unroll=self._scan_unroll)
                 xL = fwd_c[0] if nm else fwd_c
                 xs, auxs = ys["x"], ys.get("aux")
                 act_cols = ys.get("act")           # [C, 3] when nm
 
                 # ---- head (+ its whole vjp: small params, one buffer)
-                loss, head_vjp = jax.vjp(
-                    lambda od, x: self._head_fn(od, x, labels), o["p"], xL)
+                with jax.named_scope("forward"):
+                    loss, head_vjp = jax.vjp(
+                        lambda od, x: self._head_fn(od, x, labels),
+                        o["p"], xL)
                 ct = (gst["scale"].astype(loss.dtype) if scaling
                       else jnp.ones((), loss.dtype))
-                d_o_head, dxL = head_vjp(ct)
+                with jax.named_scope("backward"):
+                    d_o_head, dxL = head_vjp(ct)
                 aux_ct = None
                 if aux_active:
                     # total loss = CE + (w/L) * sum(aux); the chunk vjps
@@ -697,17 +704,18 @@ class FusedScanTrainStep:
                         return (dx, sq, fin), row
 
                     P0 = sp_c
-                    (dx0, sq, fin), grad_rows = lax.scan(
-                        norm_body,
-                        (dxL, jnp.float32(0.0), jnp.bool_(True)),
-                        (xs, jnp.arange(C)), reverse=True,
-                        unroll=self._scan_unroll)
-                    _, emb_vjp = jax.vjp(
-                        lambda od: self._embed_fn(
-                            od, ids, pos,
-                            rng_off=self._rng_base(t32, n_layers)),
-                        o["p"])
-                    (d_o_emb,) = emb_vjp(dx0)
+                    with jax.named_scope("backward"):
+                        (dx0, sq, fin), grad_rows = lax.scan(
+                            norm_body,
+                            (dxL, jnp.float32(0.0), jnp.bool_(True)),
+                            (xs, jnp.arange(C)), reverse=True,
+                            unroll=self._scan_unroll)
+                        _, emb_vjp = jax.vjp(
+                            lambda od: self._embed_fn(
+                                od, ids, pos,
+                                rng_off=self._rng_base(t32, n_layers)),
+                            o["p"])
+                        (d_o_emb,) = emb_vjp(dx0)
                     o_g32 = [(d_o_head[j].astype(jnp.float32)
                               + d_o_emb[j].astype(jnp.float32))
                              for j in range(len(o["p"]))]
@@ -760,70 +768,72 @@ class FusedScanTrainStep:
                                 .astype(jnp.float32),
                                 jnp.float32(0.0)])
                     nP, nM, nV, nMW = [], [], [], []
-                    for j in range(n_leaves):
-                        if not self._s_params[j].trainable:
-                            # frozen stacked leaf: no update (XLA DCEs
-                            # its unused dp slice); parity with the
-                            # tape path's stop_gradient handling
-                            nP.append(P[j])
-                            nM.append(M[j])
-                            nV.append(V[j])
-                            nMW.append(MW[j])
-                            continue
-                        wd, l2, lrs = s_hyp[j]
-                        m_j = lax.dynamic_index_in_dim(M[j], i,
-                                                       keepdims=False)
-                        v_j = lax.dynamic_index_in_dim(V[j], i,
-                                                       keepdims=False)
-                        mw_j = (lax.dynamic_index_in_dim(
-                            MW[j], i, keepdims=False)
-                            if MW[j] is not None else None)
-                        pv = mw_j if mw_j is not None else p_i[j]
-                        g32 = dp[j].astype(jnp.float32)
-                        if inv_s is not None:
-                            g32 = g32 * inv_s
-                        g32 = scaled(clip_g32(g32, self._s_params[j]),
-                                     self._s_params[j], scale)
-                        out, mn, vn, _ = adam(
-                            pv, g32, m_j, v_j,
-                            lr * lrs, tf, jnp.float32(wd), l2)
-                        if nm:
-                            pv32 = pv.astype(jnp.float32)
-                            d_upd = out.astype(jnp.float32) - pv32
+                    with jax.named_scope("optimizer"):
+                        for j in range(n_leaves):
+                            if not self._s_params[j].trainable:
+                                # frozen stacked leaf: no update (XLA DCEs
+                                # its unused dp slice); parity with the
+                                # tape path's stop_gradient handling
+                                nP.append(P[j])
+                                nM.append(M[j])
+                                nV.append(V[j])
+                                nMW.append(MW[j])
+                                continue
+                            wd, l2, lrs = s_hyp[j]
+                            m_j = lax.dynamic_index_in_dim(M[j], i,
+                                                           keepdims=False)
+                            v_j = lax.dynamic_index_in_dim(V[j], i,
+                                                           keepdims=False)
+                            mw_j = (lax.dynamic_index_in_dim(
+                                MW[j], i, keepdims=False)
+                                if MW[j] is not None else None)
+                            pv = mw_j if mw_j is not None else p_i[j]
+                            g32 = dp[j].astype(jnp.float32)
+                            if inv_s is not None:
+                                g32 = g32 * inv_s
+                            g32 = scaled(clip_g32(g32, self._s_params[j]),
+                                         self._s_params[j], scale)
+                            out, mn, vn, _ = adam(
+                                pv, g32, m_j, v_j,
+                                lr * lrs, tf, jnp.float32(wd), l2)
+                            if nm:
+                                pv32 = pv.astype(jnp.float32)
+                                d_upd = out.astype(jnp.float32) - pv32
+                                if found is not None:
+                                    d_upd = jnp.where(
+                                        found, jnp.zeros_like(d_upd), d_upd)
+                                p_sq = p_sq + jnp.sum(jnp.square(pv32))
+                                u_sq = u_sq + jnp.sum(jnp.square(d_upd))
+                            out_p = out.astype(P[j].dtype)
+                            mn_c = mn.astype(M[j].dtype)
+                            vn_c = vn.astype(V[j].dtype)
                             if found is not None:
-                                d_upd = jnp.where(
-                                    found, jnp.zeros_like(d_upd), d_upd)
-                            p_sq = p_sq + jnp.sum(jnp.square(pv32))
-                            u_sq = u_sq + jnp.sum(jnp.square(d_upd))
-                        out_p = out.astype(P[j].dtype)
-                        mn_c = mn.astype(M[j].dtype)
-                        vn_c = vn.astype(V[j].dtype)
-                        if found is not None:
-                            # bad step: every slot passes through
-                            # bit-identical (selection, not arithmetic)
-                            out_p = jnp.where(found, p_i[j], out_p)
-                            mn_c = jnp.where(found, m_j, mn_c)
-                            vn_c = jnp.where(found, v_j, vn_c)
-                            if mw_j is not None:
-                                out = jnp.where(found, mw_j, out)
-                        nP.append(lax.dynamic_update_index_in_dim(
-                            P[j], out_p, i, 0))
-                        nM.append(lax.dynamic_update_index_in_dim(
-                            M[j], mn_c, i, 0))
-                        nV.append(lax.dynamic_update_index_in_dim(
-                            V[j], vn_c, i, 0))
-                        nMW.append(lax.dynamic_update_index_in_dim(
-                            MW[j], out, i, 0)
-                            if MW[j] is not None else None)
+                                # bad step: every slot passes through
+                                # bit-identical (selection, not arithmetic)
+                                out_p = jnp.where(found, p_i[j], out_p)
+                                mn_c = jnp.where(found, m_j, mn_c)
+                                vn_c = jnp.where(found, v_j, vn_c)
+                                if mw_j is not None:
+                                    out = jnp.where(found, mw_j, out)
+                            nP.append(lax.dynamic_update_index_in_dim(
+                                P[j], out_p, i, 0))
+                            nM.append(lax.dynamic_update_index_in_dim(
+                                M[j], mn_c, i, 0))
+                            nV.append(lax.dynamic_update_index_in_dim(
+                                V[j], vn_c, i, 0))
+                            nMW.append(lax.dynamic_update_index_in_dim(
+                                MW[j], out, i, 0)
+                                if MW[j] is not None else None)
                     if nm:
                         ys_b["pu"] = jnp.stack([p_sq, u_sq])
                     return (dx, tuple(nP), tuple(nM), tuple(nV),
                             tuple(nMW)), ys_b
 
                 carry0 = (dxL, sp_c, sm_c, sv_c, smw_c)
-                (dx0, nP, nM, nV, nMW), bwd_ys = lax.scan(
-                    bwd_body, carry0, (xs, jnp.arange(C)), reverse=True,
-                    unroll=self._scan_unroll)
+                with jax.named_scope("backward"):
+                    (dx0, nP, nM, nV, nMW), bwd_ys = lax.scan(
+                        bwd_body, carry0, (xs, jnp.arange(C)),
+                        reverse=True, unroll=self._scan_unroll)
                 # back to the [L, ...] stacked layout
                 nP = [a.reshape((-1,) + tuple(a.shape[2:])) for a in nP]
                 nM = [a.reshape((-1,) + tuple(a.shape[2:])) for a in nM]
@@ -834,56 +844,58 @@ class FusedScanTrainStep:
                 # ---- embedding-side grads for outer params + update
                 # (already computed by the norm pass when clipping)
                 if d_o_emb is None:
-                    _, emb_vjp = jax.vjp(
-                        lambda od: self._embed_fn(
-                            od, ids, pos,
-                            rng_off=self._rng_base(t32, n_layers)),
-                        o["p"])
-                    (d_o_emb,) = emb_vjp(dx0)
+                    with jax.named_scope("backward"):
+                        _, emb_vjp = jax.vjp(
+                            lambda od: self._embed_fn(
+                                od, ids, pos,
+                                rng_off=self._rng_base(t32, n_layers)),
+                            o["p"])
+                        (d_o_emb,) = emb_vjp(dx0)
                 new_o = {"p": [], "m": [], "v": [], "mw": []}
                 if nm:
                     o_g_sq = jnp.float32(0.0)
                     o_p_sq = jnp.float32(0.0)
                     o_u_sq = jnp.float32(0.0)
-                for j in range(len(o["p"])):
-                    wd, l2, lrs = o_hyp[j]
-                    g32 = (d_o_head[j].astype(jnp.float32)
-                           + d_o_emb[j].astype(jnp.float32))
-                    if nm:
-                        # raw (still loss-scaled) grads — the inv_s²
-                        # unscale is applied once at assembly below
-                        o_g_sq = o_g_sq + jnp.sum(jnp.square(g32))
-                    if inv_s is not None:
-                        g32 = g32 * inv_s
-                    g32 = scaled(clip_g32(g32, self._o_params[j][1]),
-                                 self._o_params[j][1], scale)
-                    pv = (o["mw"][j] if o["mw"][j] is not None
-                          else o["p"][j])
-                    out, mn, vn, _ = adam(pv, g32, o["m"][j], o["v"][j],
-                                          lr * lrs, tf, jnp.float32(wd),
-                                          l2)
-                    if nm:
-                        pv32 = pv.astype(jnp.float32)
-                        d_upd = out.astype(jnp.float32) - pv32
+                with jax.named_scope("optimizer"):
+                    for j in range(len(o["p"])):
+                        wd, l2, lrs = o_hyp[j]
+                        g32 = (d_o_head[j].astype(jnp.float32)
+                               + d_o_emb[j].astype(jnp.float32))
+                        if nm:
+                            # raw (still loss-scaled) grads — the inv_s²
+                            # unscale is applied once at assembly below
+                            o_g_sq = o_g_sq + jnp.sum(jnp.square(g32))
+                        if inv_s is not None:
+                            g32 = g32 * inv_s
+                        g32 = scaled(clip_g32(g32, self._o_params[j][1]),
+                                     self._o_params[j][1], scale)
+                        pv = (o["mw"][j] if o["mw"][j] is not None
+                              else o["p"][j])
+                        out, mn, vn, _ = adam(pv, g32, o["m"][j], o["v"][j],
+                                              lr * lrs, tf, jnp.float32(wd),
+                                              l2)
+                        if nm:
+                            pv32 = pv.astype(jnp.float32)
+                            d_upd = out.astype(jnp.float32) - pv32
+                            if found is not None:
+                                d_upd = jnp.where(
+                                    found, jnp.zeros_like(d_upd), d_upd)
+                            o_p_sq = o_p_sq + jnp.sum(jnp.square(pv32))
+                            o_u_sq = o_u_sq + jnp.sum(jnp.square(d_upd))
+                        out_p = out.astype(o["p"][j].dtype)
+                        mn_c = mn.astype(o["m"][j].dtype)
+                        vn_c = vn.astype(o["v"][j].dtype)
                         if found is not None:
-                            d_upd = jnp.where(
-                                found, jnp.zeros_like(d_upd), d_upd)
-                        o_p_sq = o_p_sq + jnp.sum(jnp.square(pv32))
-                        o_u_sq = o_u_sq + jnp.sum(jnp.square(d_upd))
-                    out_p = out.astype(o["p"][j].dtype)
-                    mn_c = mn.astype(o["m"][j].dtype)
-                    vn_c = vn.astype(o["v"][j].dtype)
-                    if found is not None:
-                        out_p = jnp.where(found, o["p"][j], out_p)
-                        mn_c = jnp.where(found, o["m"][j], mn_c)
-                        vn_c = jnp.where(found, o["v"][j], vn_c)
-                        if o["mw"][j] is not None:
-                            out = jnp.where(found, o["mw"][j], out)
-                    new_o["p"].append(out_p)
-                    new_o["m"].append(mn_c)
-                    new_o["v"].append(vn_c)
-                    new_o["mw"].append(out if o["mw"][j] is not None
-                                       else None)
+                            out_p = jnp.where(found, o["p"][j], out_p)
+                            mn_c = jnp.where(found, o["m"][j], mn_c)
+                            vn_c = jnp.where(found, o["v"][j], vn_c)
+                            if o["mw"][j] is not None:
+                                out = jnp.where(found, o["mw"][j], out)
+                        new_o["p"].append(out_p)
+                        new_o["m"].append(mn_c)
+                        new_o["v"].append(vn_c)
+                        new_o["mw"].append(out if o["mw"][j] is not None
+                                           else None)
 
                 new_state = {
                     "s": {"p": list(nP), "m": list(nM), "v": list(nV),
@@ -1044,6 +1056,12 @@ class FusedScanTrainStep:
                 "opt_state": self._opt_state_arrays()}
 
     def __call__(self, ids, labels, segment_ids=None):
+        with RecordEvent("paddle_tpu.step", step=self._calls):
+            return self._call(ids, labels, segment_ids)
+
+    def _call(self, ids, labels, segment_ids):
+        n = self._calls
+        self._calls = n + 1
         ids_d = ids._data if isinstance(ids, Tensor) else ids
         lab_d = labels._data if isinstance(labels, Tensor) else labels
         seg_d = (segment_ids._data if isinstance(segment_ids, Tensor)
@@ -1061,18 +1079,22 @@ class FusedScanTrainStep:
             if canon is not None:
                 self._inject_state(canon)
             self._canon_done = True
-        state = self._extract_state()
-        lr = jnp.asarray(self._opt.get_lr(), jnp.float32)
-        self._sentinel.observe(
-            (state, lr, ids_d, lab_d, seg_d),
-            names=("state", "lr", "ids", "labels", "segment_ids"))
+        with RecordEvent("paddle_tpu.step.extract_state", step=n):
+            state = self._extract_state()
+        with RecordEvent("paddle_tpu.step.lr", step=n):
+            lr = jnp.asarray(self._opt.get_lr(), jnp.float32)
+        with RecordEvent("paddle_tpu.step.sentinel", step=n):
+            self._sentinel.observe(
+                (state, lr, ids_d, lab_d, seg_d),
+                names=("state", "lr", "ids", "labels", "segment_ids"))
         from ..observability.memory import oom_guard as _oom_guard
 
-        with RecordEvent("FusedScanTrainStep"), self._step_guard(), \
+        with self._step_guard(), \
                 _oom_guard(
                     step=type(self).__name__,
                     profile=lambda: self.memory_profile(
-                        ids_d, lab_d, seg_d, publish=False)):
+                        ids_d, lab_d, seg_d, publish=False)), \
+                RecordEvent("paddle_tpu.step.dispatch", step=n):
             out = self._jitted(state, lr, ids_d, lab_d, seg_d)
         if self._numerics is not None:
             loss, new_state, nstats = out
@@ -1081,8 +1103,9 @@ class FusedScanTrainStep:
             self._numerics.on_step(nstats)
         else:
             loss, new_state = out
-        self._inject_state(new_state)
-        sched = getattr(self._opt, "_learning_rate", None)
-        if hasattr(sched, "step"):
-            sched.step()
+        with RecordEvent("paddle_tpu.step.inject_state", step=n):
+            self._inject_state(new_state)
+            sched = getattr(self._opt, "_learning_rate", None)
+            if hasattr(sched, "step"):
+                sched.step()
         return Tensor._wrap(loss)

@@ -329,18 +329,23 @@ class TrainStep:
                             (acc, t._data.shape[0] // acc)
                             + tuple(t._data.shape[1:]))[m])
                         if isinstance(t, Tensor) else t for t in batch_t]
-                    ml = self.loss_fn(self.model, *micro) * (1.0 / acc)
-                    backward(ml)
+                    with jax.named_scope("forward"):
+                        ml = self.loss_fn(self.model, *micro) * (1.0 / acc)
+                    with jax.named_scope("backward"):
+                        backward(ml)
                     losses.append(ml._data)
                 loss = Tensor._wrap(sum(losses))
             else:
-                loss = self.loss_fn(self.model, *batch_t)
-                backward(loss)
+                with jax.named_scope("forward"):
+                    loss = self.loss_fn(self.model, *batch_t)
+                with jax.named_scope("backward"):
+                    backward(loss)
             # gradient-comm boundary: all microbatch backwards are done,
             # flush the deferred bucket collectives (one per bucket)
             sync = getattr(self.model, "apply_collective_grads", None)
             if callable(sync):
-                sync()
+                with jax.named_scope("backward"):
+                    sync()
             # the in-graph guard: ONE fused finiteness reduction over
             # the (still scaled) grads; unscale in the same program
             found = None
@@ -366,7 +371,7 @@ class TrainStep:
                             for p in self._params]
             # freeze lr at the traced scalar for this step (declared
             # protocol: Optimizer.get_lr honors _lr_override)
-            with inner.lr_frozen(lr):
+            with inner.lr_frozen(lr), jax.named_scope("optimizer"):
                 if inner.get_lr() is not lr:
                     raise RuntimeError(
                         f"{type(inner).__name__}.get_lr() ignores "
@@ -390,27 +395,28 @@ class TrainStep:
             # ---- per-parameter numerics rows (ISSUE 15): grads were
             # unscaled above, updates read the GATED new params (zero
             # on a guard-skipped step); no scanned activations here
-            rows = []
-            f32 = jnp.float32
-            for i in range(len(self._params)):
-                g = nm_grads[i]
-                old_p = state["params"][i].astype(f32)
-                new_p = new_state["params"][i].astype(f32)
-                if g is not None and jnp.issubdtype(g.dtype,
-                                                    jnp.floating):
-                    g32 = g.astype(f32)
-                    g_sq = jnp.sum(jnp.square(g32))
-                    # finiteness DERIVES from the square-sum like the
-                    # scan paths (DECISIONS §21) — no second O(params)
-                    # pass; the guard keeps its own exact fold
-                    g_bad = (~jnp.isfinite(g_sq)).astype(f32)
-                else:
-                    g_sq = f32(0.0)
-                    g_bad = f32(0.0)
-                rows.append(jnp.stack([
-                    g_sq, jnp.sum(jnp.square(old_p)),
-                    jnp.sum(jnp.square(new_p - old_p)),
-                    f32(0.0), f32(0.0), g_bad, f32(0.0), f32(0.0)]))
+            with jax.named_scope("optimizer"), jax.named_scope("numerics"):
+                rows = []
+                f32 = jnp.float32
+                for i in range(len(self._params)):
+                    g = nm_grads[i]
+                    old_p = state["params"][i].astype(f32)
+                    new_p = new_state["params"][i].astype(f32)
+                    if g is not None and jnp.issubdtype(g.dtype,
+                                                        jnp.floating):
+                        g32 = g.astype(f32)
+                        g_sq = jnp.sum(jnp.square(g32))
+                        # finiteness DERIVES from the square-sum like the
+                        # scan paths (DECISIONS §21) — no second O(params)
+                        # pass; the guard keeps its own exact fold
+                        g_bad = (~jnp.isfinite(g_sq)).astype(f32)
+                    else:
+                        g_sq = f32(0.0)
+                        g_bad = f32(0.0)
+                    rows.append(jnp.stack([
+                        g_sq, jnp.sum(jnp.square(old_p)),
+                        jnp.sum(jnp.square(new_p - old_p)),
+                        f32(0.0), f32(0.0), g_bad, f32(0.0), f32(0.0)]))
             return loss._data, new_state, jnp.stack(rows)
 
         donate = (0,) if self._donate else ()
@@ -430,6 +436,11 @@ class TrainStep:
         live_registry().track(self)
 
     def __call__(self, *batch):
+        with RecordEvent("paddle_tpu.step", step=self._step_count):
+            return self._call(batch)
+
+    def _call(self, batch):
+        n = self._step_count
         batch_data = _tree_data(list(batch))
         if self._jitted is None:
             # the global generator offset may be a device array committed to
@@ -444,10 +455,13 @@ class TrainStep:
             # the declared dry-run protocol)
             self._warmup_accumulators()
             self._build(batch_data)
-        state = self._extract_state()
-        lr = jnp.asarray(self._opt.get_lr(), jnp.float32)
-        self._sentinel.observe((state, lr, batch_data),
-                               names=("state", "lr", "batch"))
+        with RecordEvent("paddle_tpu.step.extract_state", step=n):
+            state = self._extract_state()
+        with RecordEvent("paddle_tpu.step.lr", step=n):
+            lr = jnp.asarray(self._opt.get_lr(), jnp.float32)
+        with RecordEvent("paddle_tpu.step.sentinel", step=n):
+            self._sentinel.observe((state, lr, batch_data),
+                                   names=("state", "lr", "batch"))
         try:
             # comm watchdog (reference comm_task_manager.h:37): the dispatch
             # blocks when the device queue is full behind a dead collective,
@@ -455,8 +469,8 @@ class TrainStep:
             # dispatch pipelining
             from ..distributed import comm_watchdog
 
-            with RecordEvent("TrainStep"), \
-                    comm_watchdog.watch(f"TrainStep#{self._step_count}"):
+            with comm_watchdog.watch(f"TrainStep#{n}"), \
+                    RecordEvent("paddle_tpu.step.dispatch", step=n):
                 out = self._jitted(state, lr, batch_data)
             if self._numerics is not None:
                 loss_data, new_state, nstats = out
@@ -481,11 +495,12 @@ class TrainStep:
             # restore the concrete state so the model stays usable
             self._inject_state(state)
             raise
-        self._inject_state(new_state)
-        # advance host-side schedulers
-        sched = getattr(self._opt, "_learning_rate", None)
-        if hasattr(sched, "step"):
-            sched.step()
+        with RecordEvent("paddle_tpu.step.inject_state", step=n):
+            self._inject_state(new_state)
+            # advance host-side schedulers
+            sched = getattr(self._opt, "_learning_rate", None)
+            if hasattr(sched, "step"):
+                sched.step()
         return Tensor._wrap(loss_data)
 
     # -- telemetry surface ----------------------------------------------

@@ -38,8 +38,6 @@ serving:
   rolling-window burn-rate gauges on the registry.
 - `DebugServer` — stdlib-only loopback HTTP: `/metrics` (Prometheus),
   `/healthz`, `/tracez`, `/flightz` (opt-in from ServingEngine/bench).
-- `goodput_breakdown` — per-step `goodput.*` step-time attribution
-  folded from the existing stall/bubble/comm gauges (BENCH lanes).
 - `numerics` (ISSUE 15) — in-graph training-numerics observatory:
   per-layer-chunk grad/update/activation health computed INSIDE the
   compiled step scans ([chunks, k] stats block, one deferred readback
@@ -76,9 +74,8 @@ from .flight_recorder import (  # noqa: F401
     FlightRecorder, install, install_signal_dump, recorder,
     thread_stacks,
 )
-from .goodput import goodput_baseline, goodput_breakdown  # noqa: F401
 from .hlo_costs import (  # noqa: F401
-    cost_analysis_of, load_hlo_overlap, summarize_compiled,
+    cost_analysis_of, load_hlo_overlap,
 )
 from .memory import (  # noqa: F401
     CompiledMemoryProfile, LiveBufferRegistry, dump_oom, is_oom_error,
@@ -109,9 +106,9 @@ __all__ = [
     "set_strict_retrace", "strict_retrace", "retrace_summary",
     "enabled", "FlightRecorder", "recorder", "install",
     "install_signal_dump", "thread_stacks",
-    "summarize_compiled", "cost_analysis_of", "load_hlo_overlap",
+    "cost_analysis_of", "load_hlo_overlap",
     "Span", "Tracer", "drain_chrome_spans", "SLO", "SLOTracker",
-    "DebugServer", "goodput_breakdown", "goodput_baseline",
+    "DebugServer",
     "CompiledMemoryProfile", "LiveBufferRegistry", "live_registry",
     "live_buffer_report", "parse_hlo_buffers", "is_oom_error",
     "dump_oom", "oom_guard", "last_oom_report", "memz_payload",

@@ -2,7 +2,7 @@
 
 The analytic 6N-flops MFU the bench has always reported assumes the
 model math; ``compiled.cost_analysis()`` asks the COMPILER what the
-program actually executes. `summarize_compiled` pulls flops /
+program actually executes. `cost_analysis_of` pulls flops /
 bytes-accessed per step from the compiled executable and — via
 tools/hlo_overlap.py's per-axis collective census extended with payload
 bytes — the communication bytes per step per mesh axis, then publishes
@@ -16,7 +16,7 @@ import os
 
 from .registry import registry as _registry
 
-__all__ = ["load_hlo_overlap", "summarize_compiled", "cost_analysis_of"]
+__all__ = ["load_hlo_overlap", "cost_analysis_of"]
 
 
 def load_hlo_overlap():
@@ -45,18 +45,20 @@ def _cost_dict(compiled):
     return dict(ca or {})
 
 
-def summarize_compiled(compiled, axis_degrees=None, publish=True,
-                       prefix="hlo") -> dict:
-    """Per-step accounting of one compiled XLA executable.
+def cost_analysis_of(jitted, *args, axis_degrees=None, **kw) -> dict:
+    """AOT-lower + compile ``jitted`` for ``args`` and account for one
+    step of the executable. With the persistent XLA compile cache warm
+    (the jit call already compiled the same program) this is cheap; a
+    cold compile is the price of the receipt.
 
     Returns {"flops_per_step", "bytes_accessed_per_step",
     "collectives": {counts, per_axis_counts?, per_axis_bytes?,
     total_comm_bytes}}; numbers are PER DEVICE (cost_analysis and the
     per-device HLO module both are). ``axis_degrees`` (ordered
     {axis: degree}, mesh order) labels the comm traffic per mesh axis.
-    Publishes ``<prefix>.*`` gauges into the global registry unless
-    publish=False. Never raises — fields missing on a backend are
-    reported as None."""
+    Publishes ``hlo.*`` gauges into the global registry. Fields missing
+    on a backend are reported as None."""
+    compiled = jitted.lower(*args, **kw).compile()
     out = {"flops_per_step": None, "bytes_accessed_per_step": None,
            "collectives": None}
     try:
@@ -68,9 +70,8 @@ def summarize_compiled(compiled, axis_degrees=None, publish=True,
     except Exception as e:
         out["cost_analysis_error"] = f"{type(e).__name__}: {e}"[:200]
     try:
-        text = compiled.as_text()
-        mod = load_hlo_overlap()
-        verdict = mod.analyze(text, axis_degrees=axis_degrees)
+        verdict = load_hlo_overlap().analyze(compiled.as_text(),
+                                             axis_degrees=axis_degrees)
         coll = {"counts": verdict.get("counts", {}),
                 "total_comm_bytes": verdict.get("total_comm_bytes", 0)}
         for k in ("per_axis_counts", "per_axis_bytes"):
@@ -79,33 +80,18 @@ def summarize_compiled(compiled, axis_degrees=None, publish=True,
         out["collectives"] = coll
     except Exception as e:
         out["collectives_error"] = f"{type(e).__name__}: {e}"[:200]
-    if publish:
-        try:
-            reg = _registry()
-            if out["flops_per_step"] is not None:
-                reg.gauge(f"{prefix}.flops_per_step").set(
-                    out["flops_per_step"])
-            if out["bytes_accessed_per_step"] is not None:
-                reg.gauge(f"{prefix}.bytes_accessed_per_step").set(
-                    out["bytes_accessed_per_step"])
-            coll = out.get("collectives") or {}
-            reg.gauge(f"{prefix}.comm_bytes_per_step").set(
-                coll.get("total_comm_bytes", 0))
-            for axis, nbytes in (coll.get("per_axis_bytes")
-                                 or {}).items():
-                reg.gauge(
-                    f"{prefix}.comm_bytes_per_step.{axis}").set(nbytes)
-        except Exception:
-            pass
+    try:
+        reg = _registry()
+        if out["flops_per_step"] is not None:
+            reg.gauge("hlo.flops_per_step").set(out["flops_per_step"])
+        if out["bytes_accessed_per_step"] is not None:
+            reg.gauge("hlo.bytes_accessed_per_step").set(
+                out["bytes_accessed_per_step"])
+        coll = out.get("collectives") or {}
+        reg.gauge("hlo.comm_bytes_per_step").set(
+            coll.get("total_comm_bytes", 0))
+        for axis, nbytes in (coll.get("per_axis_bytes") or {}).items():
+            reg.gauge(f"hlo.comm_bytes_per_step.{axis}").set(nbytes)
+    except Exception:
+        pass
     return out
-
-
-def cost_analysis_of(jitted, *args, axis_degrees=None, prefix="hlo",
-                     **kw) -> dict:
-    """AOT-lower + compile ``jitted`` for ``args`` and summarize. With
-    the persistent XLA compile cache warm (the jit call already
-    compiled the same program) this is cheap; a cold compile is the
-    price of the receipt."""
-    compiled = jitted.lower(*args, **kw).compile()
-    return summarize_compiled(compiled, axis_degrees=axis_degrees,
-                              prefix=prefix)

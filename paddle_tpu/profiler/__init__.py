@@ -15,8 +15,10 @@ TPU-first split of responsibilities:
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import enum
+import gc
 import json
 import os
 import threading
@@ -25,8 +27,11 @@ from collections import defaultdict
 from dataclasses import dataclass, field
 from typing import Callable, Iterable, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
-    "ProfilerState", "ProfilerTarget", "Profiler", "RecordEvent",
+    "ProfilerState", "ProfilerTarget", "Profiler", "RecordEvent", "Span",
+    "spans",
     "make_scheduler", "export_chrome_tracing", "load_profiler_result",
 ]
 
@@ -115,44 +120,88 @@ class _HostEventRecorder:
 _recorder = _HostEventRecorder()
 
 
-class RecordEvent:
-    """User annotation span (reference profiler/utils.py RecordEvent).
+_RING = 1024        # spans kept per name
+_MAX_NAMES = 256    # names that get a ring: formatted names must not leak
+_rings: dict = {}
 
-    Usable as a context manager or begin()/end() pair. Also emits a
-    `jax.profiler.TraceAnnotation` so the span shows up inside the XLA
-    device timeline when device tracing is on.
+Span = collections.namedtuple("Span", "name t0 t1 thread step")
+
+
+def spans(name=None, lo=None, hi=None) -> list:
+    """The spans kept in memory, oldest first: those called `name` (all
+    names when None) that began within [lo, hi] on `time.perf_counter`.
+    A span's parent is the span of the same thread whose interval
+    encloses it."""
+    rings = _rings.values() if name is None else [_rings.get(name, ())]
+    out = [s for ring in rings for s in list(ring)
+           if (lo is None or s.t0 >= lo) and (hi is None or s.t0 <= hi)]
+    if name is None:
+        out.sort(key=lambda s: s.t0)
+    return out
+
+
+class RecordEvent:
+    """A span (reference profiler/utils.py RecordEvent).
+
+    Usable as a context manager or begin()/end() pair. It always opens a
+    `jax.profiler.TraceAnnotation(name, **attrs)`, so that it sits in the
+    host plane of whatever trace is being taken, on the device trace's
+    clock, whoever started the trace; it always leaves `Span(name, t0,
+    t1, thread, step)` in a ring of the last 1,024 spans of its name
+    (`spans()`); and it joins the paddle `Profiler`'s host events while
+    that is recording. `step` is the one attribute the ring keeps.
     """
 
-    def __init__(self, name: str, event_type=None):
+    def __init__(self, name: str, event_type=None, **attrs):
         self.name = name
+        self._attrs = attrs
         self._t0 = None
         self._jax_ctx = None
 
     def begin(self):
-        self._t0 = time.perf_counter_ns()
-        if _recorder.enabled:
-            try:
-                import jax.profiler as jp
-
-                self._jax_ctx = jp.TraceAnnotation(self.name)
-                self._jax_ctx.__enter__()
-            except Exception:
-                self._jax_ctx = None
+        self._jax_ctx = TraceAnnotation(self.name, **self._attrs)
+        self._jax_ctx.__enter__()
+        self._t0 = time.perf_counter()
         return self
 
     def end(self):
         if self._t0 is None:
             return
-        if self._jax_ctx is not None:
-            self._jax_ctx.__exit__(None, None, None)
-            self._jax_ctx = None
-        _recorder.record(self.name, self._t0, time.perf_counter_ns())
-        self._t0 = None
+        t0, t1, self._t0 = self._t0, time.perf_counter(), None
+        self._jax_ctx.__exit__(None, None, None)
+        self._jax_ctx = None
+        ring = _rings.get(self.name)
+        if ring is None and len(_rings) < _MAX_NAMES:
+            ring = _rings.setdefault(self.name,
+                                     collections.deque(maxlen=_RING))
+        if ring is not None:
+            ring.append(Span(self.name, t0, t1, threading.get_ident(),
+                             self._attrs.get("step")))
+        if _recorder.enabled:
+            _recorder.record(self.name, int(t0 * 1e9), int(t1 * 1e9))
 
     __enter__ = begin
 
     def __exit__(self, *exc):
         self.end()
+
+
+_gc_span = []       # the open full-collection span, if any
+
+
+def _on_gc(phase, info):
+    """`gc.callbacks` hook: one `paddle_tpu.host.gc` span per FULL
+    collection — the only kind long enough to stall a training loop."""
+    if info["generation"] != 2:
+        return
+    if phase == "start":
+        _gc_span.append(
+            RecordEvent("paddle_tpu.host.gc", generation=2).begin())
+    elif _gc_span:
+        _gc_span.pop().end()
+
+
+gc.callbacks.append(_on_gc)
 
 
 def make_scheduler(*, closed: int, ready: int, record: int,

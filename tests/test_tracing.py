@@ -3,7 +3,7 @@ layer (ISSUE 13): span lifecycle/nesting, exemplar-ring bounds and
 threshold selection, orphan detection after serving churn with
 preemptions, chrome-trace merge shape, debug-server endpoints, SLO
 burn-rate math against a hand-computed window, JsonlSink rotation,
-flight-recorder signal dumps, and goodput attribution."""
+and flight-recorder signal dumps."""
 import json
 import os
 import signal
@@ -601,64 +601,3 @@ class TestSignalDump:
         assert any("MainThread" in k for k in stacks)
         assert any("test_thread_stacks_surface" in v
                    for v in stacks.values())
-
-
-# ---------------------------------------------------------------------------
-# goodput attribution
-# ---------------------------------------------------------------------------
-
-class TestGoodput:
-    def test_breakdown_folds_gauges(self):
-        reg = obs.MetricsRegistry()
-        for _ in range(4):
-            reg.histogram("input.stall_ms").observe(2.0)
-            reg.histogram("input.h2d_ms").observe(1.0)
-        reg.histogram("checkpoint.blocked_ms").observe(40.0)
-        reg.gauge("pipeline.bubble_fraction").set(0.1)
-        reg.gauge("comm.grad_scatter_bytes_per_step").set(1e6)
-        gp = obs.goodput_breakdown(step_ms=100.0, steps=4,
-                                   registry=reg)
-        assert gp["step_ms"] == 100.0
-        assert gp["input_stall_ms"] == 2.0
-        assert gp["checkpoint_block_ms"] == 10.0     # 40 / 4 steps
-        assert gp["pipeline_bubble_ms"] == pytest.approx(10.0)
-        f = gp["fracs"]
-        assert f["input_stall"] == pytest.approx(0.02)
-        assert f["checkpoint_block"] == pytest.approx(0.1)
-        assert f["pipeline_bubble"] == pytest.approx(0.1)
-        assert gp["goodput_frac"] == pytest.approx(1 - 0.22)
-        info = gp["informational"]
-        assert info["h2d_ms_overlapped"] == 1.0
-        assert info["comm_bytes"]["grad_scatter_bytes_per_step"] == 1e6
-        # published as goodput.* gauges on the same registry
-        assert reg.gauge("goodput.goodput_frac").value \
-            == gp["goodput_frac"]
-        assert reg.gauge("goodput.input_stall_frac").value \
-            == pytest.approx(0.02)
-
-    def test_breakdown_with_no_producers(self):
-        gp = obs.goodput_breakdown(step_ms=50.0,
-                                   registry=obs.MetricsRegistry())
-        assert gp["goodput_frac"] == 1.0
-        assert gp["fracs"] == {}
-
-    def test_baseline_excludes_costs_from_prior_runs(self):
-        # a primary bench run / earlier lane in the same process must
-        # not charge ITS checkpoint blocking or a stale pipeline gauge
-        # to a later run's measured window
-        reg = obs.MetricsRegistry()
-        reg.histogram("checkpoint.blocked_ms").observe(40.0)
-        reg.gauge("pipeline.bubble_fraction").set(0.1)
-        base = obs.goodput_baseline(registry=reg)
-        gp = obs.goodput_breakdown(step_ms=100.0, steps=4,
-                                   registry=reg, baseline=base)
-        assert "checkpoint_block_ms" not in gp
-        assert "pipeline_bubble_ms" not in gp
-        assert gp["goodput_frac"] == 1.0
-        # costs accrued INSIDE the window still attribute
-        reg.histogram("checkpoint.blocked_ms").observe(20.0)
-        reg.gauge("pipeline.bubble_fraction").set(0.2)
-        gp2 = obs.goodput_breakdown(step_ms=100.0, steps=4,
-                                    registry=reg, baseline=base)
-        assert gp2["checkpoint_block_ms"] == pytest.approx(5.0)
-        assert gp2["pipeline_bubble_ms"] == pytest.approx(20.0)
