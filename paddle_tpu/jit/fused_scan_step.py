@@ -802,8 +802,8 @@ class FusedScanTrainStep:
                                 if found is not None:
                                     d_upd = jnp.where(
                                         found, jnp.zeros_like(d_upd), d_upd)
-                                p_sq = p_sq + jnp.sum(jnp.square(pv32))
-                                u_sq = u_sq + jnp.sum(jnp.square(d_upd))
+                                ps_j = jnp.sum(jnp.square(pv32))
+                                us_j = jnp.sum(jnp.square(d_upd))
                             out_p = out.astype(P[j].dtype)
                             mn_c = mn.astype(M[j].dtype)
                             vn_c = vn.astype(V[j].dtype)
@@ -815,6 +815,38 @@ class FusedScanTrainStep:
                                 vn_c = jnp.where(found, v_j, vn_c)
                                 if mw_j is not None:
                                     out = jnp.where(found, mw_j, out)
+                            # A reader of a carried stack's OLD slice
+                            # that also needs this layer's gradient runs
+                            # after the slot write that makes the
+                            # gradient (XLA fuses that write into the
+                            # gradient's matmul), so XLA protects the
+                            # stack with a whole-stack copy in and a copy
+                            # out, every iteration. Two such readers
+                            # exist: the monitor's sums (of `pv`'s
+                            # stack), and, with masters, the second
+                            # parameter write re-deriving Adam from the
+                            # old moments. Leaving ONE barrier with the
+                            # new slot values orders every read before
+                            # every write: the sums fuse into the matmul
+                            # and the stacks are updated in place
+                            # (DECISIONS §21; tests/test_fused_scan_step
+                            # .py reads the compiled step for it).
+                            if mw_j is not None:
+                                new = (out_p, mn_c, vn_c, out)
+                                if nm:
+                                    new, ps_j, us_j = \
+                                        lax.optimization_barrier(
+                                            (new, ps_j, us_j))
+                                else:
+                                    new = lax.optimization_barrier(new)
+                                out_p, mn_c, vn_c, out = new
+                            elif nm:
+                                out_p, ps_j, us_j = \
+                                    lax.optimization_barrier(
+                                        (out_p, ps_j, us_j))
+                            if nm:
+                                p_sq = p_sq + ps_j
+                                u_sq = u_sq + us_j
                             nP.append(lax.dynamic_update_index_in_dim(
                                 P[j], out_p, i, 0))
                             nM.append(lax.dynamic_update_index_in_dim(

@@ -259,3 +259,229 @@ def test_layer_chunk_must_divide():
     opt = popt.AdamW(learning_rate=1e-3, parameters=model.parameters())
     with pytest.raises(ValueError, match="divide"):
         FusedScanTrainStep(model, opt, layer_chunk=2)  # 3 layers
+
+
+# -- the compiled update scan: stacks updated in place (ISSUE 25) ---------
+#
+# A reader of a carried stack's old slice that runs after the slot write
+# makes XLA copy the WHOLE stack in and out of the backward while body,
+# every layer (705 ms of a 1,445 ms step at gpt3-1.3b on the v5e, the
+# numerics monitor's sums; docs/DECISIONS.md §21). These tests read the
+# compiled step for it.
+
+_SCAN_VARIANTS = {
+    "monitor": dict(numerics=True),
+    "monitor-off": dict(numerics=False),
+    "monitor+clip": dict(numerics=True, clip=True),
+    "monitor-off+clip": dict(numerics=False, clip=True),
+    "monitor+clip+guard": dict(numerics=True, clip=True, guard=True),
+    "monitor+chunk2": dict(numerics=True, layer_chunk=2),
+    "monitor+masters": dict(numerics=True, masters=True),
+    "monitor-off+masters": dict(numerics=False, masters=True),
+}
+
+
+def _scan_step(cfg_kw, numerics, clip=False, guard=False, layer_chunk=1,
+               masters=False, bf16_compute=False):
+    """A FusedScanTrainStep as the 1.3b cell builds it (fp32-stored
+    parameters, bf16 moments; `masters`: bf16 parameters + fp32
+    masters), built but not run."""
+    import paddle_tpu.nn as nn
+
+    paddle.seed(0)
+    model = GPTForCausalLM(GPTConfig(**cfg_kw, scan_layers=True))
+    if masters:
+        model.bfloat16()
+    opt = popt.AdamW(
+        learning_rate=1e-4, weight_decay=0.01,
+        parameters=model.parameters(), moment_dtype="bfloat16",
+        multi_precision=masters,
+        grad_clip=nn.ClipGradByGlobalNorm(1.0) if clip else None)
+    step = FusedScanTrainStep(
+        model, opt, criterion=GPTPretrainingCriterion(),
+        fused_head=bf16_compute,
+        compute_dtype="bfloat16" if bf16_compute else None,
+        layer_chunk=layer_chunk, numerics=numerics,
+        guard_nonfinite=guard or None)
+    step.ensure_built()
+    return step
+
+
+def _traced(step, batch, sharding=None):
+    """The step's jit traced on the shapes of its own state (on
+    `sharding`'s device when given: a described chip holds no array)."""
+    import jax
+    import jax.numpy as jnp
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    state = jax.tree_util.tree_map(spec, step._extract_state())
+    ids = jax.ShapeDtypeStruct(batch, jnp.int32, sharding=sharding)
+    lr = jax.ShapeDtypeStruct((), jnp.float32, sharding=sharding)
+    return state, step._jitted._jit.trace(state, lr, ids, ids, None)
+
+
+def _while_bodies(text):
+    """{name: instruction lines} of the computations some `while` of the
+    optimized module runs as its body."""
+    import re
+
+    comps, cur = {}, None
+    for line in text.splitlines():
+        if line and not line[0].isspace():
+            m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+            cur = m.group(1) if m else None
+            if cur:
+                comps[cur] = []
+        elif cur:
+            comps[cur].append(line)
+    names = set(re.findall(r"body=%?([\w.\-]+)", text))
+    return {n: comps[n] for n in names if n in comps}
+
+
+def _stack_copies(text, chunks, k):
+    """`copy` instructions of a whole [chunks, k, ...] array at the top
+    level of the while body that updates the stacks, or None when the
+    text shows no such body."""
+    import re
+
+    shape = re.compile(
+        r"= (?:f32|bf16)\[%d,%d,[0-9,]+\]\S* copy\(" % (chunks, k))
+    update = [ls for ls in _while_bodies(text).values()
+              if any("dynamic-update-slice" in x
+                     or "dynamic_update_slice" in x for x in ls)]
+    if not update:
+        return None
+    return [x.strip()[:120] for ls in update for x in ls
+            if shape.search(x)]
+
+
+def _stack_bytes(state):
+    return sum(a.size * a.dtype.itemsize
+               for kind in ("p", "m", "v", "mw")
+               for a in state["s"][kind] if a is not None)
+
+
+@pytest.mark.parametrize("variant", sorted(_SCAN_VARIANTS))
+def test_update_scan_state_is_aliased(variant):
+    """The donated stacks and the step's outputs share buffers: the
+    compiled step aliases at least the stacks' bytes."""
+    kw = _SCAN_VARIANTS[variant]
+    step = _scan_step({**TINY, "num_layers": 4}, **kw)
+    state, traced = _traced(step, (4, 16))
+    compiled = traced.lower().compile()
+    assert "input_output_alias" in compiled.as_text()
+    assert (compiled.memory_analysis().alias_size_in_bytes
+            >= _stack_bytes(state))
+
+
+@pytest.mark.parametrize("variant", sorted(_SCAN_VARIANTS))
+def test_update_scan_orders_reads_before_writes(variant):
+    """What gives the in-place update, as the step is lowered: with the
+    monitor on (or with masters) every trainable stacked leaf's new slot
+    values leave one optimization barrier, with the monitor's two sums,
+    before the slot writes; without either there is none."""
+    kw = _SCAN_VARIANTS[variant]
+    step = _scan_step({**TINY, "num_layers": 4}, **kw)
+    _, traced = _traced(step, (4, 16))
+    n = traced.lower().as_text().count("optimization_barrier")
+    leaves = sum(p.trainable for p in step._s_params)
+    assert n == (leaves if kw["numerics"] or kw.get("masters") else 0)
+
+
+def _cpu_stack_copies(**kw):
+    step = _scan_step({**TINY, "num_layers": 4}, **kw)
+    k = step._layer_chunk
+    _, traced = _traced(step, (4, 16))
+    return _stack_copies(traced.lower().compile().as_text(), 4 // k, k)
+
+
+@pytest.fixture(scope="module")
+def cpu_control_copies():
+    """The stack copies this backend makes with no reader at all."""
+    return _cpu_stack_copies(numerics=False)
+
+
+@pytest.mark.parametrize("variant", sorted(_SCAN_VARIANTS))
+def test_update_scan_copies_no_stack_cpu(cpu_control_copies, variant):
+    """No `copy` of a whole [C, K, ...] parameter or moment stack in the
+    update scan's while body. Skipped where this backend copies the
+    stacks with the monitor off and no clip as well: its text then
+    cannot show what a reader costs (the CPU backend copies every
+    carried stack it updates by slice; the `slow` test below asks the
+    v5e's compiler)."""
+    if cpu_control_copies is None:
+        pytest.skip("no while body updates a stack by slice in this "
+                    "backend's optimized text")
+    if cpu_control_copies:
+        pytest.skip(f"this backend copies {len(cpu_control_copies)} whole "
+                    "stacks in the update scan with the monitor off: its "
+                    "text cannot show the monitor's reader")
+    assert _cpu_stack_copies(**_SCAN_VARIANTS[variant]) == []
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a DESCRIBED v5e (nothing attached: the TPU compiler
+    is installed, so a step compiles for it), with the Pallas wrappers
+    routed to their kernels and the compile cache off (an executable for
+    a described chip cannot be read back)."""
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from paddle_tpu.ops.pallas import routing
+    from paddle_tpu.utils import flags
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    on_tpu = routing.on_tpu
+    selfcheck = flags.get_flag("FLAGS_pallas_alias_selfcheck")
+    cache = jax.config.jax_enable_compilation_cache
+    routing.on_tpu = lambda: True
+    # its eager probe runs the kernel, which no described chip can
+    flags.set_flags({"FLAGS_pallas_alias_selfcheck": False})
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    finally:
+        routing.on_tpu = on_tpu
+        flags.set_flags({"FLAGS_pallas_alias_selfcheck": selfcheck})
+        jax.config.update("jax_enable_compilation_cache", cache)
+        compilation_cache.reset_cache()
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("variant", [
+    "monitor", "monitor-off", "monitor+clip+guard", "monitor+chunk2",
+    "monitor+masters", "monitor-off+masters"])
+def test_update_scan_copies_no_stack_v5e(v5e_chip, variant):
+    """The same assertion where it bites: the step compiled for a v5e at
+    gpt3-1.3b widths (hidden 2048, FFN 8192, 4 layers, 8 x 1024: a stack
+    of 256 MiB cannot sit in VMEM, where a copy is a memory-space move),
+    as the 1.3b cell builds it. The parent of ISSUE 25 compiled to 8
+    whole-stack copies with the monitor on, with and without clip and
+    guard, and to 16 with masters, monitor on or off. ~2 min a case:
+
+        JAX_PLATFORMS=cpu python -m pytest tests/test_fused_scan_step.py \\
+            -m slow -k v5e -p no:cacheprovider
+    """
+    kw = _SCAN_VARIANTS[variant]
+    cfg = dict(vocab_size=8192, hidden_size=2048, num_layers=4,
+               num_attention_heads=32, intermediate_size=8192,
+               max_position_embeddings=1024)
+    # with bf16-stored parameters the fused-CE kernel asks for more VMEM
+    # than a v5e has at this vocabulary: the dense head there
+    step = _scan_step(cfg, bf16_compute=not kw.get("masters"), **kw)
+    k = step._layer_chunk
+    state, traced = _traced(step, (8, 1024), sharding=v5e_chip)
+    compiled = traced.lower(lowering_platforms=("tpu",)).compile()
+    copies = _stack_copies(compiled.as_text(), 4 // k, k)
+    assert copies is not None, "no update scan in the compiled text"
+    assert copies == []
+    assert (compiled.memory_analysis().alias_size_in_bytes
+            >= _stack_bytes(state))

@@ -210,6 +210,106 @@ class TestUpdateRatio:
                 k, got, want)
 
 
+class TestFusedBlockAgainstState:
+    """ISSUE 25 moved how the fused scan step's monitor reads (the sums
+    leave one barrier with the slot's new value; DECISIONS §21), not
+    what it reports: every row of the [chunks + 1, NFIELDS] block
+    against sums taken outside the step — parameter and update
+    sq-norms from the state before and after, gradient sq-norms from
+    the eager tape, the activation count from the batch."""
+
+    @staticmethod
+    def _snapshot(model):
+        return {n: np.asarray(p._data, np.float64)
+                for n, p in model.named_parameters() if p.trainable}
+
+    @staticmethod
+    def _sq(before, after, layer_chunk):
+        """(param_sq, upd_sq): [chunks + 1] each, the outer group last."""
+        chunks = L // layer_chunk
+        p_sq, u_sq = np.zeros(chunks + 1), np.zeros(chunks + 1)
+        for n, b in before.items():
+            d = after[n] - b
+            if "blocks__" in n:
+                for c in range(chunks):
+                    rows = slice(c * layer_chunk, (c + 1) * layer_chunk)
+                    p_sq[c] += (b[rows] ** 2).sum()
+                    u_sq[c] += (d[rows] ** 2).sum()
+            else:
+                p_sq[-1] += (b ** 2).sum()
+                u_sq[-1] += (d ** 2).sum()
+        return p_sq, u_sq
+
+    @staticmethod
+    def _block(step):
+        # the device block of the step just taken, before any flush
+        return onum.NumericsMonitor._fold(step._numerics._pending[-1][1])
+
+    @pytest.mark.parametrize("layer_chunk", [1, 2])
+    @pytest.mark.parametrize("clip", [False, True])
+    @pytest.mark.parametrize("guard", [False, True])
+    def test_rows(self, layer_chunk, clip, guard):
+        ids, labels = _batch()
+        model, opt = _model_opt(clip=clip)
+        step = FusedScanTrainStep(
+            model, opt, criterion=GPTPretrainingCriterion(),
+            layer_chunk=layer_chunk, guard_nonfinite=guard or None)
+        chunks = L // layer_chunk
+        blocks = []
+        for _ in range(2):      # the second step has moments behind it
+            before = self._snapshot(model)
+            step(ids, labels)
+            blocks.append(self._block(step))
+            after = self._snapshot(model)
+            assert blocks[-1].shape == (chunks + 1, onum.NFIELDS)
+            p_sq, u_sq = self._sq(before, after, layer_chunk)
+            np.testing.assert_allclose(blocks[-1][:, onum.F_PARAM_SQ],
+                                       p_sq, rtol=1e-5)
+            np.testing.assert_allclose(blocks[-1][:, onum.F_UPD_SQ],
+                                       u_sq, rtol=1e-4)
+        # the eager reference holds the seeded weights: the first step
+        g_layer, g_outer, _ = _eager_chunk_grad_sq(ids, labels)
+        block = blocks[0]
+        g_sq = np.append(g_layer.reshape(chunks, layer_chunk).sum(1),
+                         g_outer)
+        np.testing.assert_allclose(block[:, onum.F_GRAD_SQ], g_sq,
+                                   rtol=1e-4)
+        n_act = ids.shape[0] * ids.shape[1] * TINY["hidden_size"]
+        assert list(block[:chunks, onum.F_ACT_N]) == [n_act] * chunks
+        assert np.all(block[:chunks, onum.F_ACT_SQ] > 0)
+        for f in (onum.F_GRAD_BAD, onum.F_ACT_ORIGIN,
+                  onum.F_GRAD_ORIGIN):
+            assert not block[:, f].any()
+
+    @pytest.mark.parametrize("layer_chunk", [1, 2])
+    def test_skipped_step_reports_no_update(self, layer_chunk):
+        # guard on, one chunk's gradient poisoned: the step is skipped,
+        # every row's update sq-norm is exactly 0 and the clean chunks'
+        # parameter sq-norms are still the state's
+        ids, labels = _batch()
+        model, opt = _model_opt(clip=True)
+        step = FusedScanTrainStep(
+            model, opt, criterion=GPTPretrainingCriterion(),
+            layer_chunk=layer_chunk, guard_nonfinite=True)
+        step(ids, labels)
+        bad_layer = 2
+        p = step._s_params[0]
+        p._data = p._data.at[bad_layer].set(jnp.float32("nan"))
+        before = self._snapshot(model)
+        step(ids, labels)
+        block = self._block(step)
+        after = self._snapshot(model)
+        for n, b in before.items():
+            assert np.array_equal(b, after[n], equal_nan=True), n
+        assert not block[:, onum.F_UPD_SQ].any()
+        p_sq, _ = self._sq(before, after, layer_chunk)
+        clean = [c for c in range(L // layer_chunk)
+                 if c != bad_layer // layer_chunk] + [-1]
+        np.testing.assert_allclose(block[clean, onum.F_PARAM_SQ],
+                                   p_sq[clean], rtol=1e-5)
+        assert block[:, onum.F_GRAD_BAD].any()
+
+
 class TestNanProvenance:
     """NaN injected into layer k's params -> first_bad_chunk == k on
     every scan path (activation origin: the poisoned layer's output is
