@@ -53,7 +53,11 @@ class ExpertFFN(nn.Layer):
 
 
 class MoELayer(nn.Layer):
-    """Mixture-of-experts over an expert-parallel mesh axis.
+    """Mixture-of-experts over an expert-parallel mesh axis: the CAPACITY
+    path. Top-1 / top-2 gates, [T, E, C] dispatch masks, and a token
+    past an expert's capacity is dropped. For top-k routing that drops
+    nothing (tokens sorted by expert, a grouped product over the experts
+    held, `held_experts` for one chip's share) see `dropless.DroplessMoE`.
 
     Args:
       d_model: token feature size.

@@ -31,3 +31,8 @@ from .llama import (  # noqa: F401
     llama_config,
     llama_sharding_rules,
 )
+from .keye_vl2 import (  # noqa: F401
+    KeyeVL2Config,
+    KeyeVL2Model,
+    KeyeVL2ForCausalLM,
+)

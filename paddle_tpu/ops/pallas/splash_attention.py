@@ -10,6 +10,12 @@ needed by the packed-sequence pretraining path (ROADMAP open item 2):
   ([b, s, 128] for the q side, [b, 8, s] for the kv side — the layout
   jax's own splash kernel uses, Mosaic wants full-lane tiles), and the
   mask is fused into the score tile: no [s, s] mask tensor exists.
+* **Selection mask**: learned sparse attention (an indexer's top-k per
+  query) hands the kernel `selection` int8 [b, sq, sk], ONE set per
+  token for every head: a score survives only where it is non-zero
+  (and causal). Its (block_q, block_k) tile is read beside the score
+  tile and serves a kv head's whole query group; a row whose tile
+  holds no selected key is the segment path's "dead" row.
 * **GQA**: `num_heads` a multiple of `num_kv_heads`. The group dim is
   folded into the q-row axis — q is laid out [b*kvh, grp*sq, d] with a
   kv head's `grp` query heads stacked back to back — so one grid pass
@@ -88,7 +94,7 @@ def kernel_active(q_shape, num_kv_heads, dtype) -> bool:
 # ---------------------------------------------------------------------------
 
 def splash_attention_xla(q, k, v, causal=True, segment_ids=None,
-                         scale=None):
+                         scale=None, selection=None):
     """Reference-parity path: one dense masked attention (GQA via a
     grouped einsum). Rows with no valid key get zero output AND zero
     gradient (the whole-row zeroing below keeps AD away from the
@@ -107,6 +113,8 @@ def splash_attention_xla(q, k, v, causal=True, segment_ids=None,
         seg = segment_ids.astype(jnp.int32)
         segk = seg if sk == sq else seg[:, :sk]
         mask = mask & (seg[:, :, None] == segk[:, None, :])
+    if selection is not None:
+        mask = mask & (selection != 0)
     m5 = mask[:, None, None]                          # [b, 1, 1, sq, sk]
     any_valid = jnp.any(m5, axis=-1, keepdims=True)
     s = jnp.where(m5, s, -jnp.inf)
@@ -132,18 +140,33 @@ def _seg_mask(s, segq_ref, segk_ref, block_k):
     return jnp.where(qfull[:, :block_k] == kseg, s, -jnp.inf)
 
 
+def _sel_mask(s, sel_ref):
+    """Keep the scores of the selected keys: sel tile int8 [bq, bk]."""
+    return jnp.where(sel_ref[0].astype(jnp.int32) != 0, s, -jnp.inf)
+
+
+def _split_refs(refs, n_in, with_seg, with_sel):
+    """(leading operands, segq, segk, sel, the rest) of a kernel's refs:
+    the optional mask operands sit after the `n_in` leading ones."""
+    refs = list(refs)
+    lead, rest = refs[:n_in], refs[n_in:]
+    segq = segk = sel = None
+    if with_seg:
+        segq, segk, rest = rest[0], rest[1], rest[2:]
+    if with_sel:
+        sel, rest = rest[0], rest[1:]
+    return lead, segq, segk, sel, rest
+
+
 # ---------------------------------------------------------------------------
 # forward: online softmax over kv tiles, grid (b*kvh, qi, ki)
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(*refs, scale, causal, block_q, block_k, sq, nqs, with_seg):
-    if with_seg:
-        (q_ref, k_ref, v_ref, segq_ref, segk_ref,
-         o_ref, lse_ref, acc_ref, m_ref, l_ref) = refs
-    else:
-        (q_ref, k_ref, v_ref,
-         o_ref, lse_ref, acc_ref, m_ref, l_ref) = refs
-        segq_ref = segk_ref = None
+def _fwd_kernel(*refs, scale, causal, block_q, block_k, sq, nqs, with_seg,
+                with_sel=False):
+    (q_ref, k_ref, v_ref), segq_ref, segk_ref, sel_ref, rest = _split_refs(
+        refs, 3, with_seg, with_sel)
+    o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     num_k = pl.num_programs(2)
@@ -167,6 +190,8 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, sq, nqs, with_seg):
             s = _causal_mask(s, pos0, ki * block_k, block_q, block_k)
         if with_seg:
             s = _seg_mask(s, segq_ref, segk_ref, block_k)
+        if with_sel:
+            s = _sel_mask(s, sel_ref)
         m_prev = m_ref[...]                              # [bq, LANES]
         l_prev = l_ref[...]
         m_cur = jnp.max(s, axis=1, keepdims=True)        # [bq, 1]
@@ -197,7 +222,7 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, sq, nqs, with_seg):
             l_ref[...] > 0.0, m_ref[...] + jnp.log(l_ref[...]), jnp.inf)
 
 
-def _specs(bh, bq, bk, d, nqs, kvh, with_seg):
+def _specs(bh, bq, bk, d, nqs, kvh, with_seg, with_sel=False):
     """Block specs shared by forward and fused backward. q-side tiles
     (q/do/o/lse) index the [bh, grp*sq, ...] layout by grid dim 1; the
     segment planes recover (batch, seq-position) as (g // kvh,
@@ -213,20 +238,25 @@ def _specs(bh, bq, bk, d, nqs, kvh, with_seg):
             pl.BlockSpec((1, _SUB, bk),
                          lambda g, i, j: (g // kvh, _Z, j)),
         ]
+    if with_sel:
+        seg.append(pl.BlockSpec((1, bq, bk),
+                                lambda g, i, j: (g // kvh, i % nqs, j)))
     return spec_q, spec_k, spec_lse, seg
 
 
 def _fwd(q, k, v, segq, segk, scale, causal, bq, bk, sq, kvh, with_seg,
-         interpret):
+         interpret, sel=None):
     bh, sq_all, d = q.shape
     sk = k.shape[1]
     nqs = sq // bq
+    with_sel = sel is not None
     spec_q, spec_k, spec_lse, seg_specs = _specs(
-        bh, bq, bk, d, nqs, kvh, with_seg)
+        bh, bq, bk, d, nqs, kvh, with_seg, with_sel)
     kern = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
-        sq=sq, nqs=nqs, with_seg=with_seg)
-    args = [q, k, v] + ([segq, segk] if with_seg else [])
+        sq=sq, nqs=nqs, with_seg=with_seg, with_sel=with_sel)
+    args = ([q, k, v] + ([segq, segk] if with_seg else [])
+            + ([sel] if with_sel else []))
     out, lse = routing.pallas_call(
         kern,
         name="splash_fwd",
@@ -254,16 +284,11 @@ def _fwd(q, k, v, segq, segk, scale, causal, bq, bk, sq, kvh, with_seg,
 # ---------------------------------------------------------------------------
 
 def _bwd_kernel(*refs, scale, causal, block_q, block_k, sq, nqs, with_seg,
-                qi_base):
-    if with_seg:
-        (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, segq_ref, segk_ref,
-         dki_ref, dvi_ref, dq_ref, dk_ref, dv_ref,
-         dq_acc, delta_ref) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-         dki_ref, dvi_ref, dq_ref, dk_ref, dv_ref,
-         dq_acc, delta_ref) = refs
-        segq_ref = segk_ref = None
+                qi_base, with_sel=False):
+    lead, segq_ref, segk_ref, sel_ref, rest = _split_refs(
+        refs, 6, with_seg, with_sel)
+    q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref = lead
+    (dki_ref, dvi_ref, dq_ref, dk_ref, dv_ref, dq_acc, delta_ref) = rest
     qi = qi_base + pl.program_id(1)
     ki = pl.program_id(2)
     num_k = pl.num_programs(2)
@@ -297,6 +322,8 @@ def _bwd_kernel(*refs, scale, causal, block_q, block_k, sq, nqs, with_seg,
             s = _causal_mask(s, pos0, ki * block_k, block_q, block_k)
         if with_seg:
             s = _seg_mask(s, segq_ref, segk_ref, block_k)
+        if with_sel:
+            s = _sel_mask(s, sel_ref)
         # lse=+inf on empty rows makes every p an exact 0 (s - lse is
         # -inf even where s itself is -inf) — zero grads fall out free
         p = jnp.exp(s - lse)                             # [bq, bk]
@@ -314,7 +341,7 @@ def _bwd_kernel(*refs, scale, causal, block_q, block_k, sq, nqs, with_seg,
 
 def _bwd_call(q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
               causal, bq, bk, sq, kvh, with_seg, num_q, qi_base,
-              interpret):
+              interpret, sel=None):
     bh, _, d = q.shape
     sk = k.shape[1]
     nqs = sq // bq
@@ -322,14 +349,17 @@ def _bwd_call(q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
     # rowloop passes one q-row per call), so q-side specs index from 0
     # (the rowloop's single segment block hits index 0 either way);
     # qi_base only offsets the causal/segment positions in the kernel.
+    with_sel = sel is not None
     spec_q, spec_k, spec_lse, seg_specs = _specs(
-        bh, bq, bk, d, nqs, kvh, with_seg)
+        bh, bq, bk, d, nqs, kvh, with_seg, with_sel)
     kern = functools.partial(
         _bwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
-        sq=sq, nqs=nqs, with_seg=with_seg, qi_base=qi_base)
-    n_in = 6 + (2 if with_seg else 0)
+        sq=sq, nqs=nqs, with_seg=with_seg, qi_base=qi_base,
+        with_sel=with_sel)
+    n_in = 6 + (2 if with_seg else 0) + (1 if with_sel else 0)
     args = ([q, k, v, do, out, lse]
-            + ([segq, segk] if with_seg else []) + [dk_acc, dv_acc])
+            + ([segq, segk] if with_seg else [])
+            + ([sel] if with_sel else []) + [dk_acc, dv_acc])
     return routing.pallas_call(
         kern,
         name="splash_bwd",
@@ -353,7 +383,8 @@ def _bwd_call(q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
 
 
 def _bwd_rowloop(q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
-                 causal, bq, bk, sq, kvh, with_seg, num_q, interpret):
+                 causal, bq, bk, sq, kvh, with_seg, num_q, interpret,
+                 sel=None):
     """Hazard-free backward: one q-row per pallas call, threading dk/dv
     through as aliased call inputs (each aliased block visited once per
     call) — interpret mode replays revisited aliased blocks from the
@@ -364,14 +395,16 @@ def _bwd_rowloop(q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
     for qi in range(num_q):
         sl = functools.partial(jax.lax.dynamic_slice_in_dim,
                                start_index=qi * bq, slice_size=bq, axis=1)
-        sq_seg = None
+        sq_seg = sel_rows = None
+        pos0 = (qi % nqs) * bq
         if with_seg:
-            pos0 = (qi % nqs) * bq
             sq_seg = jax.lax.dynamic_slice_in_dim(segq, pos0, bq, 1)
+        if sel is not None:
+            sel_rows = jax.lax.dynamic_slice_in_dim(sel, pos0, bq, 1)
         dq_row, dk_acc, dv_acc = _bwd_call(
             sl(q), k, v, sl(do), sl(out), sl(lse), sq_seg, segk,
             dk_acc, dv_acc, scale, causal, bq, bk, sq, kvh, with_seg,
-            1, qi, interpret)
+            1, qi, interpret, sel=sel_rows)
         dq_rows.append(dq_row)
     return jnp.concatenate(dq_rows, axis=1), dk_acc, dv_acc
 
@@ -429,7 +462,7 @@ def _alias_selfcheck(dtype, d, scale, causal, bq, bk, sk):
 
 
 def _bwd(q, k, v, out, lse, do, segq, segk, scale, causal, bq, bk, sq,
-         kvh, with_seg, interpret):
+         kvh, with_seg, interpret, sel=None):
     bh, sq_all, d = q.shape
     sk = k.shape[1]
     num_q = sq_all // bq
@@ -438,7 +471,8 @@ def _bwd(q, k, v, out, lse, do, segq, segk, scale, causal, bq, bk, sq,
     if not interpret and num_q == 1:
         dq, dk_acc, dv_acc = _bwd_call(
             q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
-            causal, bq, bk, sq, kvh, with_seg, num_q, 0, interpret)
+            causal, bq, bk, sq, kvh, with_seg, num_q, 0, interpret,
+            sel=sel)
         return dq, dk_acc.astype(k.dtype), dv_acc.astype(v.dtype)
     # shrink the backward k-block until the aliased-revisit distance is
     # safe (the forward keeps its own block_k: no aliased accumulators)
@@ -450,11 +484,12 @@ def _bwd(q, k, v, out, lse, do, segq, segk, scale, causal, bq, bk, sq,
         _alias_selfcheck(q.dtype, d, scale, causal, bq, bkb, sk)
         dq, dk_acc, dv_acc = _bwd_call(
             q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
-            causal, bq, bkb, sq, kvh, with_seg, num_q, 0, interpret)
+            causal, bq, bkb, sq, kvh, with_seg, num_q, 0, interpret,
+            sel=sel)
     else:
         dq, dk_acc, dv_acc = _bwd_rowloop(
             q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
-            causal, bq, bk, sq, kvh, with_seg, num_q, interpret)
+            causal, bq, bk, sq, kvh, with_seg, num_q, interpret, sel=sel)
     return dq, dk_acc.astype(k.dtype), dv_acc.astype(v.dtype)
 
 
@@ -462,32 +497,33 @@ def _bwd(q, k, v, out, lse, do, segq, segk, scale, causal, bq, bk, sq,
 # custom_vjp wrapper + public entry
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10,
-                                                    11, 12))
-def _splash(q, k, v, segq, segk, scale, causal, bq, bk, sq, kvh,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11,
+                                                    12, 13))
+def _splash(q, k, v, segq, segk, sel, scale, causal, bq, bk, sq, kvh,
             with_seg, interpret):
     out, _ = _fwd(q, k, v, segq, segk, scale, causal, bq, bk, sq, kvh,
-                  with_seg, interpret)
+                  with_seg, interpret, sel=sel)
     return out
 
 
-def _splash_fwd(q, k, v, segq, segk, scale, causal, bq, bk, sq, kvh,
+def _splash_fwd(q, k, v, segq, segk, sel, scale, causal, bq, bk, sq, kvh,
                 with_seg, interpret):
     out, lse = _fwd(q, k, v, segq, segk, scale, causal, bq, bk, sq, kvh,
-                    with_seg, interpret)
-    return out, (q, k, v, segq, segk, out, lse)
+                    with_seg, interpret, sel=sel)
+    return out, (q, k, v, segq, segk, sel, out, lse)
 
 
 def _splash_bwd(scale, causal, bq, bk, sq, kvh, with_seg, interpret,
                 res, do):
-    q, k, v, segq, segk, out, lse = res
+    q, k, v, segq, segk, sel, out, lse = res
     dq, dk, dv = _bwd(q, k, v, out, lse, do, segq, segk, scale, causal,
-                      bq, bk, sq, kvh, with_seg, interpret)
-    zseg = (None if segq is None
-            else np.zeros(segq.shape, dtype=jax.dtypes.float0))
-    zsegk = (None if segk is None
-             else np.zeros(segk.shape, dtype=jax.dtypes.float0))
-    return dq, dk, dv, zseg, zsegk
+                      bq, bk, sq, kvh, with_seg, interpret, sel=sel)
+
+    def no_grad(ints):
+        return (None if ints is None
+                else np.zeros(ints.shape, dtype=jax.dtypes.float0))
+
+    return dq, dk, dv, no_grad(segq), no_grad(segk), no_grad(sel)
 
 
 _splash.defvjp(_splash_fwd, _splash_bwd)
@@ -495,8 +531,10 @@ _splash.defvjp(_splash_fwd, _splash_bwd)
 
 def splash_attention(q, k, v, causal=True, segment_ids=None, scale=None,
                      block_q=None, block_k=None, interpret=None,
-                     use_kernel=None):
+                     use_kernel=None, selection=None):
     """Splash training attention (see module docstring for layouts).
+    `selection`, int8 [batch, sq, sk], keeps for every head of a query
+    only the keys where it is non-zero (and causal, when `causal`).
 
     Routes to the Pallas kernel on TPU when the geometry qualifies
     (`supports`), the XLA dense fallback otherwise. `interpret=True`
@@ -517,7 +555,8 @@ def splash_attention(q, k, v, causal=True, segment_ids=None, scale=None,
         interpret, use_kernel)
     if not use_kernel:
         return splash_attention_xla(q, k, v, causal=causal,
-                                    segment_ids=segment_ids, scale=scale)
+                                    segment_ids=segment_ids, scale=scale,
+                                    selection=selection)
     grp = h // kvh
     if block_q is None:
         block_q = _pick_block(sq)
@@ -538,7 +577,8 @@ def splash_attention(q, k, v, causal=True, segment_ids=None, scale=None,
         kseg = seg if sk == sq else seg[:, :sk]
         segq = jnp.broadcast_to(seg[:, :, None], (b, sq, _LANES))
         segk = jnp.broadcast_to(kseg[:, None, :], (b, _SUB, sk))
-    out2 = _splash(q2, k2, v2, segq, segk, float(scale), bool(causal),
+    sel = None if selection is None else selection.astype(jnp.int8)
+    out2 = _splash(q2, k2, v2, segq, segk, sel, float(scale), bool(causal),
                    int(block_q), int(block_k), int(sq), int(kvh),
                    with_seg, bool(interpret))
     return jnp.transpose(out2.reshape(b, kvh * grp, sq, d), (0, 2, 1, 3))
