@@ -31,6 +31,11 @@ needed by the packed-sequence pretraining path (ROADMAP open item 2):
   HBM via `input_output_aliases` exactly like the flash tiled backward,
   with the same hazard-free per-q-row fallback for interpret mode and
   short revisit distances.
+* **Causal work only** — the tiling rule: a k tile above the diagonal has
+  no body and copies nothing (read-only k-side blocks repeat the q tile's
+  last index; the dK/dV accumulators park on one spare block); where one
+  square tile holds the whole sequence it is cut into two strips and only
+  their squares on the diagonal are compared (`_strips`, `computed_pairs`).
 
 Two paths, one contract (the `paged_attention.py` pattern):
 
@@ -61,7 +66,7 @@ from .flash_attention import (  # noqa: F401  (shared kernel helpers)
 )
 
 __all__ = ["splash_attention", "splash_attention_xla", "supports",
-           "kernel_active"]
+           "kernel_active", "computed_pairs"]
 
 _SUB = 8  # sublane replication of the kv-side segment-id plane
 
@@ -127,22 +132,107 @@ def splash_attention_xla(q, k, v, causal=True, segment_ids=None,
 
 
 # ---------------------------------------------------------------------------
+# causal geometry: which tiles a q-row tile visits, how the tile on the
+# diagonal is cut, which blocks a grid step reads. The kernels, the index
+# maps and `computed_pairs` all take it from here.
+# ---------------------------------------------------------------------------
+
+def _strips(block_q, block_k, causal, num_k):
+    """Strips the tile on the diagonal is cut into (1: one masked tile).
+    A function of the shapes alone, measured on a v5e (PERF.md, PR 28):
+
+    * cut only where a q row's keys all sit in ONE square tile. There the
+      diagonal tile is all the work; in a grid of several k tiles it is 1
+      of up to `num_k` a row, and a strip is one more body of kernel text
+      for every call site to trace and lower before its first step — at
+      seq 8192 a second body cost more set-up than it saved in steps.
+    * two strips (75 % of the square formed). Four form 62.5 % and ran
+      2-3 % slower at head widths 64 and 128 alike, eight 20-30 % slower:
+      the MXU streams a strip's rows past each key tile it loads, and
+      time follows the pairs only while those rows stay in the hundreds.
+    """
+    if not causal or block_q != block_k or num_k != 1:
+        return 1
+    return 2 if block_q % (2 * _LANES) == 0 else 1
+
+
+def _div(a, b):
+    """a // b and, below, a % b for a >= 0, b > 0 (grid indices, tile
+    counts). Traced — in an index map or a kernel — `lax.div` / `lax.rem`
+    are ONE equation where `//` / `%` are a nested jit of a dozen, traced
+    anew by every map of every call site: seconds of a program's set-up."""
+    return a // b if isinstance(a, int) else jax.lax.div(a, np.int32(b))
+
+
+def _rem(a, b):
+    return a % b if isinstance(a, int) else jax.lax.rem(a, np.int32(b))
+
+
+def _last_tile(i, nqs, block_q, block_k):
+    """Last k tile the causal q tile `i` visits (its own last row's)."""
+    return _div(_rem(i, nqs) * block_q + (block_q - 1), block_k)
+
+
+def computed_pairs(sq, block_q=None, block_k=None, causal=True):
+    """Score entries the forward kernel (and the backward at the same
+    blocks) forms for one head over a self-attention sequence of `sq`;
+    the pairs the mask can keep are sq (sq + 1) / 2 under `causal`."""
+    bq = block_q or _pick_block(sq)
+    bk = block_k or _pick_block(sq)
+    nqs, num_k = sq // bq, sq // bk
+    if not causal:
+        return sq * sq
+    n = _strips(bq, bk, causal, num_k)
+    if n > 1:       # the one tile there is
+        return bq * bk * (n + 1) // (2 * n)
+    return sum(_last_tile(i, nqs, bq, bk) + 1 for i in range(nqs)) * bq * bk
+
+
+# ---------------------------------------------------------------------------
 # kernel helpers
 # ---------------------------------------------------------------------------
 
-def _seg_mask(s, segq_ref, segk_ref, block_k):
-    """Apply the segment mask to a score tile. segq tile: [bq, LANES]
-    lane-replicated; segk tile: [SUB, bk] sublane-replicated."""
-    qseg = segq_ref[0]                                   # [bq, LANES]
-    kseg = segk_ref[0][:1]                               # [1, bk]
-    reps = block_k // _LANES
-    qfull = qseg if reps == 1 else pltpu.repeat(qseg, reps, axis=1)
-    return jnp.where(qfull[:, :block_k] == kseg, s, -jnp.inf)
+def _lower_triangle(s):
+    """Keep row >= column of a square piece that sits on the diagonal."""
+    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(row >= col, s, -jnp.inf)
 
 
-def _sel_mask(s, sel_ref):
-    """Keep the scores of the selected keys: sel tile int8 [bq, bk]."""
-    return jnp.where(sel_ref[0].astype(jnp.int32) != 0, s, -jnp.inf)
+def _scores(refs, rows, cols, scale, diag):
+    """Masked fp32 scores of the tile's q rows [r0, r1) x keys [c0, c1)
+    (static bounds). `diag`: None = no causal compare; (pos0, k0) =
+    compare by sequence position, the tile's corner traced; "square" =
+    a strip of the tile on the diagonal, whose upper right corner is the
+    only part compared: a square of the strip's shorter side.
+    segq tile: [bq, LANES] lane-replicated; segk tile: [SUB, bk]
+    sublane-replicated; sel tile: int8 [bq, bk]."""
+    q_ref, k_ref, segq_ref, segk_ref, sel_ref = refs
+    (r0, r1), (c0, c1) = rows, cols
+    nr, nc = r1 - r0, c1 - c0
+    s = _dot(q_ref[0, r0:r1, :], k_ref[0, c0:c1, :],
+             ((1,), (1,))) * scale                       # [nr, nc] fp32
+    if diag == "square":
+        if nr < nc:         # a row strip: its last nr keys
+            s = jnp.concatenate(
+                [s[:, :nc - nr], _lower_triangle(s[:, nc - nr:])], axis=1)
+        elif nc < nr:       # a column strip: its first nc rows
+            s = jnp.concatenate(
+                [_lower_triangle(s[:nc]), s[nc:]], axis=0)
+        else:
+            s = _lower_triangle(s)
+    elif diag is not None:
+        s = _causal_mask(s, diag[0], diag[1], nr, nc)    # a whole tile
+    if segq_ref is not None:
+        qseg = segq_ref[0, r0:r1, :]                     # [nr, LANES]
+        kseg = segk_ref[0, :, c0:c1][:1]                 # [1, nc]
+        reps = nc // _LANES
+        qfull = qseg if reps == 1 else pltpu.repeat(qseg, reps, axis=1)
+        s = jnp.where(qfull[:, :nc] == kseg, s, -jnp.inf)
+    if sel_ref is not None:
+        s = jnp.where(sel_ref[0, r0:r1, c0:c1].astype(jnp.int32) != 0, s,
+                      -jnp.inf)
+    return s
 
 
 def _split_refs(refs, n_in, with_seg, with_sel):
@@ -162,15 +252,67 @@ def _split_refs(refs, n_in, with_seg, with_sel):
 # forward: online softmax over kv tiles, grid (b*kvh, qi, ki)
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(*refs, scale, causal, block_q, block_k, sq, nqs, with_seg,
-                with_sel=False):
+def _fwd_kernel(*refs, scale, causal, block_q, block_k, nqs, num_k, strips,
+                with_seg, with_sel=False):
     (q_ref, k_ref, v_ref), segq_ref, segk_ref, sel_ref, rest = _split_refs(
         refs, 3, with_seg, with_sel)
-    o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
+    o_ref, lse_ref = rest[:2]
+    mask_refs = (q_ref, k_ref, segq_ref, segk_ref, sel_ref)
+    # a piece can be FULLY masked under segments or a selection (unlike
+    # pure causal, where a row's first piece always holds the diagonal),
+    # so m_new may still be -inf: exp(-inf - -inf) would poison the stats
+    # with nan — pin those rows' exponentials to 0 instead
+    may_die = with_seg or with_sel
+
+    def update(state, rows, c1, diag):
+        """Online-softmax step of (m [r, LANES], l [r, LANES], acc [r, d])
+        — None: fresh rows — over q rows `rows` x keys [0, c1)."""
+        s = _scores(mask_refs, rows, (0, c1), scale, diag)
+        v = v_ref[0, :c1, :]
+        m_new = jnp.broadcast_to(jnp.max(s, axis=1, keepdims=True),
+                                 (rows[1] - rows[0], _LANES))
+        corr = None
+        if state is not None:
+            m_prev, l_prev, acc = state
+            m_new = jnp.maximum(m_prev, m_new)
+            corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new[:, :1])                    # [r, c1] fp32
+        if may_die:
+            dead = m_new == -jnp.inf                     # [r, LANES]
+            p = jnp.where(dead[:, :1], 0.0, p)
+            if corr is not None:
+                corr = jnp.where(dead, 0.0, corr)
+        l_new = jnp.broadcast_to(jnp.sum(p, axis=1, keepdims=True),
+                                 m_new.shape)
+        pv = _dot(p.astype(v.dtype), v, ((1,), (0,)))    # [r, d]
+        if corr is not None:
+            l_new = corr * l_prev + l_new
+            pv = acc * corr[:, :1] + pv
+        return m_new, l_new, pv
+
+    def finish(r0, r1, state):
+        m, l, acc = state
+        l1 = l[:, :1]                                    # [r, 1]
+        o_ref[0, r0:r1, :] = (acc / jnp.where(l1 == 0.0, 1.0, l1)
+                              ).astype(o_ref.dtype)
+        # empty rows carry lse=+inf: backward's exp(s - lse) is then an
+        # exact 0 (even for masked s=-inf), no special-casing needed
+        lse_ref[0, r0:r1, :] = jnp.where(l > 0.0, m + jnp.log(l), jnp.inf)
+
+    if strips > 1:
+        # the one tile there is, on the diagonal: strip r of the q rows
+        # meets keys [0, (r + 1) t) and is a whole softmax, so no running
+        # state, no scratch, and nothing above the diagonal is formed
+        t = block_q // strips
+        for r0 in range(0, block_q, t):
+            finish(r0, r0 + t,
+                   update(None, (r0, r0 + t), r0 + t, "square"))
+        return
+
+    acc_ref, m_ref, l_ref = rest[2:]
     qi = pl.program_id(1)
     ki = pl.program_id(2)
-    num_k = pl.num_programs(2)
-    pos0 = (qi % nqs) * block_q     # sequence position of the tile's row 0
+    pos0 = _rem(qi, nqs) * block_q  # sequence position of the tile's row 0
 
     @pl.when(ki == 0)
     def _init():
@@ -178,96 +320,95 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, sq, nqs, with_seg,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    active = (ki * block_k <= pos0 + block_q - 1) if causal else ki >= 0
-
-    @pl.when(active)
     def _step():
-        q = q_ref[0]                                     # [bq, d]
-        k = k_ref[0]                                     # [bk, d]
-        v = v_ref[0]
-        s = _dot(q, k, ((1,), (1,))) * scale             # [bq, bk] fp32
-        if causal:
-            s = _causal_mask(s, pos0, ki * block_k, block_q, block_k)
-        if with_seg:
-            s = _seg_mask(s, segq_ref, segk_ref, block_k)
-        if with_sel:
-            s = _sel_mask(s, sel_ref)
-        m_prev = m_ref[...]                              # [bq, LANES]
-        l_prev = l_ref[...]
-        m_cur = jnp.max(s, axis=1, keepdims=True)        # [bq, 1]
-        m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
-        # a tile can be FULLY masked under segments (unlike pure causal,
-        # where the first visited tile always holds the diagonal), so
-        # m_new may still be -inf: exp(-inf - -inf) would poison the
-        # stats with nan — pin those rows' exponentials to 0 instead
-        dead = m_new == -jnp.inf                         # [bq, LANES]
-        corr = jnp.where(dead, 0.0, jnp.exp(m_prev - m_new))
-        p = jnp.where(dead[:, :1], 0.0,
-                      jnp.exp(s - m_new[:, :1]))         # [bq, bk] fp32
-        l_new = corr * l_prev + jnp.broadcast_to(
-            jnp.sum(p, axis=1, keepdims=True), l_prev.shape)
-        m_ref[...] = m_new
-        l_ref[...] = l_new
-        pv = _dot(p.astype(v.dtype), v, ((1,), (0,)))    # [bq, d]
-        acc_ref[...] = acc_ref[...] * corr[:, :1] + pv
+        m_ref[...], l_ref[...], acc_ref[...] = update(
+            (m_ref[...], l_ref[...], acc_ref[...]), (0, block_q), block_k,
+            (pos0, ki * block_k) if causal else None)
+
+    if causal:      # a tile above the diagonal: no body, no copy (`_specs`)
+        pl.when(ki * block_k <= pos0 + block_q - 1)(_step)
+    else:
+        _step()
 
     @pl.when(ki == num_k - 1)
     def _finish():
-        l = l_ref[...][:, :1]                            # [bq, 1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
-        # empty rows carry lse=+inf: backward's exp(s - lse) is then an
-        # exact 0 (even for masked s=-inf), no special-casing needed
-        lse_ref[0] = jnp.where(
-            l_ref[...] > 0.0, m_ref[...] + jnp.log(l_ref[...]), jnp.inf)
+        finish(0, block_q, (m_ref[...], l_ref[...], acc_ref[...]))
 
 
-def _specs(bh, bq, bk, d, nqs, kvh, with_seg, with_sel=False):
+def _specs(bq, bk, d, nqs, kvh, with_seg, with_sel, causal, num_k,
+           qi_base=0):
     """Block specs shared by forward and fused backward. q-side tiles
     (q/do/o/lse) index the [bh, grp*sq, ...] layout by grid dim 1; the
     segment planes recover (batch, seq-position) as (g // kvh,
-    qi % nqs) — q tiles never straddle a head boundary."""
+    qi % nqs) — q tiles never straddle a head boundary. A step above the
+    diagonal copies nothing: its read-only k-side blocks (k, v, the
+    k-side segment plane, the selection tile) repeat the q tile's last
+    visited index, and the aliased dK/dV accumulators (`spec_acc`), whose
+    blocks pass through every step, park on ONE spare block past the keys
+    (index `num_k`; `_acc_zeros`) — held at a visited block instead, the
+    pass-through would write that block's stale input over its sum."""
+    def last(i):
+        return _last_tile(qi_base + i, nqs, bq, bk)
+
+    def kv(i, j):
+        return jax.lax.min(j, last(i)) if causal else j
+
+    def acc(i, j):
+        if not causal:
+            return j
+        return jax.lax.select(jax.lax.gt(j, last(i)), np.int32(num_k), j)
+
     spec_q = pl.BlockSpec((1, bq, d), lambda g, i, j: (g, i, _Z))
-    spec_k = pl.BlockSpec((1, bk, d), lambda g, i, j: (g, j, _Z))
+    spec_k = pl.BlockSpec((1, bk, d), lambda g, i, j: (g, kv(i, j), _Z))
+    spec_acc = pl.BlockSpec((1, bk, d), lambda g, i, j: (g, acc(i, j), _Z))
     spec_lse = pl.BlockSpec((1, bq, _LANES), lambda g, i, j: (g, i, _Z))
     seg = []
     if with_seg:
         seg = [
             pl.BlockSpec((1, bq, _LANES),
-                         lambda g, i, j: (g // kvh, i % nqs, _Z)),
+                         lambda g, i, j: (_div(g, kvh), _rem(i, nqs), _Z)),
             pl.BlockSpec((1, _SUB, bk),
-                         lambda g, i, j: (g // kvh, _Z, j)),
+                         lambda g, i, j: (_div(g, kvh), _Z, kv(i, j))),
         ]
     if with_sel:
-        seg.append(pl.BlockSpec((1, bq, bk),
-                                lambda g, i, j: (g // kvh, i % nqs, j)))
-    return spec_q, spec_k, spec_lse, seg
+        seg.append(pl.BlockSpec(
+            (1, bq, bk),
+            lambda g, i, j: (_div(g, kvh), _rem(i, nqs), kv(i, j))))
+    return spec_q, spec_k, spec_acc, spec_lse, seg
+
+
+def _acc_zeros(bh, sk, bk, d, causal):
+    """A fresh fp32 dK or dV accumulator: the keys' rows and, under
+    `causal`, the spare block that steps above the diagonal park on."""
+    return jnp.zeros((bh, sk + (bk if causal else 0), d), jnp.float32)
 
 
 def _fwd(q, k, v, segq, segk, scale, causal, bq, bk, sq, kvh, with_seg,
          interpret, sel=None):
     bh, sq_all, d = q.shape
     sk = k.shape[1]
-    nqs = sq // bq
+    nqs, num_k = sq // bq, sk // bk
     with_sel = sel is not None
-    spec_q, spec_k, spec_lse, seg_specs = _specs(
-        bh, bq, bk, d, nqs, kvh, with_seg, with_sel)
+    spec_q, spec_k, _, spec_lse, seg_specs = _specs(
+        bq, bk, d, nqs, kvh, with_seg, with_sel, causal, num_k)
+    strips = _strips(bq, bk, causal, num_k)
     kern = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
-        sq=sq, nqs=nqs, with_seg=with_seg, with_sel=with_sel)
+        nqs=nqs, num_k=num_k, strips=strips, with_seg=with_seg,
+        with_sel=with_sel)
     args = ([q, k, v] + ([segq, segk] if with_seg else [])
             + ([sel] if with_sel else []))
     out, lse = routing.pallas_call(
         kern,
         name="splash_fwd",
-        grid=(bh, sq_all // bq, sk // bk),
+        grid=(bh, sq_all // bq, num_k),
         in_specs=[spec_q, spec_k, spec_k] + seg_specs,
         out_specs=[spec_q, spec_lse],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq_all, d), q.dtype),
             jax.ShapeDtypeStruct((bh, sq_all, _LANES), jnp.float32),
         ],
-        scratch_shapes=[
+        scratch_shapes=[] if strips > 1 else [
             pltpu.VMEM((bq, d), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
@@ -283,56 +424,88 @@ def _fwd(q, k, v, segq, segk, scale, causal, bq, bk, sq, kvh, with_seg,
 # with segment masking and mod-sq causal positions folded in
 # ---------------------------------------------------------------------------
 
-def _bwd_kernel(*refs, scale, causal, block_q, block_k, sq, nqs, with_seg,
-                qi_base, with_sel=False):
+def _bwd_kernel(*refs, scale, causal, block_q, block_k, nqs, num_k, strips,
+                with_seg, qi_base, once, with_sel=False):
     lead, segq_ref, segk_ref, sel_ref, rest = _split_refs(
         refs, 6, with_seg, with_sel)
     q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref = lead
-    (dki_ref, dvi_ref, dq_ref, dk_ref, dv_ref, dq_acc, delta_ref) = rest
+    if once:
+        # every k block is met by this one grid step alone (one q tile in
+        # all): dK/dV leave as they are formed, in the operands' dtype —
+        # no fp32 planes of zeros in, none out, no cast after
+        dq_ref, dk_ref, dv_ref, dq_acc, delta_ref = rest
+    else:
+        dki_ref, dvi_ref, dq_ref, dk_ref, dv_ref, dq_acc, delta_ref = rest
+        # pass the accumulators through unconditionally: a block's sum
+        # so far, or the spare block a step above the diagonal parks on
+        dk_ref[0] = dki_ref[0]
+        dv_ref[0] = dvi_ref[0]
+    mask_refs = (q_ref, k_ref, segq_ref, segk_ref, sel_ref)
     qi = qi_base + pl.program_id(1)
     ki = pl.program_id(2)
-    num_k = pl.num_programs(2)
-    pos0 = (qi % nqs) * block_q
+    pos0 = _rem(qi, nqs) * block_q
 
-    @pl.when(ki == 0)
-    def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
+    def gradients(rows, cols, diag):
+        """(dq [r, d], dk [c, d], dv [c, d]) fp32 of q rows x keys."""
+        (r0, r1), (c0, c1) = rows, cols
+        q = q_ref[0, r0:r1, :]
+        do = do_ref[0, r0:r1, :]
+        k = k_ref[0, c0:c1, :]
+        lse = lse_ref[0, r0:r1, :][:, :1]                # [r, 1]
+        delta = delta_ref[r0:r1, :][:, :1]
+        s = _scores(mask_refs, rows, cols, scale, diag)
+        # lse=+inf on empty rows makes every p an exact 0 (s - lse is
+        # -inf even where s itself is -inf) — zero grads fall out free
+        p = jnp.exp(s - lse)                             # [r, c]
+        dv = _dot(p.astype(do.dtype), do, ((0,), (0,)))  # [c, d]
+        dp = _dot(do, v_ref[0, c0:c1, :], ((1,), (1,)))  # [r, c] fp32
+        ds = (p * (dp - delta) * scale).astype(q.dtype)
+        dk = _dot(ds, q, ((0,), (0,)))                   # [c, d]
+        return _dot(ds, k, ((1,), (0,))), dk, dv
+
+    def _delta():
         do = do_ref[0].astype(jnp.float32)
         o = o_ref[0].astype(jnp.float32)
         delta_ref[...] = jnp.broadcast_to(
             jnp.sum(do * o, axis=-1, keepdims=True), delta_ref.shape)
 
-    active = (ki * block_k <= pos0 + block_q - 1) if causal else ki >= 0
+    if strips > 1:
+        # the one tile there is, on the diagonal (so `once`): strip c of
+        # the keys meets the q rows from c t on; its dK/dV are whole, and
+        # dQ gathers over the strips, the first of which has every row
+        _delta()
+        t = block_k // strips
+        for c0 in range(0, block_k, t):
+            dq, dk, dv = gradients((c0, block_q), (c0, c0 + t), "square")
+            dk_ref[0, c0:c0 + t, :] = dk.astype(dk_ref.dtype)
+            dv_ref[0, c0:c0 + t, :] = dv.astype(dv_ref.dtype)
+            if c0:
+                dq_acc[c0:, :] += dq
+            else:
+                dq_acc[...] = dq
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+        return
 
-    # pass the accumulators through unconditionally (skipped causal
-    # blocks must still round-trip their current value)
-    dk_ref[0] = dki_ref[0]
-    dv_ref[0] = dvi_ref[0]
+    @pl.when(ki == 0)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        _delta()
 
-    @pl.when(active)
     def _step():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, :1]                          # [bq, 1]
-        delta = delta_ref[...][:, :1]
-        s = _dot(q, k, ((1,), (1,))) * scale             # [bq, bk] fp32
-        if causal:
-            s = _causal_mask(s, pos0, ki * block_k, block_q, block_k)
-        if with_seg:
-            s = _seg_mask(s, segq_ref, segk_ref, block_k)
-        if with_sel:
-            s = _sel_mask(s, sel_ref)
-        # lse=+inf on empty rows makes every p an exact 0 (s - lse is
-        # -inf even where s itself is -inf) — zero grads fall out free
-        p = jnp.exp(s - lse)                             # [bq, bk]
-        pc = p.astype(do.dtype)
-        dv_ref[0] += _dot(pc, do, ((0,), (0,)))          # [bk, d]
-        dp = _dot(do, v, ((1,), (1,)))                   # [bq, bk] fp32
-        ds = (p * (dp - delta) * scale).astype(q.dtype)
-        dk_ref[0] += _dot(ds, q, ((0,), (0,)))           # [bk, d]
-        dq_acc[...] += _dot(ds, k, ((1,), (0,)))         # [bq, d]
+        dq, dk, dv = gradients((0, block_q), (0, block_k),
+                               (pos0, ki * block_k) if causal else None)
+        if once:
+            dk_ref[0] = dk.astype(dk_ref.dtype)
+            dv_ref[0] = dv.astype(dv_ref.dtype)
+        else:
+            dv_ref[0] += dv
+            dk_ref[0] += dk
+        dq_acc[...] += dq
+
+    if causal:
+        pl.when(ki * block_k <= pos0 + block_q - 1)(_step)
+    else:
+        _step()
 
     @pl.when(ki == num_k - 1)
     def _finish():
@@ -344,40 +517,48 @@ def _bwd_call(q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
               interpret, sel=None):
     bh, _, d = q.shape
     sk = k.shape[1]
-    nqs = sq // bq
+    nqs, num_k = sq // bq, sk // bk
     # q-side operands arrive pre-sliced to the processed rows (the
     # rowloop passes one q-row per call), so q-side specs index from 0
     # (the rowloop's single segment block hits index 0 either way);
-    # qi_base only offsets the causal/segment positions in the kernel.
+    # qi_base offsets the causal/segment positions in the kernel and the
+    # k-side index maps' hold.
+    # `dk_acc` None: the call's one q tile is every k block's only visit,
+    # so dK/dV have nothing to add to and leave in k's dtype (`once`).
+    once = dk_acc is None
     with_sel = sel is not None
-    spec_q, spec_k, spec_lse, seg_specs = _specs(
-        bh, bq, bk, d, nqs, kvh, with_seg, with_sel)
+    spec_q, spec_k, spec_acc, spec_lse, seg_specs = _specs(
+        bq, bk, d, nqs, kvh, with_seg, with_sel, causal, num_k, qi_base)
     kern = functools.partial(
         _bwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
-        sq=sq, nqs=nqs, with_seg=with_seg, qi_base=qi_base,
-        with_sel=with_sel)
+        nqs=nqs, num_k=num_k,
+        strips=_strips(bq, bk, causal, num_k) if once else 1,
+        with_seg=with_seg, qi_base=qi_base, once=once, with_sel=with_sel)
     n_in = 6 + (2 if with_seg else 0) + (1 if with_sel else 0)
     args = ([q, k, v, do, out, lse]
             + ([segq, segk] if with_seg else [])
-            + ([sel] if with_sel else []) + [dk_acc, dv_acc])
+            + ([sel] if with_sel else [])
+            + ([] if once else [dk_acc, dv_acc]))
+    acc_dtype = k.dtype if once else jnp.float32
+    acc_rows = sk if once else dk_acc.shape[1]
     return routing.pallas_call(
         kern,
         name="splash_bwd",
-        grid=(bh, num_q, sk // bk),
+        grid=(bh, num_q, num_k),
         in_specs=[spec_q, spec_k, spec_k, spec_q, spec_q, spec_lse]
-        + seg_specs + [spec_k, spec_k],
-        out_specs=[spec_q, spec_k, spec_k],
+        + seg_specs + ([] if once else [spec_acc, spec_acc]),
+        out_specs=[spec_q, spec_acc, spec_acc],
         out_shape=[
             jax.ShapeDtypeStruct((bh, num_q * bq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
+            jax.ShapeDtypeStruct((bh, acc_rows, d), acc_dtype),
+            jax.ShapeDtypeStruct((bh, acc_rows, d), acc_dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
         ],
         # dk/dv accumulators alias their inputs (last two -> outs 1, 2)
-        input_output_aliases={n_in: 1, n_in + 1: 2},
+        input_output_aliases={} if once else {n_in: 1, n_in + 1: 2},
         interpret=interpret,
     )(*args)
 
@@ -434,15 +615,15 @@ def _alias_selfcheck(dtype, d, scale, causal, bq, bk, sk):
         k, v = mk(sk), mk(sk)
         out, lse = _fwd(q, k, v, None, None, scale, causal, bq, bk, sq,
                         1, False, False)
-        z = lambda: jnp.zeros((1, sk, d), jnp.float32)  # noqa: E731
+        z = lambda: _acc_zeros(1, sk, bk, d, causal)  # noqa: E731
         f = _bwd_call(q, k, v, do, out, lse, None, None, z(), z(),
                       scale, causal, bq, bk, sq, 1, False,
                       sq // bq, 0, False)
         r = _bwd_rowloop(q, k, v, do, out, lse, None, None, z(), z(),
                          scale, causal, bq, bk, sq, 1, False,
                          sq // bq, False)
-        return {n: float(jnp.max(jnp.abs(a.astype(jnp.float32)
-                                         - b.astype(jnp.float32))))
+        return {n: float(jnp.max(jnp.abs(a[:, :sk].astype(jnp.float32)
+                                         - b[:, :sk].astype(jnp.float32))))
                 for n, a, b in zip(("dq", "dk", "dv"), f, r)}
 
     # run eagerly even when tracing (fresh thread has no trace context)
@@ -466,31 +647,34 @@ def _bwd(q, k, v, out, lse, do, segq, segk, scale, causal, bq, bk, sq,
     bh, sq_all, d = q.shape
     sk = k.shape[1]
     num_q = sq_all // bq
-    dk_acc = jnp.zeros((bh, sk, d), jnp.float32)
-    dv_acc = jnp.zeros((bh, sk, d), jnp.float32)
-    if not interpret and num_q == 1:
-        dq, dk_acc, dv_acc = _bwd_call(
-            q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
+    if num_q == 1:
+        return _bwd_call(
+            q, k, v, do, out, lse, segq, segk, None, None, scale,
             causal, bq, bk, sq, kvh, with_seg, num_q, 0, interpret,
             sel=sel)
-        return dq, dk_acc.astype(k.dtype), dv_acc.astype(v.dtype)
     # shrink the backward k-block until the aliased-revisit distance is
     # safe (the forward keeps its own block_k: no aliased accumulators)
     bkb = bk
     while sk // bkb < _REVISIT_MIN and bkb % 2 == 0 \
             and (bkb // 2) % _LANES == 0 and sk % (bkb // 2) == 0:
         bkb //= 2
-    if not interpret and sk // bkb >= _REVISIT_MIN:
-        _alias_selfcheck(q.dtype, d, scale, causal, bq, bkb, sk)
+    fused = not interpret and sk // bkb >= _REVISIT_MIN
+    if fused:
+        bk = bkb
+    dk_acc = _acc_zeros(bh, sk, bk, d, causal)
+    dv_acc = _acc_zeros(bh, sk, bk, d, causal)
+    if fused:
+        _alias_selfcheck(q.dtype, d, scale, causal, bq, bk, sk)
         dq, dk_acc, dv_acc = _bwd_call(
             q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
-            causal, bq, bkb, sq, kvh, with_seg, num_q, 0, interpret,
+            causal, bq, bk, sq, kvh, with_seg, num_q, 0, interpret,
             sel=sel)
     else:
         dq, dk_acc, dv_acc = _bwd_rowloop(
             q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
             causal, bq, bk, sq, kvh, with_seg, num_q, interpret, sel=sel)
-    return dq, dk_acc.astype(k.dtype), dv_acc.astype(v.dtype)
+    return (dq, dk_acc[:, :sk].astype(k.dtype),
+            dv_acc[:, :sk].astype(v.dtype))
 
 
 # ---------------------------------------------------------------------------

@@ -151,6 +151,230 @@ class TestSplashKernel:
         assert not sa.supports((2, 256, 8, 64), 8, jnp.int8)
 
 
+# -- causal-only work (PR 28): strips on the diagonal tile, no compare below
+# it, nothing fetched above it -------------------------------------------
+
+# (seq, block_q, block_k): which tiling of the kernels a case runs
+GEOMETRY = {
+    # one square tile: the strips, forward and (group 1) backward
+    "one_tile": (256, 256, 256),
+    # a grid of tiles: below / on / above the diagonal, k-side maps held
+    "4x4_tiles": (512, 128, 128),
+    # block_q = 2 block_k, what `_REVISIT_MIN` halves a backward to: the
+    # diagonal crosses two tiles a q row, masked by position
+    "bk_halved": (512, 256, 128),
+    # several q tiles against one k tile: the per-row backward calls
+    "row_loop": (256, 128, 256),
+}
+
+
+def _selection(b, s, keep, seed):
+    """int8 [b, s, s]: each key kept with probability `keep`, and the
+    diagonal, so that no causal row is empty."""
+    rng = np.random.default_rng(seed)
+    sel = (rng.random((b, s, s)) < keep) | np.eye(s, dtype=bool)[None]
+    return sel.astype(np.int8)
+
+
+def _grads(fn, q, k, v):
+    def run(q, k, v):
+        out, vjp = jax.vjp(fn, q, k, v)
+        return (out,) + tuple(vjp(jnp.cos(out)))
+
+    # one program: an interpreted backward is a kernel call a q tile
+    return jax.jit(run)(q, k, v)
+
+
+def _parity_cases():
+    """masks x group x head_dim x geometry. A group of 8 over a grid of
+    tiles is 16-32 interpreted backward calls (~10 s): there the two
+    masks go together, and apart only on the one-tile geometry."""
+    import itertools
+
+    return [c for c in itertools.product(
+        ["plain", "segments", "selection", "both"], [1, 8], [64, 128],
+        list(GEOMETRY))
+        if c[1] == 1 or c[3] == "one_tile" or c[0] == "both"]
+
+
+def _assert_parity(q, k, v, atol=(3e-5, 5e-4), **kw):
+    """Forward, dQ, dK, dV of the interpreted kernel against the dense
+    XLA path with the same masks."""
+    blocks = {n: kw.pop(n) for n in ("block_q", "block_k") if n in kw}
+    got = _grads(lambda q, k, v: sa.splash_attention(
+        q, k, v, interpret=True, **blocks, **kw), q, k, v)
+    want = _grads(lambda q, k, v: sa.splash_attention_xla(
+        q, k, v, **kw), q, k, v)
+    for name, a, b, tol in zip(("out", "dq", "dk", "dv"), got, want,
+                               (atol[0],) + (atol[1],) * 3):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        assert float(jnp.max(jnp.abs(a - b))) < tol, name
+    return got
+
+
+class TestCausalOnlyWork:
+    @pytest.mark.parametrize("masks,group,d,geometry", _parity_cases())
+    def test_matches_dense(self, masks, group, d, geometry):
+        s, bq, bk = GEOMETRY[geometry]
+        q, k, v = _rand(1, s, group, 1, d, seed=11)
+        kw = {}
+        if masks in ("segments", "both"):
+            kw["segment_ids"] = _segments(1, s, 3, seed=12)
+        if masks in ("selection", "both"):
+            kw["selection"] = jnp.asarray(_selection(1, s, 0.3, seed=13))
+        _assert_parity(q, k, v, causal=True, block_q=bq, block_k=bk, **kw)
+
+    @pytest.mark.parametrize("geometry", ["one_tile", "4x4_tiles"])
+    def test_empty_row_is_zero_out_and_zero_grad(self, geometry):
+        s, bq, bk = GEOMETRY[geometry]
+        q, k, v = _rand(1, s, 2, 1, 64, seed=14)
+        sel = _selection(1, s, 0.3, seed=15)
+        sel[:, 5, :] = 0
+        sel[:, s - 1, :] = 0
+        out, dq, _, _ = _assert_parity(
+            q, k, v, causal=True, selection=jnp.asarray(sel), block_q=bq,
+            block_k=bk)
+        for row in (5, s - 1):
+            assert float(jnp.max(jnp.abs(out[:, row]))) == 0.0
+            assert float(jnp.max(jnp.abs(dq[:, row]))) == 0.0
+
+    @pytest.mark.parametrize("group", [1, 2])
+    def test_selection_empties_a_diagonal_sub_tile(self, group):
+        """Rows 128..255 of one 256-tile keep no key of their own strip's
+        square on the diagonal: what is left of their softmax sits in the
+        part of the strip that takes no causal compare."""
+        s = 256
+        assert sa._strips(s, s, True, 1) == 2
+        q, k, v = _rand(1, s, group, 1, 64, seed=16)
+        sel = _selection(1, s, 0.3, seed=17)
+        sel[:, 128:, 128:] = 0
+        _assert_parity(q, k, v, causal=True, selection=jnp.asarray(sel))
+        sel[:, :128, :128] = 0         # and the first strip is all empty
+        out = _assert_parity(q, k, v, causal=True,
+                             selection=jnp.asarray(sel))[0]
+        assert float(jnp.max(jnp.abs(out[:, :128]))) == 0.0
+
+    def test_bf16_strips(self):
+        q, k, v = _rand(1, 512, 2, 2, 64, dtype=jnp.bfloat16, seed=18)
+        assert sa._strips(512, 512, True, 1) == 2
+        _assert_parity(q, k, v, atol=(2e-2, 6e-2), causal=True)
+
+    # the non-causal kernel is the one every earlier tree compiled: these
+    # are sha256 of its (out, dq, dk, dv) bytes on this input, taken from
+    # the tree before PR 28 (interpret mode, this container's CPU)
+    NONCAUSAL_BITS = {"0.9.0": {
+        "plain": "2041cdf2b88b4d73e63cb525865a523abb98c85599cfe38a2969703276ae47bf",
+        "segments": "b677b6a782c9bbd07f42e051494f7a72f02a83a6ad552eb085b692db1f65ba7e",
+    }}
+
+    @pytest.mark.parametrize("masks", ["plain", "segments"])
+    def test_noncausal_is_bit_identical_to_the_parent(self, masks):
+        import hashlib
+
+        golden = self.NONCAUSAL_BITS.get(jax.__version__)
+        if golden is None:
+            pytest.skip(f"no parent bits recorded for jax {jax.__version__}")
+        q, k, v = _rand(1, 256, 2, 1, 32, seed=19)
+        kw = ({"segment_ids": _segments(1, 256, 3, seed=20)}
+              if masks == "segments" else {})
+        assert sa._strips(128, 128, False, 2) == 1
+        got = _grads(lambda q, k, v: sa.splash_attention(
+            q, k, v, causal=False, interpret=True, block_q=128,
+            block_k=128, **kw), q, k, v)
+        digest = hashlib.sha256(b"".join(
+            np.asarray(a).tobytes() for a in got)).hexdigest()
+        assert digest == golden[masks]
+
+
+def _eqn_jaxprs(eqn):
+    for value in eqn.params.values():
+        for item in value if isinstance(value, (list, tuple)) else [value]:
+            item = getattr(item, "jaxpr", item)
+            if hasattr(item, "eqns"):
+                yield item
+
+
+def _kernel_equations(fn, *args):
+    """Equations in the body of every pallas_call `fn` traces (nested
+    `pl.when` bodies included), in call order. Nothing is lowered."""
+    def count(jaxpr):
+        return len(jaxpr.eqns) + sum(
+            count(sub) for eqn in jaxpr.eqns for sub in _eqn_jaxprs(eqn))
+
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(count(eqn.params["jaxpr"]))
+            else:
+                for sub in _eqn_jaxprs(eqn):
+                    walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+class TestSetUpBudget:
+    """What refused PR 27: the causal-only kernels were faster in every
+    cell, and their text cost the Keye cell 6.2 s before its first step
+    (each of its 18 call sites traces and lowers the kernel body). The
+    work the kernels form and the size of what they trace are pinned."""
+
+    @pytest.mark.parametrize("seq,ceiling", [(1024, 1.5), (8192, 1.13)])
+    def test_computed_pairs_over_required(self, seq, ceiling):
+        required = seq * (seq + 1) // 2
+        assert sa.computed_pairs(seq, 1024, 1024, causal=False) == seq * seq
+        share = sa.computed_pairs(seq, 1024, 1024) / required
+        assert 1.0 <= share <= ceiling, share
+
+    def test_computed_pairs_by_hand(self):
+        # one 256-tile in two strips: 128 x 128 + 128 x 256
+        assert sa.computed_pairs(256) == 128 * 128 + 128 * 256
+        # 2 x 2 tiles of 128: the two on the diagonal whole, one below
+        assert sa.computed_pairs(256, 128, 128) == 3 * 128 * 128
+        # block_q = 2 block_k: both tiles of a q row are crossed
+        assert sa.computed_pairs(512, 256, 128) == (2 + 4) * 256 * 128
+
+    # (forward, backward) equations of the kernels at the benchmark's
+    # shapes. The tree before PR 28 traced (107, 87) without a selection
+    # and (114, 94) with one at either shape; PR 28 traces (83, 92) and
+    # (111, 106) at seq 1024, two strips, and (82, 77) and (100, 84) at
+    # seq 8192, one body as before. PR 27's walked tiles were refused for
+    # what their text cost the Keye cell's 18 call sites.
+    @pytest.mark.parametrize("shape,selection,ceiling", [
+        ((8, 1024, 32, 32, 64), False, (90, 100)),
+        ((8, 1024, 32, 32, 64), True, (118, 112)),
+        ((4, 8192, 32, 4, 128), False, (88, 82)),
+        ((4, 8192, 32, 4, 128), True, (106, 90)),
+    ])
+    def test_traced_kernel_bodies_stay_small(self, shape, selection,
+                                             ceiling):
+        b, s, h, kvh, d = shape
+        spec = jax.ShapeDtypeStruct
+        args = [spec((b, s, h, d), jnp.bfloat16),
+                spec((b, s, kvh, d), jnp.bfloat16),
+                spec((b, s, kvh, d), jnp.bfloat16)]
+        if selection:
+            args.append(spec((b, s, s), jnp.int8))
+
+        def fwd_and_bwd(q, k, v, *sel):
+            out, vjp = jax.vjp(lambda q, k, v: sa.splash_attention(
+                q, k, v, causal=True, selection=sel[0] if sel else None,
+                use_kernel=True, interpret=False), q, k, v)
+            return vjp(out)
+
+        from paddle_tpu.utils import flags
+        selfcheck = flags.get_flag("FLAGS_pallas_alias_selfcheck")
+        flags.set_flags({"FLAGS_pallas_alias_selfcheck": False})
+        try:
+            fwd, bwd = _kernel_equations(fwd_and_bwd, *args)
+        finally:
+            flags.set_flags({"FLAGS_pallas_alias_selfcheck": selfcheck})
+        assert fwd <= ceiling[0] and bwd <= ceiling[1], (fwd, bwd)
+
+
 class TestFunctionalRouting:
     def test_sdpa_segments_route_to_splash(self):
         import paddle_tpu as paddle
