@@ -256,7 +256,6 @@ def phase_train(z):
     from paddle_tpu.jit import FusedScanTrainStep
     from paddle_tpu.models import GPTPretrainingCriterion
     from paddle_tpu.ops.pallas import routing
-    from paddle_tpu.ops.pallas.training_selftest import forbidden_shapes
 
     paddle.set_device(z.platform)
     t0 = time.perf_counter()
@@ -277,7 +276,7 @@ def phase_train(z):
                   "fused_ce_bwd"} <= set(kernels),
        "splash fwd+bwd and fused-CE fwd+bwd run as Mosaic kernels"
        + (" (tiny: interpreted, not checked)" if z.tiny else ""))
-    bad = forbidden_shapes(hlo, z.batch, z.seq, z.vocab)
+    bad = routing.forbidden_shapes(hlo, z.batch, z.seq, z.vocab)
     ok(z.tiny or not bad,
        f"no [{z.batch * z.seq}, {z.vocab}] logits and no [{z.batch}, "
        f"{z.heads}, {z.seq}, {z.seq}] scores buffer {bad[:3]}"

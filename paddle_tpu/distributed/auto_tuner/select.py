@@ -120,7 +120,7 @@ def layout_name(cand) -> str:
 def record_measured_step(layout, step_ms, n_devices, platform=None,
                          cache_dir=None):
     """Feed one MEASURED per-step wall time back to the planner
-    (ISSUE 17 closed loop): bench lanes and training loops call this so
+    (ISSUE 17 closed loop): training loops call this so
     `pick_layout` can re-rank from live timelines instead of static
     calibration. ``layout`` is a `Candidate` or a `layout_name` string.
     Records are keyed like the backend-calib cache ((platform, n)) and
@@ -224,10 +224,9 @@ def _parse_env_layout(text):
     return out
 
 
-# scan_unroll / layer_chunk for the picked layout. `bench.py --sweep`
-# measures the grid on the chip, but nothing reads a record of it back:
-# a number taken on another machine or an older program never steers the
-# step's shape.
+# scan_unroll / layer_chunk for the picked layout. No record of a sweep
+# is read back: a number taken on another machine or an older program
+# never steers the step's shape.
 _SCAN_KNOBS = {"scan_unroll": 2, "layer_chunk": 1, "source": "default"}
 
 

@@ -328,7 +328,8 @@ def _quantized_sum_traced(axes, nranks, qformat):
     each side (the "two-sided" scales: the scatter leg ships each source
     rank's block scales, the gather leg ships the reduced chunk's), or
     bf16. Accumulation is fp32 on every path, so only the wire format is
-    lossy; the fp32-parity contract is asserted by comm_quant_selftest."""
+    lossy; the fp32-parity contract is asserted by `comm_quant_selftest`
+    (tests/test_comm_bucketed.py)."""
     ax = _axis_arg(axes)
     n = int(nranks)
     if qformat not in ("int8", "bf16"):
@@ -431,7 +432,7 @@ def quantized_psum_scatter_traced(axis, nranks, qformat):
     so the dp×mp/pp/ep hybrid steps' flattened grad scatter gets the
     same wire format as the single-axis path (``nranks`` is the
     flattened product; verified against the exact tuple psum_scatter by
-    ``comm_quant_multiaxis_selftest``)."""
+    ``comm_quant_multiaxis_selftest``, tests/test_sharded_storage.py)."""
     n = int(nranks)
     if isinstance(axis, (list, tuple)):
         axis = tuple(axis) if len(axis) > 1 else axis[0]

@@ -304,7 +304,7 @@ def bucketed_reduce_scatter(tensors, group=None, bucket_mb=None):
 
 
 # ---------------------------------------------------------------------------
-# HLO collective-count probe (tests, bench MULTICHIP lane)
+# HLO collective-count probe (tests/test_comm_bucketed.py)
 # ---------------------------------------------------------------------------
 
 _COLLECTIVE_RE = {
@@ -324,164 +324,3 @@ def count_hlo_collectives(fn, *args):
     txt = jax.jit(fn).lower(*args).compile().as_text()
     return {name: len(rx.findall(txt))
             for name, rx in _COLLECTIVE_RE.items()}
-
-
-# ---------------------------------------------------------------------------
-# host-mesh selftest (bench.py lane; run under JAX_PLATFORMS=cpu)
-# ---------------------------------------------------------------------------
-
-def bucketed_reduce_scatter_parity(n_devices=8, seed=0):
-    """Parity probe on an n-device host mesh: bucketed reduce_scatter ==
-    per-tensor reduce_scatter == the plain fp32 sum, plus the int8
-    compressed all-reduce within tolerance. Returns a dict suitable for
-    the BENCH selftest block."""
-    from . import collective as coll
-    from . import env as denv
-
-    devs = jax.devices("cpu")[:n_devices]
-    if len(devs) < n_devices:
-        return {"check": f"FAIL: {len(devs)} cpu devices < {n_devices} "
-                         "(set --xla_force_host_platform_device_count)"}
-    from jax.sharding import Mesh
-
-    mesh = Mesh(np.asarray(devs), ("sharding",))
-    denv.set_mesh(mesh)
-    group = coll.new_group(axes=["sharding"], mesh=mesh)
-    rng = np.random.default_rng(seed)
-    n = group.nranks
-    shapes = [(64, 16), (16,), (7, 5), (33,), (16, 8)]  # odd shapes too
-    grads = [rng.standard_normal(s).astype(np.float32) for s in shapes]
-
-    bucketed_ts = [Tensor(jnp.asarray(g)) for g in grads]
-    bucketed_reduce_scatter(bucketed_ts, group=group)
-    bitwise_ok, max_rel = True, 0.0
-    for g, bt in zip(grads, bucketed_ts):
-        got = np.asarray(bt._data)
-        if g.size % n == 0:
-            # per-tensor reduce_scatter exists for these: bit-for-bit
-            pp = np.asarray(coll.reduce_scatter(
-                None, Tensor(jnp.asarray(g.reshape(-1))), group=group,
-                axis=0)._data).reshape(g.shape)
-            if not np.array_equal(got, pp):
-                bitwise_ok = False
-        # every shape (odd ones only bucket): value == n replicated copies
-        denom = max(float(np.max(np.abs(g))) * n, 1e-30)
-        max_rel = max(max_rel,
-                      float(np.max(np.abs(got - g * n))) / denom)
-    q = coll.comm_quant_selftest(group=group, qformat="int8")
-    if not (bitwise_ok and max_rel < 1e-6 and q["pass"]):
-        return {"check": f"FAIL: bitwise={bitwise_ok} "
-                         f"fp32_rel={max_rel:.2e} "
-                         f"int8_rel_err={q['rel_err']:.2e}"}
-    return {"check": "pass", "n_devices": n_devices,
-            "int8_rel_err": q["rel_err"]}
-
-
-def _main():
-    """`python -m paddle_tpu.distributed.comm_bucketer [--multichip]` —
-    run the host-mesh parity probe (and, with --multichip, the bucketed
-    vs per-param stage-2 collective-count/walltime comparison) and print
-    one JSON line. The caller is responsible for a cpu-forced env
-    (tools/cpu_env.sh or bench.py's stripped subprocess env)."""
-    import json
-    import sys
-    import time
-
-    out = {"bucketed_reduce_scatter_parity":
-           bucketed_reduce_scatter_parity()}
-    if "--multichip" in sys.argv:
-        import paddle_tpu as paddle
-        import paddle_tpu.nn as nn
-        import paddle_tpu.optimizer as popt
-        from paddle_tpu.distributed import env as denv
-        from paddle_tpu.distributed.sharding import group_sharded_parallel
-        from paddle_tpu.jit import TrainStep
-
-        def stage2_step(bucket_mb):
-            denv.reset()
-            mesh = denv.build_mesh({"sharding": 8})
-            denv.set_mesh(mesh)
-            paddle.seed(0)
-            model = nn.Sequential(nn.Linear(256, 512), nn.GELU(),
-                                  nn.Linear(512, 256), nn.GELU(),
-                                  nn.Linear(256, 128))
-            opt = popt.AdamW(learning_rate=1e-3,
-                             parameters=model.parameters())
-            _flags.set_flags({"FLAGS_comm_bucket_mb": bucket_mb})
-            mw, ow, _ = group_sharded_parallel(model, opt, level="os_g")
-            x = paddle.to_tensor(np.random.default_rng(0).standard_normal(
-                (32, 256)).astype(np.float32))
-            y = paddle.to_tensor(np.random.default_rng(1).standard_normal(
-                (32, 128)).astype(np.float32))
-            x._data = jax.device_put(x._data, NamedSharding(
-                mesh, P("sharding", None)))
-            step = TrainStep(mw, lambda m, a, b:
-                             ((m(a) - b) ** 2).mean(), ow)
-            loss = float(step(x, y))       # compile + step 1
-            t0 = time.perf_counter()
-            for _ in range(5):
-                loss = float(step(x, y))
-            dt = (time.perf_counter() - t0) / 5
-            nb = (mw._bucketer.num_buckets if mw._bucketer is not None
-                  else None)
-            return {"loss": loss, "step_ms": round(dt * 1e3, 2),
-                    "n_buckets": nb}
-
-        def stage2_counts(bucket_mb):
-            """Backward-pass collective counts by HLO inspection: the
-            op-count probe of the acceptance criteria (per-param stage-2
-            emits one reduce-scatter per shardable param; bucketed emits
-            ceil(total_grad_bytes / bucket_size))."""
-            denv.reset()
-            mesh = denv.build_mesh({"sharding": 8})
-            denv.set_mesh(mesh)
-            paddle.seed(0)
-            model = nn.Sequential(nn.Linear(256, 512), nn.GELU(),
-                                  nn.Linear(512, 256), nn.GELU(),
-                                  nn.Linear(256, 128))
-            _flags.set_flags({"FLAGS_comm_bucket_mb": bucket_mb})
-            mw, _, _ = group_sharded_parallel(
-                model, popt.AdamW(learning_rate=1e-3,
-                                  parameters=model.parameters()),
-                level="os_g")
-            x = jax.device_put(
-                jnp.asarray(np.random.default_rng(0).standard_normal(
-                    (32, 256)), jnp.float32),
-                NamedSharding(mesh, P("sharding", None)))
-            y = jnp.asarray(np.random.default_rng(1).standard_normal(
-                (32, 128)), jnp.float32)
-            params = list(model.parameters())
-
-            def f(xd, yd):
-                loss = ((mw(Tensor._wrap(xd))
-                         - Tensor._wrap(yd)) ** 2).mean()
-                loss.backward()
-                mw.apply_collective_grads()
-                gs = [p.grad._data for p in params]
-                return gs
-
-            try:
-                counts = count_hlo_collectives(f, x, y)
-            finally:
-                for p in params:
-                    p.clear_grad()
-            return counts
-
-        try:
-            out["multichip"] = {
-                "n_devices": 8,
-                "bucketed_25mb": stage2_step(25),
-                "per_param": stage2_step(0),
-                "backward_collectives": {
-                    "bucketed_25mb": stage2_counts(25),
-                    "per_param": stage2_counts(0),
-                },
-            }
-        finally:
-            _flags.set_flags({"FLAGS_comm_bucket_mb": 25})
-            denv.reset()
-    print(json.dumps(out))
-
-
-if __name__ == "__main__":
-    _main()

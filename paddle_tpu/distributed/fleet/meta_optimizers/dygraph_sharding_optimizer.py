@@ -92,10 +92,6 @@ class DygraphShardingOptimizer:
                 per, self._mesh, self._axis)
         opt._master_weights.update(
             shard_state_arrays(opt._master_weights, self._mesh, self._axis))
-        # offloaded masters: shard_state_arrays re-homed them into HBM with
-        # a mesh sharding; push them back to pinned host and refresh the
-        # host/device sharding pair the traced update addresses
-        opt._rehome_offloaded_masters()
 
     def step(self):
         if (self._comm_bucketer is not None

@@ -533,7 +533,7 @@ class PagedKVCache:
         # capacity receipt (ISSUE 16/20): bytes per cached token across
         # all layers, K+V, counting the fp32 scale pools honestly —
         # int8 pays head_dim + 4 bytes, int4 head_dim//2 + 4 (packed) —
-        # the "Nx slots at equal HBM" math the bench records
+        # the "Nx slots at equal HBM" math
         per_tok = self.num_layers * 2 * self.num_kv_heads * (
             self.pool_head_dim * self.dtype.itemsize
             + (4 if self.quantized else 0))

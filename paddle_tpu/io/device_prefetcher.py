@@ -18,7 +18,8 @@ ON DEVICE ahead of the consumer:
   released when the consumer takes the batch — a buffer can never be
   rewritten while an in-flight step may still read it;
 - placement is identical for every batch of a stream, so feeding a jitted
-  train step adds ZERO retraces (compile-count probe in the selftest).
+  train step adds ZERO retraces (compile-count probe in
+  tests/test_input_pipeline.py).
 
 Instrumented end to end: per-step `input_stall_ms` (how long `next()`
 blocked waiting for data — ≈0 when the pipeline keeps up) and `h2d_ms`

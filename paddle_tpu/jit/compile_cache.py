@@ -38,10 +38,10 @@ Metrics (process-global registry): `jit.cache.hit` / `jit.cache.miss`
 counters, `jit.cache.deserialize_ms` / `jit.cache.compile_ms`
 histograms, lazy `jit.cache.entries` / `jit.cache.bytes` gauges.
 
-This module is also the ONE home for code fingerprinting: bench's
-compile-path hash, the sweep auto-apply gate and the backend-calib
-invalidation hash all build on `fingerprint` / `source_fingerprint`
-below instead of three drifting ad-hoc sha256 recipes.
+This module is also the ONE home for code fingerprinting: the store's
+keys and the backend-calib invalidation hash
+(`distributed/auto_tuner/select.py`) build on `fingerprint` /
+`source_fingerprint` below instead of ad-hoc sha256 recipes.
 """
 from __future__ import annotations
 
@@ -73,8 +73,8 @@ _DEFAULT_CAP_MB = 512
 # WHY two entries differ) and guards flags that alter runtime behavior
 # without reshaping the HLO text.
 _KEY_FLAGS = (
-    "FLAGS_fused_ce", "FLAGS_fused_ce_chunks", "FLAGS_splash_attn",
-    "FLAGS_attention_fp32_scores", "FLAGS_numerics_monitor",
+    "FLAGS_splash_attn", "FLAGS_attention_fp32_scores",
+    "FLAGS_numerics_monitor",
     "FLAGS_pallas_force_interpret", "FLAGS_pallas_flash_min_seqlen",
     "FLAGS_comm_quant", "FLAGS_param_storage",
 )
@@ -255,10 +255,15 @@ class CompileCache:
         try:
             with open(path, "rb") as f:
                 rec = pickle.load(f)
+            import jax
             from jax.experimental import serialize_executable as _se
 
+            # without its devices named, a program compiled for one
+            # device loads as one shard on EVERY local device
+            by_id = {d.id: d for d in jax.devices()}
             compiled = _se.deserialize_and_load(
-                rec["payload"], rec["in_tree"], rec["out_tree"])
+                rec["payload"], rec["in_tree"], rec["out_tree"],
+                execution_devices=[by_id[i] for i in rec["device_ids"]])
         except Exception as e:          # corrupt/stale: evict, recompile
             logger.warning("compile cache entry %s unusable (%s: %s) — "
                            "evicting, falling back to compile",
@@ -279,9 +284,12 @@ class CompileCache:
             from jax.experimental import serialize_executable as _se
 
             payload, in_tree, out_tree = _se.serialize(compiled)
-            blob = pickle.dumps({"payload": payload, "in_tree": in_tree,
-                                 "out_tree": out_tree},
-                                protocol=pickle.HIGHEST_PROTOCOL)
+            blob = pickle.dumps(
+                {"payload": payload, "in_tree": in_tree,
+                 "out_tree": out_tree,
+                 "device_ids": [d.id for d in compiled
+                                .runtime_executable().local_devices()]},
+                protocol=pickle.HIGHEST_PROTOCOL)
         except Exception as e:
             logger.warning("compile cache: cannot serialize %s (%s: %s)",
                            components.get("label", "?"),

@@ -91,8 +91,7 @@ class FusedScanTrainStep:
         step = FusedScanTrainStep(model, opt)   # model: scan_layers=True
         loss = step(ids, labels)                # one fused launch
 
-    Constraints (asserted): Adam/AdamW without amsgrad/offload (pinned-host
-    offload was measured counterproductive, docs/DECISIONS.md §8).
+    Constraints (asserted): Adam/AdamW without amsgrad.
 
     Grad clip: ClipGradByValue applies elementwise inside the scan (free);
     ClipGradByGlobalNorm runs a DEFERRED-NORM two-pass backward — pass 1
@@ -173,10 +172,6 @@ class FusedScanTrainStep:
                     "rejected)")
         if opt._amsgrad:
             raise ValueError("amsgrad moment2_max not supported")
-        if opt._offload_masters:
-            raise ValueError(
-                "master offload defeats the in-scan update (measured "
-                "worse, docs/DECISIONS.md §8)")
         cfg = model.config
         # dropout is legal here: the per-layer PRNG offset binding
         # (_RNG_SLOTS scheme) makes the backward's block recompute draw

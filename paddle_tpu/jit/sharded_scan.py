@@ -2288,7 +2288,7 @@ def select_train_step(model, optimizer, criterion=None, mesh=None,
 
 
 # ---------------------------------------------------------------------------
-# HLO probe program (tools/hlo_overlap.py --probe, bench --multichip)
+# HLO probe program (tools/hlo_overlap.py --probe, the HLO receipts in tests/)
 # ---------------------------------------------------------------------------
 
 def build_probe_lowered(n_devices=8, scan_unroll=2, layer_chunk=1,

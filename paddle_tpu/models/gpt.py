@@ -862,8 +862,7 @@ class GPTForCausalLM(nn.Layer):
              segment_ids=None):
         """Training loss via the fused LM head: hidden states go straight
         into F.fused_linear_cross_entropy, so the [tokens, vocab] logits
-        are never materialized (vocab-tiled streaming CE by default —
-        FLAGS_fused_ce — else chunked logsumexp). `segment_ids` packs
+        are never materialized (vocab-tiled streaming CE). `segment_ids` packs
         multiple documents per row (see GPTModel.forward). Numerically
         equal to GPTPretrainingCriterion(self(ids), labels)."""
         hidden = self.gpt(input_ids, position_ids,

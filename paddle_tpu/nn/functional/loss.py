@@ -393,143 +393,40 @@ def ctc_loss(log_probs, labels, input_lengths, label_lengths, blank=0,
 # c_softmax_with_cross_entropy_kernel.cu) and fused_softmax_mask — which fuse
 # the softmax/CE chain to avoid logits round-trips. TPU-first: at a 50k vocab
 # the fp32 logits tensor (batch*seq x vocab) dominates the LM-head HBM traffic
-# and is held across the whole backward as a vjp residual; instead we scan
-# over token chunks, computing each chunk's logits on the MXU, reducing to
-# logsumexp + the picked logit, and discarding the chunk. The custom VJP
-# recomputes per-chunk logits in backward (flash-attention-style
-# recompute-over-store) and accumulates the weight gradient in fp32.
+# and is held across the whole backward as a vjp residual; the vocab-tiled
+# kernel (ops/pallas/fused_cross_entropy.py) never builds it.
 # ---------------------------------------------------------------------------
-
-from functools import partial as _partial
-
-import numpy as _np
-from jax import lax as _lax
-
-
-def _chunk_logits(hc, w, transpose_y):
-    # hc [C, H]; w [V, H] when transpose_y (embedding layout) else [H, V].
-    if transpose_y:
-        return jnp.dot(hc, w.T, preferred_element_type=jnp.float32)
-    return jnp.dot(hc, w, preferred_element_type=jnp.float32)
-
-
-def _pad_chunks(x, n_chunks, pad_value):
-    n = x.shape[0]
-    c = -(-n // n_chunks)
-    pad = c * n_chunks - n
-    if pad:
-        cfg = [(0, pad)] + [(0, 0)] * (x.ndim - 1)
-        x = jnp.pad(x, cfg, constant_values=pad_value)
-    return x.reshape((n_chunks, c) + x.shape[1:])
-
-
-@_partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _fused_linear_ce(h, w, labels, transpose_y, ignore_index, n_chunks):
-    losses, _ = _fused_linear_ce_fwd(h, w, labels, transpose_y, ignore_index,
-                                     n_chunks)
-    return losses
-
-
-def _fused_linear_ce_fwd(h, w, labels, transpose_y, ignore_index, n_chunks):
-    n = h.shape[0]
-    hr = _pad_chunks(h, n_chunks, 0)
-    lr = _pad_chunks(labels, n_chunks, ignore_index)
-
-    def body(_, hl):
-        hc, lc = hl
-        logits = _chunk_logits(hc, w, transpose_y)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        valid = lc != ignore_index
-        safe = jnp.where(valid, lc, 0).astype(jnp.int32)
-        picked = jnp.take_along_axis(logits, safe[:, None], axis=-1)[:, 0]
-        return None, jnp.where(valid, lse - picked, 0.0)
-
-    _, losses = _lax.scan(body, None, (hr, lr))
-    return losses.reshape(-1)[:n], (h, w, labels)
-
-
-def _fused_linear_ce_bwd(transpose_y, ignore_index, n_chunks, res, g):
-    h, w, labels = res
-    n, hidden = h.shape
-    hr = _pad_chunks(h, n_chunks, 0)
-    lr = _pad_chunks(labels, n_chunks, ignore_index)
-    gr = _pad_chunks(g, n_chunks, 0)
-
-    def body(dw, hlg):
-        hc, lc, gc = hlg
-        c = hc.shape[0]
-        logits = _chunk_logits(hc, w, transpose_y)
-        p = jax.nn.softmax(logits, axis=-1)
-        valid = lc != ignore_index
-        safe = jnp.where(valid, lc, 0).astype(jnp.int32)
-        d = p.at[jnp.arange(c), safe].add(-1.0)
-        d = d * jnp.where(valid, gc, 0.0).astype(jnp.float32)[:, None]
-        dlow = d.astype(h.dtype)  # grads ride the MXU in the param dtype
-        if transpose_y:           # w [V, H]
-            dh = jnp.dot(dlow, w, preferred_element_type=jnp.float32)
-            dwc = jnp.dot(dlow.T, hc, preferred_element_type=jnp.float32)
-        else:                     # w [H, V]
-            dh = jnp.dot(dlow, w.T, preferred_element_type=jnp.float32)
-            dwc = jnp.dot(hc.T, dlow, preferred_element_type=jnp.float32)
-        return dw + dwc, dh.astype(h.dtype)
-
-    dw, dh = _lax.scan(body, jnp.zeros(w.shape, jnp.float32), (hr, lr, gr))
-    dh = dh.reshape(-1, hidden)[:n]
-    ct_labels = _np.zeros(labels.shape, dtype=jax.dtypes.float0)
-    return dh, dw.astype(w.dtype), ct_labels
-
-
-_fused_linear_ce.defvjp(_fused_linear_ce_fwd, _fused_linear_ce_bwd)
 
 
 def fused_linear_cross_entropy(hidden, weight, labels, transpose_y=True,
                                ignore_index=-100, reduction="mean",
-                               n_chunks=None, vocab_tiled=None,
                                name=None):
     """Cross entropy of `softmax(hidden @ weight)` with the full logits
-    matrix never hitting HBM. Two fused implementations:
-
-    * **vocab-tiled streaming** (default, `FLAGS_fused_ce`): logits
-      stream through vocab tiles — online logsumexp + gathered label
-      logit in forward, d_logits folded into dhidden/dweight per tile in
-      backward (ops/pallas/fused_cross_entropy.py — Pallas kernel on
-      TPU, lax.scan tiles elsewhere). No [tokens, vocab] array exists in
-      either pass.
-    * **token-chunked logsumexp** (flag off, or `vocab_tiled=False`):
-      the round-4 scheme — full-vocab logits per token chunk, discarded
-      after reduction (see module comment above; FLAGS_fused_ce_chunks).
+    matrix never hitting HBM: logits stream through vocab tiles — online
+    logsumexp + gathered label logit in forward, d_logits folded into
+    dhidden/dweight per tile in backward (ops/pallas/fused_cross_entropy.py
+    — Pallas kernel on TPU, lax.scan tiles elsewhere). No [tokens, vocab]
+    array exists in either pass.
 
     hidden: [..., H] activations; weight: [V, H] (transpose_y=True — the
     tied-embedding layout) or [H, V]; labels: int [...] matching hidden's
     leading dims. reduction "mean" averages over non-ignored tokens.
     """
-    from ...utils import flags as _flags
+    from ...ops.pallas import fused_cross_entropy as _fce
 
     hidden = ensure_tensor(hidden)
     weight = ensure_tensor(weight)
     labels = ensure_tensor(labels)
-    if n_chunks is None:
-        n_chunks = int(_flags.get_flags(["FLAGS_fused_ce_chunks"])
-                       ["FLAGS_fused_ce_chunks"])
-    n_chunks = max(1, int(n_chunks))
-    if vocab_tiled is None:
-        vocab_tiled = bool(_flags.get_flag("FLAGS_fused_ce"))
 
     def f(h, w, lbl):
         hsz = h.shape[-1]
         flat_h = h.reshape(-1, hsz)
         flat_l = lbl.reshape(-1).astype(jnp.int32)
-        if vocab_tiled:
-            from ...ops.pallas import fused_cross_entropy as _fce
-
-            # kernel layout is [vocab, hidden]; an [H, V] head transposes
-            # outside (AD routes dweight back through the transpose)
-            w_vh = w if transpose_y else w.T
-            losses = _fce.fused_cross_entropy(
-                flat_h, w_vh, flat_l, ignore_index=ignore_index)
-        else:
-            losses = _fused_linear_ce(flat_h, w, flat_l, transpose_y,
-                                      ignore_index, n_chunks)
+        # kernel layout is [vocab, hidden]; an [H, V] head transposes
+        # outside (AD routes dweight back through the transpose)
+        w_vh = w if transpose_y else w.T
+        losses = _fce.fused_cross_entropy(
+            flat_h, w_vh, flat_l, ignore_index=ignore_index)
         if reduction == "none":
             return losses.reshape(lbl.shape)
         if reduction == "sum":

@@ -14,10 +14,10 @@ serving:
 - `RetraceSentinel` — wraps every jitted step path; an unexpected
   recompile becomes one attributed log line naming the argument leaf
   whose shape/dtype/weak-type/placement changed, and a hard error
-  under `set_strict_retrace(True)` (the selftest lanes).
+  under `set_strict_retrace(True)` (tests/test_observability.py).
 - `hlo_costs` — ``compiled.cost_analysis()`` flops/bytes per step and
-  the per-mesh-axis collective byte census, feeding cost-analysis MFU
-  into BENCH records.
+  the per-mesh-axis collective byte census (`cost_analysis()` of the
+  step classes).
 - `FlightRecorder` / `recorder()` — a bounded black box of recent
   events dumped (with a registry snapshot) on crashes;
   `install_signal_dump()` adds SIGQUIT hung-process dumps (ring +
@@ -27,8 +27,8 @@ serving:
   KV hand-off corruption, host-ring drop, checkpoint chunk flip,
   stragglers), scriptable one-shot/probabilistic/scheduled triggers,
   every firing logged to the flight recorder and counted on the
-  registry. The substrate behind the chaos selftest lane and the
-  fleet's self-healing rehearsals.
+  registry. The substrate behind tests/test_chaos.py and the fleet's
+  self-healing rehearsals.
 - `Tracer` / `Span` (ISSUE 13) — request-scoped causal timelines: a
   bounded ring of span trees with O(1) begin/end, tail-exemplar
   retention, orphan detection, chrome-trace export on per-request

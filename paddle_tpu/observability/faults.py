@@ -70,9 +70,6 @@ FAULT_POINTS = {
     "ckpt.chunk.flip":
         "flip one byte in a written checkpoint chunk before commit "
         "(manifest verification must catch it on restore)",
-    "proc.sigkill":
-        "SIGKILL a victim subprocess after a seeded delay (the kill "
-        "lane of ft_selftest)",
     "train.step.crash":
         "raise at a train-step boundary (elastic-resume rehearsal)",
     "train.step.straggler":

@@ -21,8 +21,8 @@ __all__ = ["load_hlo_overlap", "cost_analysis_of"]
 
 def load_hlo_overlap():
     """tools/hlo_overlap.py by path (tools/ lives at the repo root,
-    next to the paddle_tpu package — same loader the linalg probe and
-    the sharded-scan selftest use)."""
+    next to the paddle_tpu package — same loader the linalg probe
+    uses)."""
     import importlib.util
 
     root = os.path.dirname(os.path.dirname(os.path.dirname(

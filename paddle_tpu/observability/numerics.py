@@ -38,8 +38,8 @@ sums rank partials at readback time. The grad sq-norms share the
 clip's per-bucket shard reductions (the monitor reads the same
 per-chunk terms the `ClipGradByGlobalNorm` carry folds, and computes
 them only when clipping is off), so the compiled sharded step carries
-exactly the collectives it carried before — the numerics selftest
-lane's per-axis census is the receipt. The ONE exception is the
+exactly the collectives it carried before — the per-axis census in
+tests/test_numerics.py is the receipt. The ONE exception is the
 pipeline ring: the input-finiteness flag hops stages as a scalar
 ppermute per ring tick riding beside the existing activation ppermute
 (the flag cannot thread a same-device carry there — its producer is

@@ -3,7 +3,7 @@
 The unification layer ISSUE 12 asks for: every runtime producer (input
 prefetcher, serving scheduler, non-finite guard, checkpoint manager,
 comm bucketer, pipeline schedule) publishes into ONE registry instead
-of a private dict, and every consumer (bench records, Prometheus
+of a private dict, and every consumer (Prometheus
 scrapes, chrome-trace counter tracks, the crash flight recorder) reads
 the same surface.
 

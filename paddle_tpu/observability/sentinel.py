@@ -19,7 +19,7 @@ kind) and:
   declared bucketed/optional argument (prefill length buckets, the
   optional segment-id arg) — everything else is an **unexpected
   recompile**, logged, counted in the registry, noted in the flight
-  recorder, and raised as ``RetraceError`` in strict mode (selftests).
+  recorder, and raised as ``RetraceError`` in strict mode (tests).
 
 All the existing compile-count probes are expressible through the
 sentinel: ``signatures`` is the trace count, ``calls`` the dispatch
@@ -49,8 +49,7 @@ class RetraceError(RuntimeError):
 
 def set_strict_retrace(on: bool):
     """Global strict toggle: any sentinel without an explicit
-    ``strict=`` raises `RetraceError` on an unexpected recompile. The
-    hybrid/serving/observability selftest lanes run with this ON."""
+    ``strict=`` raises `RetraceError` on an unexpected recompile."""
     global _strict
     _strict = bool(on)
 
@@ -335,8 +334,7 @@ class RetraceSentinel:
 
 def retrace_summary():
     """{sentinel name: stats} over every live sentinel — the one-call
-    clean-run receipt the selftest lanes record (total unexpected must
-    be 0)."""
+    clean-run receipt (total unexpected must be 0)."""
     out, total = {}, 0
     with _sentinel_lock:
         refs = list(_all_sentinels)

@@ -8,8 +8,8 @@ metrics render as counter lanes under the host/device spans.
 Schema: every record carries ``ts`` (unix seconds), ``lane`` (e.g.
 "train"/"serve") and ``step`` (int); all other fields are free-form and
 should be JSON scalars (numeric fields become chrome counter tracks).
-``read_jsonl()`` is the matching loader the schema round-trip selftest
-uses.
+``read_jsonl()`` is the matching loader (the schema round trip is in
+tests/test_observability.py).
 """
 from __future__ import annotations
 

@@ -16,7 +16,7 @@ Design constraints (same bar as the registry):
   slotted object and appends to its parent's child list; ``end`` stamps
   ``t1`` and, for roots, rotates the bounded ring. No percentile math,
   no serialization, no device access ever happens on the hot path —
-  `to_dict` trees are built at scrape time (`/tracez`, selftests).
+  `to_dict` trees are built at scrape time (`/tracez`, tests).
 - **Bounded everywhere.** Completed roots live in a ring
   (``capacity``), tail exemplars in their own ring
   (``exemplar_capacity``), children per span are capped
@@ -27,7 +27,7 @@ Design constraints (same bar as the registry):
   still open while its root is closed (the churn-with-preemption bug
   class — a decode span leaked across a retire), or closed with a
   dangling parent that was never recorded. ``orphans()`` walks the
-  open set at call time; the serving selftest asserts it is empty
+  open set at call time; tests/test_tracing.py asserts it is empty
   after drain + ``abort_all``.
 - **Chrome export on per-request tracks.** Ended spans (when
   ``chrome=True``) land in a bounded module buffer on the same

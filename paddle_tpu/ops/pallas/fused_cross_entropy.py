@@ -251,8 +251,8 @@ def _alias_selfcheck(dtype, hidden, bn, bv):
                 f"fused-CE backward self-check FAILED ({name} max err "
                 f"{err:.3e}, tol {tol:.0e}, config {key}): the aliased "
                 "dW accumulator round-trip no longer matches the "
-                "hazard-free path. Set FLAGS_fused_ce=0 to route the "
-                "loss to the token-chunked path, and report this.")
+                "hazard-free path. F.cross_entropy on dense logits does "
+                "not use this kernel; report this.")
     _alias_checked.add(key)   # only memoize a PASSING check
 
 

@@ -14,6 +14,7 @@ The rule (same for every kernel):
 from __future__ import annotations
 
 import collections
+import math
 import re
 
 import jax
@@ -83,6 +84,24 @@ def mosaic_kernels(hlo_text: str) -> collections.Counter:
         name for line in hlo_text.splitlines()
         if "tpu_custom_call" in line
         for name in _MOSAIC_RE.findall(line))
+
+
+_SHAPE_RE = re.compile(r"(?:f32|f16|bf16|f64)\[([0-9,]+)\]")
+
+
+def forbidden_shapes(hlo_text: str, batch: int, seq: int, vocab: int):
+    """Buffers the fused train step must never hold, found in its HLO
+    text: logits-shaped (last dim == vocab with >= batch*seq rows behind
+    it) and attention-scores-shaped (>= 3d, trailing [seq, seq])."""
+    bad = []
+    for m in _SHAPE_RE.finditer(hlo_text):
+        dims = [int(x) for x in m.group(1).split(",") if x]
+        if len(dims) >= 2 and dims[-1] == vocab \
+                and math.prod(dims[:-1]) >= batch * seq:
+            bad.append(dims)
+        if len(dims) >= 3 and dims[-1] == seq and dims[-2] == seq:
+            bad.append(dims)
+    return bad
 
 
 def route(kernel: str, supported: bool, geometry, interpret=None,

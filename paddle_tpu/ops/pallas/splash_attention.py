@@ -40,7 +40,7 @@ needed by the packed-sequence pretraining path (ROADMAP open item 2):
 Two paths, one contract (the `paged_attention.py` pattern):
 
 * **Pallas kernel** — TPU (or `interpret=True` for hermetic CPU
-  parity runs; see `paddle_tpu/ops/pallas/training_selftest.py`).
+  parity runs; see `tests/test_splash_attention.py`).
 * **XLA path** (`splash_attention_xla`) — CPU, unsupported geometry: one
   dense masked attention with identical mask + empty-row semantics,
   parity-tested against the interpret-mode kernel.
@@ -66,7 +66,7 @@ from .flash_attention import (  # noqa: F401  (shared kernel helpers)
 )
 
 __all__ = ["splash_attention", "splash_attention_xla", "supports",
-           "kernel_active", "computed_pairs"]
+           "computed_pairs"]
 
 _SUB = 8  # sublane replication of the kv-side segment-id plane
 
@@ -81,17 +81,6 @@ def supports(q_shape, num_kv_heads, dtype, sk=None) -> bool:
     if sk is None:
         sk = sq
     return _pick_block(sq) is not None and _pick_block(sk) is not None
-
-
-def kernel_active(q_shape, num_kv_heads, dtype) -> bool:
-    """Would `splash_attention` run the compiled kernel here and now?
-    (Flag + geometry + on-TPU; the bench records this per config.)"""
-    from ...utils import flags as _flags
-
-    if not _flags.get_flag("FLAGS_splash_attn"):
-        return False
-    return (supports(tuple(q_shape), num_kv_heads, dtype)
-            and routing.on_tpu())
 
 
 # ---------------------------------------------------------------------------

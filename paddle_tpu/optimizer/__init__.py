@@ -48,10 +48,9 @@ class Adam(Optimizer):
     def __init__(self, learning_rate=0.001, beta1=0.9, beta2=0.999, epsilon=1e-8,
                  parameters=None, weight_decay=None, grad_clip=None, lazy_mode=False,
                  multi_precision=False, use_multi_tensor=None, amsgrad=False,
-                 moment_dtype=None, offload_master_weights=False, name=None):
+                 moment_dtype=None, name=None):
         super().__init__(learning_rate, parameters, weight_decay, grad_clip,
-                         multi_precision, name,
-                         offload_master_weights=offload_master_weights)
+                         multi_precision, name)
         self._beta1 = beta1
         self._beta2 = beta2
         self._epsilon = epsilon
@@ -230,14 +229,11 @@ class AdamW(Adam):
                  parameters=None, weight_decay=0.01, lr_ratio=None,
                  apply_decay_param_fun=None, grad_clip=None, lazy_mode=False,
                  multi_precision=False, amsgrad=False, moment_dtype=None,
-                 use_multi_tensor=None, offload_master_weights=False,
-                 name=None):
+                 use_multi_tensor=None, name=None):
         super().__init__(learning_rate, beta1, beta2, epsilon, parameters,
                          None, grad_clip, lazy_mode, multi_precision,
                          use_multi_tensor=use_multi_tensor, amsgrad=amsgrad,
-                         moment_dtype=moment_dtype,
-                         offload_master_weights=offload_master_weights,
-                         name=name)
+                         moment_dtype=moment_dtype, name=name)
         self._wd_coeff = float(weight_decay) if weight_decay else 0.0
         self._apply_decay_param_fun = apply_decay_param_fun
 

@@ -19,8 +19,7 @@ from .lr import LRScheduler
 
 class Optimizer:
     def __init__(self, learning_rate=0.001, parameters=None, weight_decay=None,
-                 grad_clip=None, multi_precision=False, name=None,
-                 offload_master_weights=False):
+                 grad_clip=None, multi_precision=False, name=None):
         self._learning_rate = learning_rate
         # param groups (reference optimizer.py:140: list of dicts whose
         # 'learning_rate' is a SCALE of the base lr and whose
@@ -63,14 +62,6 @@ class Optimizer:
             self._weight_decay = weight_decay  # None or regularizer-like
         self._accumulators = {}  # name -> {param_name: jax array}
         self._master_weights = {}  # param_name -> fp32 jax array
-        # pinned-host offload of fp32 master weights (the PERF.md capacity
-        # lever for 1.3b-on-one-chip: frees ~4 bytes/param of HBM; the
-        # update still runs on device — XLA streams the h2d read and d2h
-        # write-back of each master through the step). Shardings are
-        # captured at master creation so the traced update can address the
-        # host space without reading tracer metadata.
-        self._offload_masters = bool(offload_master_weights)
-        self._master_shardings = {}  # param_name -> (host_sh, dev_sh)
         self._step_count = 0
         # traced-step protocol fields (see the "traced-step protocol"
         # section): a frozen lr tracer and the dry-run switch
@@ -114,74 +105,20 @@ class Optimizer:
     def _master_weight(self, param):
         key = param.name or str(id(param))
         if key not in self._master_weights:
-            master = param._data.astype(jnp.float32)
-            if self._offload_masters:
-                import jax
-
-                sh = getattr(master, "sharding", None)
-                dev = master.devices().pop()
-                # TPU-only: the CPU PJRT backend does not honor pinned_host
-                # placements on jit outputs (buffer/sharding memory-kind
-                # mismatch aborts the process), so elsewhere the flag is a
-                # clean no-op
-                if (sh is not None and dev.platform == "tpu"
-                        and "pinned_host" in {
-                            m.kind for m in dev.addressable_memories()}):
-                    host_sh = sh.with_memory_kind("pinned_host")
-                    self._master_shardings[key] = (
-                        host_sh, sh.with_memory_kind("device"))
-                    master = jax.device_put(master, host_sh)
-            self._master_weights[key] = master
+            self._master_weights[key] = param._data.astype(jnp.float32)
         return self._master_weights[key]
-
-    def _rehome_offloaded_masters(self):
-        """Re-derive the pinned-host placement of every master from its
-        CURRENT sharding. Called after a wrapper (ZeRO-1 etc.) reshards the
-        master arrays: the new mesh sharding replaces the creation-time
-        single-device pair, keeping the offload effective (and the traced
-        update's device_puts consistent) under sharded state."""
-        if not self._offload_masters or not self._master_shardings:
-            return
-        import jax
-
-        for key in list(self._master_shardings):
-            m = self._master_weights.get(key)
-            sh = getattr(m, "sharding", None)
-            if m is None or sh is None:
-                continue
-            host_sh = sh.with_memory_kind("pinned_host")
-            dev_sh = sh.with_memory_kind("device")
-            self._master_shardings[key] = (host_sh, dev_sh)
-            if m.sharding.memory_kind != "pinned_host":
-                self._master_weights[key] = jax.device_put(m, host_sh)
 
     def _write_param(self, param, new_value_f32_or_native):
         if self._dry_run:
             return
-        key = param.name or str(id(param))
         if self._use_master(param):
-            new_master = new_value_f32_or_native
-            if key in self._master_shardings:
-                import jax
-
-                new_master = jax.device_put(new_master,
-                                            self._master_shardings[key][0])
-            self._master_weights[key] = new_master
-            param._data = new_value_f32_or_native.astype(param._data.dtype)
-        else:
-            param._data = new_value_f32_or_native.astype(param._data.dtype)
+            key = param.name or str(id(param))
+            self._master_weights[key] = new_value_f32_or_native
+        param._data = new_value_f32_or_native.astype(param._data.dtype)
 
     def _param_value(self, param):
         if self._use_master(param):
-            master = self._master_weight(param)
-            key = param.name or str(id(param))
-            if key in self._master_shardings:
-                import jax
-
-                # read the offloaded master back into HBM for the update
-                master = jax.device_put(master,
-                                        self._master_shardings[key][1])
-            return master
+            return self._master_weight(param)
         return param._data
 
     # -- step ----------------------------------------------------------------

@@ -14,8 +14,6 @@ sequence ever waits for another's tail (ROADMAP item 1).
   lowest-priority victim when the page pool runs dry).
 * ``ServingMetrics`` — queue depth, TTFT, inter-token latency, tok/s,
   preemption counters.
-* ``traffic`` — synthetic Poisson traffic + the static generate-and-wait
-  baseline for the bench A/B (bench.py --serve).
 * ``OnlineTuner`` — opt-in closed loop (ISSUE 17) nudging admission
   watermark / prefill aggressiveness / decode burst from live SLO-burn
   and queue-depth gauges; bounded, hysteretic, flight-recorded.

@@ -13,8 +13,8 @@ Preemption is invisible in the output stream: the request re-prefills
 its prompt PLUS everything it already generated, and the per-request
 RNG stream (seed, context-position) makes the resumed tokens match an
 uninterrupted run wherever the chunk-prefill and decode paths produce
-the same logits — exact on the shared XLA path (asserted by the
-selftest); on-chip the two paths run different kernels, so a token
+the same logits — exact on the shared XLA path (asserted by
+tests/test_serving.py); on-chip the two paths run different kernels, so a token
 sitting exactly on a sampling decision boundary could in principle
 flip on kernel-level numerics.
 """

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives — the one place in the
 repository that sets `jax_compilation_cache_dir`.
 
-`chip_smoke.py`, `bench.py`, `tools/diag_fused_mem.py` and
+`chip_smoke.py`, `benchmark/run.py`, `tools/diag_fused_mem.py` and
 `tests/conftest.py` call `use_compile_cache()` before their first
 compile. Runs that are meant to share compiled programs must agree on
 the directory, so it is never a temporary one: `JAX_COMPILATION_CACHE_DIR`

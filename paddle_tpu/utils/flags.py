@@ -73,10 +73,6 @@ define_flag("FLAGS_attention_fp32_scores", False,
             "store attention scores in fp32 instead of the input dtype "
             "(softmax math is fp32 either way); costs ~2x score-matrix "
             "HBM traffic")
-define_flag("FLAGS_fused_ce_chunks", 4,
-            "token-chunk count for fused_linear_cross_entropy: logits are "
-            "computed per chunk and discarded instead of materializing the "
-            "full [tokens, vocab] fp32 matrix")
 define_flag("FLAGS_pallas_alias_selfcheck", True,
             "one-time per-config on-device check that the fused flash "
             "backward's aliased dK/dV HBM accumulation matches the "
@@ -122,18 +118,12 @@ define_flag("FLAGS_splash_attn", True,
             "the geometry qualifies, and packed-sequence segment "
             "attention through it on every backend (XLA fallback off "
             "TPU). Off restores the round-3 flash/XLA routing.")
-define_flag("FLAGS_fused_ce", True,
-            "route fused_linear_cross_entropy through the vocab-tiled "
-            "streaming CE (ops/pallas/fused_cross_entropy.py: Pallas "
-            "kernel on TPU, lax.scan tiles elsewhere) — the "
-            "[tokens, vocab] logits never exist in forward or "
-            "backward. Off restores the token-chunked logsumexp path "
-            "(FLAGS_fused_ce_chunks).")
 define_flag("FLAGS_pallas_force_interpret", False,
             "testing: route the splash-attention / fused-CE Pallas "
-            "kernels in interpret mode even off-TPU, so hermetic CPU "
-            "lanes (training_kernels selftest, HLO probes) exercise "
-            "the kernel code paths instead of the XLA fallbacks")
+            "kernels in interpret mode even off-TPU, so CPU tests "
+            "(tests/test_training_kernels.py, chip_smoke.py --tiny) "
+            "exercise the kernel code paths instead of the XLA "
+            "fallbacks")
 define_flag("FLAGS_pallas_flash_min_seqlen", 1024,
             "min seq len to route scaled_dot_product_attention to the "
             "pallas flash kernel. Measured on v5e (h16 d64 bf16, fwd+bwd "

@@ -7,9 +7,8 @@ blobs rejected by crc32 before allocation, per-request deadlines,
 brown-out shedding below the healthy-capacity watermark, replica-kill
 re-dispatch with bit-exact token parity, and hung-join accounting at
 stop(). The randomized multi-seed churn sweep is marked ``slow``
-(tier-1 runs only the deterministic lanes); the heavyweight recovery
-lanes (stuck watchdog, elastic resume, MTTR measurement) live in the
-bench ``chaos`` selftest, not here.
+(tier-1 runs only the deterministic lanes). The dp8 -> dp4 elastic
+resume is in tests/test_sharded_storage.py; no test measures an MTTR.
 """
 import time
 

@@ -92,6 +92,9 @@ salt = float(sys.argv[1])          # a program no earlier run compiled
 step = TrainStep(m, lambda mm, a: ((mm(a) - salt) ** 2).mean(),
                  popt.SGD(learning_rate=0.1, parameters=m.parameters()))
 print("LOSS", float(step(paddle.ones([2, 4]))))
+def placed_from_outside_witness(x):   # after the step: had the package
+    return x * salt                   # moved the cache, this would follow
+jax.jit(placed_from_outside_witness)(jax.numpy.ones(3)).block_until_ready()
 """
 _KEEP_ALL = {"JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0"}
 
@@ -102,7 +105,10 @@ def _entries(path):
 
 def test_compile_cache_is_placed_from_outside(tmp_path):
     """A child given JAX_COMPILATION_CACHE_DIR runs one TrainStep
-    through the helper: the entries land there and nowhere else. The
+    through the helper: the entries land there and nowhere else (the
+    other workers of a parallel run write `jit_step_fn-*` entries into
+    <checkout>/.jax_cache meanwhile, so "nowhere else" is read off a
+    function only the child compiles, after its step has run). The
     same child pins that importing the package (and .serving, .jit,
     .distributed.launch) initialises no backend — a launcher parent must
     leave the chip to its child. Without the variable the place is
@@ -121,8 +127,12 @@ def test_compile_cache_is_placed_from_outside(tmp_path):
         {"JAX_COMPILATION_CACHE_DIR": str(tmp_path), **_KEEP_ALL})
     assert rc == 0, err[-2000:]
     assert f"CACHE {tmp_path}" in out
-    assert _entries(str(tmp_path)), "no entry in the directory given"
-    assert _entries(default) == before, "entries leaked into .jax_cache"
+    given = _entries(str(tmp_path))
+    assert any(e.startswith("jit_step_fn-") for e in given), given
+    assert any("placed_from_outside_witness" in e for e in given), given
+    leaked = {e for e in _entries(default) - before
+              if "placed_from_outside_witness" in e}
+    assert not leaked, f"entries leaked into .jax_cache: {leaked}"
 
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR") or default
     assert use_compile_cache() == placed
@@ -145,11 +155,13 @@ def test_chip_smoke_tiny_passes_and_plain_run_finds_no_tpu():
     assert '"ok"' not in out
 
 
-def test_bench_fails_on_cpu_rather_than_printing_an_mfu():
-    rc, out, err = _run(["bench.py"])
+def test_benchmark_fails_on_cpu_rather_than_printing_a_metric():
+    rc, out, err = _run(["benchmark/run.py", "--workload",
+                         "gpt3-350m.train.8x1024", "--seed", "1",
+                         "--seconds", "1", "--trace", "0"])
     assert rc != 0
-    assert "no TPU" in err
-    assert "mfu" not in out and "tokens_per_sec" not in out
+    assert "benchmark: no TPU" in err
+    assert "train_tok_s_chip" not in out and '"metrics"' not in out
 
 
 def test_model_built_off_the_mesh_shards_without_touching_chip_0():

@@ -130,13 +130,13 @@ class TestKeyComponents:
     def test_flag_flip_changes_key(self):
         from paddle_tpu.utils import flags as _flags
 
-        old = _flags.get_flag("FLAGS_fused_ce")
+        old = _flags.get_flag("FLAGS_numerics_monitor")
         base = digest_key(_components())
         try:
-            _flags.set_flags({"FLAGS_fused_ce": not old})
+            _flags.set_flags({"FLAGS_numerics_monitor": not old})
             assert digest_key(_components()) != base
         finally:
-            _flags.set_flags({"FLAGS_fused_ce": old})
+            _flags.set_flags({"FLAGS_numerics_monitor": old})
 
 
 # ---------------------------------------------------------------------------
@@ -215,14 +215,14 @@ class TestCachedJit:
 
         x = jnp.arange(8.0)
         cached_jit(lambda v: v * 2, label="t")(x)
-        old = _flags.get_flag("FLAGS_fused_ce")
+        old = _flags.get_flag("FLAGS_numerics_monitor")
         try:
-            _flags.set_flags({"FLAGS_fused_ce": not old})
+            _flags.set_flags({"FLAGS_numerics_monitor": not old})
             f2 = cached_jit(lambda v: v * 2, label="t")
             f2(x)
             assert f2.disk_misses == 1 and f2.disk_hits == 0
         finally:
-            _flags.set_flags({"FLAGS_fused_ce": old})
+            _flags.set_flags({"FLAGS_numerics_monitor": old})
 
     def test_corrupted_entry_self_evicts_and_recovers(self, cache_dir):
         x = jnp.arange(8.0)
@@ -240,7 +240,8 @@ class TestCachedJit:
         # the corrupt entry was evicted, then re-put by the recompile
         with open(bin_path, "rb") as fh:
             rec = pickle.load(fh)     # readable again
-        assert set(rec) == {"payload", "in_tree", "out_tree"}
+        assert set(rec) == {"payload", "in_tree", "out_tree",
+                            "device_ids"}
 
     def test_disabled_cache_is_plain_jit(self, tmp_path):
         set_cache_dir(None)

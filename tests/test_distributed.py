@@ -473,72 +473,6 @@ class TestShardingStage3:
                    for s in (m2.weight._data.sharding.spec or (None,)))
 
 
-class TestMasterWeightOffload:
-    """Pinned-host offload of fp32 masters (the PERF.md 1.3b capacity
-    lever): numerics identical, masters live in host memory, and the
-    ZeRO-1 wrapper reshards without pulling them back into HBM."""
-
-    def _train(self, offload, wrap_zero1=False, mesh=None):
-        import paddle_tpu.optimizer as popt
-        from paddle_tpu.distributed.fleet import DygraphShardingOptimizer
-        from paddle_tpu.jit import TrainStep
-        import paddle_tpu.nn as nn
-
-        paddle.seed(5)
-        model = nn.Linear(16, 8)
-        model.bfloat16()
-        inner = popt.AdamW(learning_rate=0.01,
-                           parameters=model.parameters(),
-                           multi_precision=True,
-                           offload_master_weights=offload)
-        optimizer = (DygraphShardingOptimizer(inner) if wrap_zero1
-                     else inner)
-
-        def lf(m, xx, yy):
-            d = m(xx) - yy
-            return (d * d).mean()
-
-        x = paddle.to_tensor(np.random.RandomState(3).randn(8, 16)
-                             .astype(np.float32)).astype("bfloat16")
-        y = paddle.to_tensor(np.random.RandomState(4).randn(8, 8)
-                             .astype(np.float32)).astype("bfloat16")
-        step = TrainStep(model, lf, optimizer)
-        losses = [float(step(x, y)) for _ in range(3)]
-        return losses, inner
-
-    def test_parity_on_cpu_noop(self):
-        """On non-TPU backends the flag must be a clean no-op (the CPU
-        PJRT backend aborts on host-placed jit outputs): identical
-        numerics, masters stay in device memory, no shardings recorded.
-        On-chip pinned_host residency + parity is asserted by the TPU
-        selftest lane (bench.py)."""
-        base, _ = self._train(offload=False)
-        off, inner = self._train(offload=True)
-        assert base == off, (base, off)
-        # masters stay in the backend's DEFAULT memory space (the CPU
-        # backend names it 'unpinned_host', TPU 'device') — never pinned
-        kinds = {m.sharding.memory_kind
-                 for m in inner._master_weights.values()}
-        assert len(kinds) == 1 and "pinned_host" not in kinds, kinds
-        assert not inner._master_shardings
-
-    def test_zero1_with_offload_flag(self):
-        """ZeRO-1 wrapper + offload flag coexist (flag no-ops on CPU;
-        _rehome_offloaded_masters must not disturb the resharded state)."""
-        mesh = Mesh(np.asarray(cpu8()[:4]), ("sharding",))
-        denv.set_mesh(mesh)
-        try:
-            losses, inner = self._train(offload=True, wrap_zero1=True)
-            assert all(np.isfinite(v) for v in losses)
-            # ZeRO-1 actually sharded the (shardable) masters
-            assert any(
-                any(ax is not None for ax in (m.sharding.spec or ()))
-                for m in inner._master_weights.values()
-                if hasattr(m.sharding, "spec"))
-        finally:
-            denv.reset()
-
-
 class TestVocabParallelCrossEntropy:
     """Explicit sharded-logsumexp CE (reference mp_layers.py:742): parity
     with plain CE, grads through the psum transposes, and the memory

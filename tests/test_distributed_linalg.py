@@ -218,12 +218,15 @@ class TestHLOReceipts:
         v = probe.collective_receipt(low, grid, full_elems=64 * 64,
                                      what="matmul operand")
         assert v["no_full_matrix"]
-        # one all-reduce per panel per operand, each over exactly ONE
-        # mesh axis (lcm(4,2)=4 panels -> 4 + 4)
+        # a panel of each operand is all-reduced over exactly ONE mesh
+        # axis, and nothing else moves: no gather, nothing over the
+        # flattened grid. How many all-reduce ops carry the 4 + 4 panels
+        # is the compiler's choice (this XLA combines an axis's four
+        # into one tuple all-reduce), so the count is not asserted.
         pa = v["per_axis_counts"]
-        assert pa["rows"]["all-reduce"] == 4
-        assert pa["cols"]["all-reduce"] == 4
-        assert "other" not in pa
+        assert set(pa) == {"rows", "cols"}, pa
+        assert all(set(kinds) == {"all-reduce"} for kinds in pa.values()), pa
+        assert set(v["counts"]) == {"all-reduce"}, v["counts"]
 
     def test_cholesky_receipt(self, grid2x2):
         low = dla.cholesky_lowered(32, grid=grid2x2)
@@ -245,10 +248,7 @@ class TestHLOReceipts:
         assert v["counts"] == {"all-gather": 1}
         assert v["per_axis_counts"]["rows+cols"]["all-gather"] == 1
 
-    @pytest.mark.slow
     def test_eigsh_receipt(self, grid):
-        """Marked slow: the hermetic `distributed_linalg` selftest lane
-        asserts the same census on every bench run."""
         low = dla.eigsh_lowered(64, k=4, iters=8, grid=grid)
         v = probe.collective_receipt(low, grid, full_elems=64 * 64,
                                      what="eigsh input")

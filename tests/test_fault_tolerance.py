@@ -38,6 +38,8 @@ from paddle_tpu.models import (
     GPTConfig, GPTForCausalLM, GPTPretrainingCriterion,
 )
 
+from ft_victim import victim_state
+
 TINY = dict(vocab_size=96, hidden_size=32, num_layers=2,
             num_attention_heads=2, max_position_embeddings=16,
             hidden_dropout_prob=0.0, attention_dropout_prob=0.0)
@@ -207,16 +209,16 @@ class TestCheckpointManager:
         verified checkpoint at a step the victim actually committed.
         Victims run in parallel batches to amortize interpreter
         startup."""
-        from paddle_tpu.distributed.checkpoint.ft_selftest import (
-            _victim_state,
-        )
-
         trials, batch = 20, 5
         rng = np.random.default_rng(7)
+        here = os.path.dirname(os.path.abspath(__file__))
+        repo = os.path.dirname(here)
         env = dict(os.environ)
         env.setdefault("JAX_PLATFORMS", "cpu")
+        env["PYTHONPATH"] = os.pathsep.join(
+            [repo] + [p for p in env.get("PYTHONPATH", "").split(
+                os.pathsep) if p])
         mid_save = 0
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         done = 0
         while done < trials:
             n = min(batch, trials - done)
@@ -224,9 +226,8 @@ class TestCheckpointManager:
             for i in range(n):
                 root = str(tmp_path / f"t{done + i}")
                 child = subprocess.Popen(
-                    [sys.executable, "-m",
-                     "paddle_tpu.distributed.checkpoint.ft_selftest",
-                     "--victim", root],
+                    [sys.executable, os.path.join(here, "ft_victim.py"),
+                     root],
                     stdout=subprocess.PIPE, text=True, env=env,
                     cwd=repo)
                 victims.append((root, child))
@@ -243,14 +244,14 @@ class TestCheckpointManager:
                              if ln.startswith("committed")]
                 if any(".tmp_" in nme for nme in os.listdir(root)):
                     mid_save += 1
-                extra = _victim_state(0)
+                extra = victim_state(0)
                 mgr = CheckpointManager(root, extra_state=extra)
                 got = mgr.restore_or_init()
                 assert got is not None, f"{root}: nothing restorable"
                 verify_checkpoint(os.path.join(root, f"step_{got}"))
                 if confirmed:
                     assert got >= max(confirmed), (got, confirmed)
-                want = _victim_state(got)
+                want = victim_state(got)
                 assert extra["step_scalar"] == got
                 for k in ("w0", "w1"):
                     assert np.array_equal(np.asarray(extra[k]), want[k])
@@ -313,6 +314,38 @@ class TestCheckpointManager:
         assert tgt["step_tag"] == 0
         np.testing.assert_array_equal(np.asarray(tgt["w"]),
                                       np.zeros(8, np.float32))
+
+    def test_armed_chunk_flip_is_caught_and_restore_falls_back(
+            self, tmp_path):
+        """The manager's own ``ckpt.chunk.flip`` fault point (one flipped
+        byte in a chunk it has just written, before commit): manifest
+        verification refuses that step and restore lands on the previous
+        one with its payload intact."""
+        from paddle_tpu.observability import faults
+
+        root = str(tmp_path / "ck")
+        inj = faults.install(0)
+        # the manager asks the point once per save: fire on the SECOND,
+        # so step 0 stays whole as the step to fall back to
+        inj.arm("ckpt.chunk.flip", at=2)
+        try:
+            extra = victim_state(0)
+            mgr = CheckpointManager(root, extra_state=extra)
+            for step in (0, 1):
+                extra.clear()
+                extra.update(victim_state(step))
+                mgr.save(step)
+            assert inj.hits.get("ckpt.chunk.flip", 0) >= 2, inj.hits
+        finally:
+            faults.reset()
+        with pytest.raises(CheckpointError):
+            verify_checkpoint(os.path.join(root, "step_1"))
+        tgt = victim_state(1)
+        assert CheckpointManager(
+            root, extra_state=tgt).restore_or_init() == 0
+        assert tgt["step_scalar"] == 0
+        np.testing.assert_array_equal(np.asarray(tgt["w0"]),
+                                      victim_state(0)["w0"])
 
     def test_restore_key_mismatch_raises_not_silent(self, tmp_path):
         """A template/checkpoint key mismatch is NOT corruption: older
@@ -409,6 +442,38 @@ class TestCheckpointManager:
                                  optimizer=opt2)
         assert mgr2.restore_or_init() == 2
         part2 = [float(step2(x, y)) for _ in range(2)]
+        assert straight == part1 + part2
+
+    def test_fused_scan_async_save_restore_continue_bit_identical(
+            self, tmp_path):
+        """FusedScanTrainStep through an ASYNC save: save at step 2,
+        restore into a model and optimizer built from another seed,
+        continue — the losses equal the uninterrupted run's bit for
+        bit."""
+        ids, labels = _batch(bs=4)
+
+        def build(seed=0):
+            model, opt = _gpt(seed=seed)
+            return model, opt, FusedScanTrainStep(
+                model, opt, criterion=GPTPretrainingCriterion())
+
+        _, _, step = build()
+        straight = [float(step(ids, labels)) for _ in range(5)]
+
+        model, opt, step = build()
+        mgr = CheckpointManager(str(tmp_path / "ck"), model=model,
+                                optimizer=opt, async_save=True)
+        part1 = [float(step(ids, labels)) for _ in range(3)]
+        mgr.save(2)
+        mgr.wait()
+        assert {"snapshot_s", "blocked_s", "io_s"} <= set(mgr.last_timings)
+
+        model2, opt2, step2 = build(seed=123)
+        step2.ensure_built()            # optimizer state slots exist
+        assert CheckpointManager(
+            str(tmp_path / "ck"), model=model2,
+            optimizer=opt2).restore_or_init() == 2
+        part2 = [float(step2(ids, labels)) for _ in range(2)]
         assert straight == part1 + part2
 
     def test_no_retrace_after_restore(self, tmp_path):

@@ -267,54 +267,31 @@ class TestMergedPercentiles:
 
 
 # ---------------------------------------------------------------------------
-# traffic: deterministic per-request identity
+# per-request seeds: the same requests give the same tokens on 1 and 2
+# replicas
 # ---------------------------------------------------------------------------
 
-class TestTrafficSeeding:
-    def test_replay_is_bit_identical(self):
-        from paddle_tpu.serving.traffic import poisson_traffic
-
-        a = poisson_traffic(32, 100.0, 64, seed=5, sessions=4)
-        b = poisson_traffic(32, 100.0, 64, seed=5, sessions=4)
-        for x, y in zip(a, b):
-            assert x.arrival_s == y.arrival_s
-            assert x.seed == y.seed and x.session == y.session
-            np.testing.assert_array_equal(x.prompt, y.prompt)
-        # per-request seeds are distinct (streams never collide)
-        assert len({r.seed for r in a}) == len(a)
-
-    def test_identity_stream_never_shifts_load_draws(self):
-        """Seeds/sessions come from a separate generator: toggling
-        sessions must not move arrivals, prompts or budgets (the lanes
-        tuned on the pre-fleet traffic stay byte-identical)."""
-        from paddle_tpu.serving.traffic import poisson_traffic
-
-        plain = poisson_traffic(32, 100.0, 64, seed=5)
-        tagged = poisson_traffic(32, 100.0, 64, seed=5, sessions=8)
-        for x, y in zip(plain, tagged):
-            assert x.arrival_s == y.arrival_s
-            assert x.max_new_tokens == y.max_new_tokens
-            np.testing.assert_array_equal(x.prompt, y.prompt)
-        assert plain[0].session is None
-        assert all(t.session is not None for t in tagged)
-
+class TestRequestSeeding:
     def test_one_vs_two_replica_streams_identical(self, model):
-        """The property the seeding exists for: the SAME workload
-        replayed against 1 and 2 replicas yields bit-identical tokens
-        per request, sampled, whatever the router did."""
+        """The property per-request seeds exist for: the SAME ten
+        requests served by 1 and by 2 replicas yield bit-identical
+        tokens per request, sampled, whatever the router did."""
         from paddle_tpu.serving import FleetRouter
-        from paddle_tpu.serving.traffic import poisson_traffic
 
         kw = dict(max_slots=4, max_len=64, page_size=8, chunk_size=16,
                   do_sample=True, temperature=0.9, top_k=8)
-        traffic = poisson_traffic(10, 1e9, 64, prompt_lens=(4, 20),
-                                  out_lens=(4, 12), seed=13)
+        rng = np.random.default_rng(13)
+        requests = [
+            (rng.integers(1, 64, (int(rng.integers(4, 21)),))
+             .astype(np.int32),
+             int(rng.integers(4, 13)), int(rng.integers(0, 2**31 - 1)))
+            for _ in range(10)]
 
         def serve(n):
             fleet = FleetRouter(model=model, decode_replicas=n,
                                 engine_kw=kw, seed=3)
-            hs = [fleet.submit(t.prompt, t.max_new_tokens, seed=t.seed,
-                               session=t.session) for t in traffic]
+            hs = [fleet.submit(prompt, budget, seed=seed)
+                  for prompt, budget, seed in requests]
             fleet.run()
             lk = fleet.leak_check()
             assert lk["clean"], lk

@@ -32,7 +32,7 @@ def _unfused(hidden, weight, labels, reduction, ignore_index):
 def test_fused_ce_loss_parity(reduction):
     hidden, weight, labels = _setup()
     got = F.fused_linear_cross_entropy(hidden, weight, labels,
-                                       reduction=reduction, n_chunks=4)
+                                       reduction=reduction)
     want = _unfused(hidden, weight, labels, reduction, -100)
     np.testing.assert_allclose(np.asarray(got._data), np.asarray(want._data),
                                rtol=2e-5, atol=2e-5)
@@ -43,7 +43,7 @@ def test_fused_ce_ignore_index_and_grads():
     hidden.stop_gradient = False
     weight.stop_gradient = False
     loss = F.fused_linear_cross_entropy(hidden, weight, labels,
-                                        ignore_index=-1, n_chunks=3)
+                                        ignore_index=-1)
     loss.backward()
     gh, gw = np.asarray(hidden.grad._data), np.asarray(weight.grad._data)
 
@@ -65,7 +65,7 @@ def test_fused_ce_untransposed_weight():
     w_hv = paddle.to_tensor(np.asarray(weight._data).T.copy())
     w_hv.stop_gradient = False
     loss = F.fused_linear_cross_entropy(hidden, w_hv, labels,
-                                        transpose_y=False, n_chunks=2)
+                                        transpose_y=False)
     loss.backward()
     weight.stop_gradient = False
     want = _unfused(hidden, weight, labels, "mean", -100)
@@ -102,14 +102,13 @@ def test_gpt_model_fused_loss_parity():
 # ---------------------------------------------------------------------------
 # vocab-tiled streaming CE (ops/pallas/fused_cross_entropy.py, ISSUE 7):
 # interpret-mode kernel == XLA tile scan == the unfused dense path, for
-# loss AND both gradients; plus the FLAGS_fused_ce routing surface.
+# loss AND both gradients.
 # ---------------------------------------------------------------------------
 
 import jax
 import jax.numpy as jnp
 
 from paddle_tpu.ops.pallas import fused_cross_entropy as fce
-from paddle_tpu.utils import flags as _flags
 
 
 def _dense_ref(h, w, lbl, ii):
@@ -175,29 +174,6 @@ def test_vocab_tiled_bf16():
     got = fce.fused_cross_entropy(h, w, lbl, interpret=True)
     want = _dense_ref(h, w, lbl, -100)
     assert float(jnp.max(jnp.abs(got - want))) < 3e-2
-
-
-def test_fused_linear_ce_routing_flag():
-    """F.fused_linear_cross_entropy: FLAGS_fused_ce on (vocab-tiled) and
-    off (token-chunked) agree with each other and the unfused path —
-    both reductions, both weight layouts."""
-    hidden, weight, labels = _setup(n=37, h=16, v=53)
-    want = _unfused(hidden, weight, labels, "mean", -100)
-    for tiled in (True, False):
-        _flags.set_flags({"FLAGS_fused_ce": tiled})
-        try:
-            got = F.fused_linear_cross_entropy(hidden, weight, labels)
-            np.testing.assert_allclose(float(got._data),
-                                       float(want._data), rtol=2e-5,
-                                       atol=2e-5)
-            w_hv = paddle.to_tensor(np.asarray(weight._data).T.copy())
-            got_t = F.fused_linear_cross_entropy(hidden, w_hv, labels,
-                                                 transpose_y=False)
-            np.testing.assert_allclose(float(got_t._data),
-                                       float(want._data), rtol=2e-5,
-                                       atol=2e-5)
-        finally:
-            _flags.set_flags({"FLAGS_fused_ce": True})
 
 
 def test_supports_gate():

@@ -23,7 +23,7 @@ TINY = dict(vocab_size=96, hidden_size=32, num_layers=4,
             num_attention_heads=2, max_position_embeddings=16,
             hidden_dropout_prob=0.0, attention_dropout_prob=0.0)
 N_DEV = 8
-LOSS_TOL = 5e-4          # the sharded_scan_selftest parity bar
+LOSS_TOL = 5e-4          # the sharded-scan parity bar (test_sharded_scan.py)
 PARAM_REL_TOL = 5e-3
 PARAM_ABS = 5e-4
 
@@ -97,7 +97,7 @@ def _ldiff(a, b):
 
 def test_dpmp_parity_vs_dp_only_and_eager():
     """dp4×mp2 loss/param trajectories match the dp-only sharded scan
-    and the eager TrainStep within the selftest tolerances, with the
+    and the eager TrainStep within LOSS_TOL / PARAM_TOL, with the
     global-norm clip ACTIVE (acceptance bar of ISSUE 8)."""
     devs = _devs()
     from jax.sharding import Mesh
@@ -236,8 +236,13 @@ def test_mp_hlo_grads_reduced_in_scan_no_full_gather():
                               None).compile().as_text()
     v = hlo.analyze(text, axis_degrees={"dp": 4, "mp": 2})
     per = v["per_axis_counts"]
-    assert per.get("mp", {}).get("all-reduce", 0) >= 2 * TINY[
-        "num_layers"], per      # >= 2 row-parallel psums per layer
+    # a layer's two row-parallel psums (attention out, mlp fc2) are
+    # all-reduces on the mp axis ALONE. A scan body holds them once
+    # however many layers run it, and how many bodies the compiler keeps
+    # (forward, recompute, backward; unrolled or rolled) is its choice,
+    # so not a count per layer
+    assert per.get("mp", {}).get("all-reduce", 0) >= 2, per
+    assert set(per.get("mp", {})) == {"all-reduce"}, per
     assert per.get("dp+mp", {}).get("reduce-scatter", 0) >= 1, per
     # no grad traffic outside the classified patterns, and no gathers
     # anywhere but the flattened dp+mp param gather
@@ -280,7 +285,7 @@ def test_mp_rejects_attention_dropout_and_custom_criterion():
 
 def test_pipeline_parity_dp2pp2():
     """dp2×pp2 ring pipeline matches the eager TrainStep and the
-    dp-only sharded scan within the selftest tolerances."""
+    dp-only sharded scan within LOSS_TOL / PARAM_TOL."""
     devs = _devs()
     from jax.sharding import Mesh
 

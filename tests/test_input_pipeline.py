@@ -5,8 +5,8 @@ The prefetcher stages host batches onto device on a background thread
 (sharding-aware device_put into a depth-K ring). The safety bundle the
 acceptance criteria demand — bit-identical training sync vs prefetched,
 zero added retraces, no rewrite-in-flight under buffer reuse — is
-asserted here on the library surface; the throttled A/B perf gate lives
-in the hermetic bench lane (paddle_tpu/io/input_pipeline_selftest.py).
+asserted here on the library surface. What the prefetcher buys is a
+number of the benchmark (`input_stall` in its train cells), not of a test.
 """
 import gc
 import multiprocessing as mp

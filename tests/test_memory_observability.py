@@ -325,8 +325,7 @@ class TestStorageReceipt:
     def test_sharded_vs_replicated_profile_delta(self):
         # the PR-11 receipt through the ONE profile implementation:
         # probe HLO max buffer 49,984 elems (sharded) vs 65,536
-        # (replicated) — also asserted in the hermetic memory lane,
-        # where the measured numbers land in BENCH_r*.json
+        # (replicated)
         from paddle_tpu.jit.sharded_scan import build_probe_lowered
         from paddle_tpu.observability.memory import (
             CompiledMemoryProfile,

@@ -6,9 +6,8 @@ actuates ONLY through the safe-boundary rebuild hook and never under
 speculative decoding, and every decision is recorded with provenance.
 
 These run against a FakeEngine so the control law is tested exhaustively
-in milliseconds; the real-engine closed loop (token parity, strict
-retrace sentinel with cache + tuner enabled) lives in
-``paddle_tpu/serving/selftest.py::tuner_closed_loop``.
+in milliseconds; the last test closes the loop on a real engine (token
+parity through the tuner's moves).
 """
 import pytest
 
@@ -255,3 +254,44 @@ class TestEngineDefaultOff:
 
         sig = inspect.signature(ServingEngine.__init__)
         assert sig.parameters["tuner"].default is False
+
+
+def test_real_engine_keeps_token_parity_through_tuner_moves():
+    """Every knob shapes the schedule, never the numerics: a real engine
+    with the tuner on emits, for each request, the tokens plain
+    `generate` emits; each decision is one bounded step on a known knob;
+    no page leaks."""
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    from paddle_tpu.serving import ServingEngine
+
+    paddle.seed(0)
+    m = GPTForCausalLM(GPTConfig(
+        vocab_size=64, hidden_size=32, num_layers=2,
+        num_attention_heads=4, max_position_embeddings=96,
+        hidden_dropout_prob=0.0, attention_dropout_prob=0.0))
+    m.eval()
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(1, 64, (n,)).astype(np.int32)
+               for n in (5, 11, 19, 8, 14, 26, 7, 12)]
+    eng = ServingEngine(
+        m, max_slots=3, max_len=64, page_size=8, chunk_size=8, tuner=True,
+        tuner_kw={"interval": 4, "hysteresis": 2, "cooldown": 1}).warmup()
+    hs = [eng.submit(p, 6 + (i % 3) * 3) for i, p in enumerate(prompts)]
+    eng.run(max_steps=5000)
+    for h in hs:
+        ref = m.generate(np.asarray(h.request.prompt)[None],
+                         max_new_tokens=h.request.max_new_tokens,
+                         use_cache="paged")
+        assert np.asarray(ref._data)[0].tolist() == h.output_tokens, \
+            f"rid {h.request.rid} diverged"
+    assert eng.tuner.evaluations > 0
+    for d in eng.tuner.decisions:
+        assert d["knob"] in ("admit_watermark", "prefill_chunks_per_step",
+                             "chunk_size", "decode_burst"), d
+        if d["knob"] != "chunk_size":
+            assert abs(d["to"] - d["from"]) == 1, d
+    leaks = eng.leak_check()
+    assert leaks["free_pages"] == leaks["total_pages"], leaks

@@ -268,9 +268,9 @@ def test_hlo_reduce_scatter_per_chunk_and_no_full_grads(mesh):
     assert "f32[4,1,6248]" in txt          # the 1/N shard carry
     assert "f32[4,1,49984]" not in txt     # never the full grad stack
 
-    from paddle_tpu.jit.sharded_scan_selftest import _load_hlo_overlap
+    from paddle_tpu.observability.hlo_costs import load_hlo_overlap
 
-    verdict = _load_hlo_overlap().analyze(txt)
+    verdict = load_hlo_overlap().analyze(txt)
     assert verdict["counts"]["reduce-scatter"] >= 2
     assert verdict["overlap_ok"], verdict
 
@@ -278,7 +278,7 @@ def test_hlo_reduce_scatter_per_chunk_and_no_full_grads(mesh):
 def test_hlo_overlap_async_parser():
     """The checker's async branch (what TPU/GPU programs emit), on a
     synthetic scheduled module: start/done pair bracketing one fusion."""
-    from paddle_tpu.jit.sharded_scan_selftest import _load_hlo_overlap
+    from paddle_tpu.observability.hlo_costs import load_hlo_overlap
 
     hlo = """HloModule m, is_scheduled=true
 
@@ -290,7 +290,7 @@ def test_hlo_overlap_async_parser():
   ROOT %t = (f32[1]{0}, f32[8]{0}) tuple(%rsd, %f)
 }
 """
-    v = _load_hlo_overlap().analyze(hlo)
+    v = load_hlo_overlap().analyze(hlo)
     assert v["mode"] == "async"
     assert v["async_pairs"] == 1
     assert v["async_pairs_bracketing_compute"] == 1

@@ -3,9 +3,7 @@ stored as 1/N flat bucket shards, all-gathered on use inside the scans
 (double-buffered prefetch), written back as shards by the update scan —
 plus the quantized multi-axis collective legs, dropout under pp, and
 the resharding checkpoint restore. Runs on the conftest
-8-virtual-CPU-device host mesh. The heavyweight cross-mesh parity and
-HLO-receipt duplicates of the hermetic `sharded_storage` selftest lane
-are marked slow."""
+8-virtual-CPU-device host mesh."""
 import numpy as np
 import pytest
 
@@ -392,6 +390,38 @@ def test_planner_ep_grid_and_rules():
     assert all(c.pruned_reason for c in pruned_d if c.ep > 1)
 
 
+def test_armed_step_crash_fires_before_dispatch(mesh):
+    """`train.step.crash` raises at the step boundary, BEFORE the compiled
+    step is dispatched: no donated buffer is half-consumed, and the same
+    step object goes on to the uninterrupted run's losses."""
+    from paddle_tpu.observability import faults
+
+    ids, labels = _batch()
+
+    def build():
+        paddle.seed(0)
+        model = GPTForCausalLM(GPTConfig(**TINY, scan_layers=True))
+        opt = popt.AdamW(learning_rate=1e-2,
+                         parameters=model.parameters())
+        return ShardedFusedScanTrainStep(
+            model, opt, criterion=GPTPretrainingCriterion(), mesh=mesh,
+            axis="sharding")
+
+    step = build()
+    straight = [float(step(ids, labels)) for _ in range(3)]
+    step = build()
+    got = [float(step(ids, labels))]
+    inj = faults.install(0)
+    inj.arm("train.step.crash", message="armed by the test")
+    try:
+        with pytest.raises(faults.FaultError):
+            step(ids, labels)
+    finally:
+        faults.reset()
+    got += [float(step(ids, labels)) for _ in range(2)]
+    assert got == straight
+
+
 def test_planner_sharded_storage_memory_and_gather_term():
     from paddle_tpu.distributed.auto_tuner.tuner import (
         Candidate, ModelSpec, estimate_memory_gb, estimate_step_ms,
@@ -409,25 +439,49 @@ def test_planner_sharded_storage_memory_and_gather_term():
     assert estimate_step_ms(sh, c) > estimate_step_ms(rep, c)
 
 
-@pytest.mark.slow
 def test_hlo_no_full_param_buffer_receipt():
-    """Compiled-HLO receipt (duplicated by the hermetic selftest lane,
-    hence slow): the sharded-storage probe program holds no buffer the
-    size of even one stacked [L, ...] leaf, and its peak buffer is
-    strictly below the replicated program's."""
+    """Compiled-HLO receipt: the sharded-storage probe program holds no
+    buffer the size of the parameter set, nor of even ONE stacked
+    [L, ...] leaf (the replicated layout's storage unit: at most ~a layer
+    chunk's gathered params are live across chunk boundaries); its
+    largest buffer is strictly below the replicated program's; and every
+    all-gather is the param gather over the mesh's axis, nothing
+    unclassified."""
+    from paddle_tpu.jit.sharded_scan import build_probe_lowered
+    from paddle_tpu.linalg.distributed.probe import max_buffer_elems
+    from paddle_tpu.observability.hlo_costs import load_hlo_overlap
+
     denv.reset()
-    from paddle_tpu.jit.sharded_scan_selftest import param_storage_probe
+    # the probe model's parameter accounting (build_probe_lowered's config)
+    cfg = GPTConfig(vocab_size=128, hidden_size=64, num_layers=4,
+                    num_attention_heads=2, max_position_embeddings=32,
+                    hidden_dropout_prob=0.0, attention_dropout_prob=0.0,
+                    scan_layers=True)
+    paddle.seed(0)
+    trainable = [(n, p) for n, p in
+                 GPTForCausalLM(cfg).named_parameters() if p.trainable]
+    total_elems = sum(int(np.prod(p.shape)) for _, p in trainable)
+    largest_stacked = max(
+        int(np.prod(p.shape)) for n, p in trainable
+        if "blocks__" in n and p.ndim >= 1
+        and p.shape[0] == cfg.num_layers)
 
-    v = param_storage_probe()
-    assert v["param_storage_ok"], v
-    assert v["sharded"]["max_buffer_elems"] < \
-        v["replicated"]["max_buffer_elems"]
+    text = {storage: build_probe_lowered(
+        n_devices=N_DEV, scan_unroll=2,
+        param_storage=storage).compile().as_text()
+        for storage in ("sharded", "replicated")}
+    peak = {k: max_buffer_elems(t) for k, t in text.items()}
+    assert peak["sharded"] < largest_stacked < total_elems, peak
+    assert peak["sharded"] < peak["replicated"], peak
+    per_axis = load_hlo_overlap().analyze(
+        text["sharded"],
+        axis_degrees={"sharding": N_DEV})["per_axis_counts"]
+    assert per_axis["sharding"]["all-gather"] >= 1, per_axis
+    assert set(per_axis) == {"sharding"}, per_axis
 
 
-@pytest.mark.slow
 def test_bit_parity_hybrid_meshes():
-    """dp4×mp2 and dp2×pp2 sharded-vs-replicated storage parity
-    (duplicated by the hermetic selftest lane, hence slow)."""
+    """dp4×mp2 and dp2×pp2 sharded-vs-replicated storage parity."""
     from jax.sharding import Mesh
 
     devs = jax.devices("cpu")[:N_DEV]

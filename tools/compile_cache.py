@@ -18,8 +18,7 @@ Usage::
 ``--dir`` defaults to ``$PADDLE_TPU_COMPILE_CACHE``. ``evict`` accepts
 an unambiguous key prefix (keys are 32-hex). ``prune`` runs the same
 LRU cap enforcement the store applies online (``--max-mb`` overrides
-``$PADDLE_TPU_COMPILE_CACHE_MB``, default 512). `bench.py` calls
-`render_list`/`render_stats` for its cold-start lane report.
+``$PADDLE_TPU_COMPILE_CACHE_MB``, default 512).
 
 Exit codes: 0 ok / 1 usage or no cache dir / 3 evict target missing or
 ambiguous.

@@ -30,7 +30,7 @@ Two modes, chosen by what the backend emits:
 Every collective also contributes its RESULT-shape payload bytes to a
 per-kind and per-axis byte census (``bytes`` / ``total_comm_bytes`` /
 ``per_axis_bytes`` in the verdict) — the comm-bytes-per-step numbers
-ISSUE 12 pipes into BENCH records and the metrics registry.
+ISSUE 12 pipes into the metrics registry.
 
 Per-axis classification covers every COLLECTIVE_KINDS entry — including
 ``all-to-all`` (both the single-operand and the tuple form XLA emits for
@@ -42,19 +42,11 @@ scatter under ``dp+ep``.
 Standalone:
     python tools/hlo_overlap.py <hlo_text_file> [--assert-overlap]
     python tools/hlo_overlap.py --probe [--assert-overlap]
-    python tools/hlo_overlap.py --probe-ep
-    python tools/hlo_overlap.py --probe-param-gather [--mp 2 | --pp 2]
 `--probe` builds the sharded fused-scan train step on the host mesh
-(requires JAX_PLATFORMS=cpu + xla_force_host_platform_device_count, the
-bench.py _run_cpu_probe env) and analyzes its compiled HLO; `--probe-ep`
-builds the dp4×ep2 expert-parallel MoE variant and reports the ep-axis
-all-to-all census. `--probe-param-gather` (ISSUE 11) compiles the step
-under BOTH parameter-storage formats, classifies the param-gather
-all-gathers per mesh axis, and checks the sharded-storage liveness
-receipts: no full-parameter-set buffer, no stacked-leaf-sized buffer,
-peak buffer strictly below the replicated program's. Invoked by
-`bench.py --multichip` via paddle_tpu.jit.sharded_scan_selftest; the
-verdicts land in MULTICHIP_r*.json / BENCH_r*.json.
+(requires JAX_PLATFORMS=cpu + xla_force_host_platform_device_count) and
+analyzes its compiled HLO. The per-axis receipts of the other layouts
+are tests: tests/test_moe.py (ep all-to-alls), tests/test_sharded_storage.py
+(the param gather under both storage formats), tests/test_hybrid_parallel.py.
 """
 from __future__ import annotations
 
@@ -367,48 +359,6 @@ def _build_probe_hlo():
 def main(argv):
     do_assert = "--assert-overlap" in argv
     argv = [a for a in argv if a != "--assert-overlap"]
-    if "--probe-param-gather" in argv:
-        # ISSUE 11: sharded-vs-replicated parameter storage receipts —
-        # per-axis param-gather census + compiled-buffer liveness bounds
-        import os
-
-        root = os.path.dirname(os.path.dirname(os.path.abspath(
-            __file__)))
-        if root not in sys.path:
-            sys.path.insert(0, root)
-        from paddle_tpu.jit.sharded_scan_selftest import (
-            param_storage_probe,
-        )
-
-        def flag(name):
-            if name in argv:
-                return int(argv[argv.index(name) + 1])
-            return 1
-
-        verdict = param_storage_probe(mp=flag("--mp"), pp=flag("--pp"))
-        print(json.dumps(verdict))
-        if do_assert and not verdict.get("param_storage_ok"):
-            raise AssertionError(
-                f"param-storage receipt failed: {verdict}")
-        return 0
-    if "--probe-ep" in argv:
-        # dp4×ep2 MoE probe: per-axis census incl. the ep all-to-alls
-        import os
-
-        root = os.path.dirname(os.path.dirname(os.path.abspath(
-            __file__)))
-        if root not in sys.path:
-            sys.path.insert(0, root)
-        from paddle_tpu.jit.sharded_scan_selftest import (
-            hlo_overlap_probe,
-        )
-
-        verdict = hlo_overlap_probe(ep=2)
-        print(json.dumps(verdict))
-        if do_assert and not verdict.get("ep_dispatch_ok"):
-            raise AssertionError(
-                f"ep all-to-all receipt failed: {verdict}")
-        return 0
     if "--probe" in argv:
         text = _build_probe_hlo()
     elif argv:
