@@ -54,6 +54,9 @@ TINY = dict(tiny=True, seq=128, batch=4, steps=6, heads=2, hd=32,
 # the same vocab tiles in the same order on both sides: 1e-3.
 TOL_BF16 = 2e-2
 TOL_CE_LOSS = 1e-3
+# The index scores are float32 sums of the same exact bf16 products on
+# both sides, the heads in another order.
+TOL_INDEXER = 1e-4
 # one chip vs four chips, same global batch: per-step loss, bf16 compute,
 # different batch split and reduction order. tests/test_sharded_scan.py
 # uses rtol = atol = 5e-4 in fp32 on the CPU; widened here for bf16.
@@ -146,6 +149,28 @@ def phase_kernels(z):
     name = f"fused CE n={n} hidden={z.hidden} vocab={z.vocab}"
     agree(name + " loss", got[:1], want[:1], TOL_CE_LOSS)
     agree(name + " grads", got, want, TOL_BF16)
+
+    # the sparse indexer's scores of one query chunk and their pull-back
+    # at Keye's widths: a first chunk (fifteen key tiles of sixteen have
+    # no body), a middle one, the last
+    from paddle_tpu.ops import sparse_attention as spa
+    from paddle_tpu.ops.pallas import indexer_scores as isc
+
+    t, s, j, d = (32, 256, 4, 16) if z.tiny else (512, 8192, 16, 64)
+    qkw = [randn((t, j, d)), randn((s, d)), randn((t, j))]
+    for t0 in (0, s // 2 - t, s - t):
+        valid = jnp.arange(s)[None, :] <= t0 + jnp.arange(t)[:, None]
+        wgt = jnp.where(valid, randn((t, s), 1.0, f32), 0.0)
+        got = fwd_bwd(lambda q, k, w: jnp.where(
+            valid, isc.causal_indexer_scores(
+                q, k, w, jnp.int32(t0), interpret=z.tiny), 0.0), qkw, wgt)
+        want = fwd_bwd(lambda q, k, w: jnp.where(
+            valid, spa.indexer_scores(q, k, w), 0.0), qkw, wgt)
+        name = f"indexer scores q{[t, j, d]} k{[s, d]} t0={t0}"
+        agree(name, got[:1], want[:1], TOL_INDEXER)
+        agree(name + " grads", got, want, TOL_BF16)
+        ok(not np.asarray(got[2], np.float32)[t0 + t:].any(),
+           name + ": dk_idx beyond the chunk's last query is exactly 0")
 
     # serving attention: ragged decode + chunk, bf16 / int8 / int4 pools
     b, nh, d, ps = z.slots, z.heads, z.hd, z.page

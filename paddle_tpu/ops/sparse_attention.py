@@ -11,9 +11,12 @@ top-k per query are kept, and attention runs over the kept keys only
 The selection is handed on as an int8 mask [b, s, s] (one per token, not
 per head) that `ops.pallas.splash_attention(selection=)` reads tile by
 tile; nothing of size [s, s] per head is ever formed. Everything here is
-XLA, a query chunk of one sequence at a time, but the indexer's target
-(`ops/pallas/attention_probs.py`: the head-averaged probabilities of a
-chunk, whose [heads, chunk, s] logits XLA would write out and re-read):
+XLA, a query chunk of one sequence at a time, but the two things whose
+[heads, chunk, s] intermediate XLA would write out and re-read: the
+chunk's index scores with their pull-back (`ops/pallas/
+indexer_scores.py`, on TPU; `indexer_scores` below elsewhere) and the
+indexer's target (`ops/pallas/attention_probs.py`: the head-averaged
+probabilities of a chunk):
 
 * `topk_mask` finds each row's k-th largest score exactly, with no sort:
   32 counting passes over the order-preserving integer image of the
@@ -32,6 +35,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .pallas import indexer_scores as _kernels, routing
 from .pallas.attention_probs import head_mean_probs
 
 __all__ = ["mrope_angles", "apply_rotary", "indexer_scores", "topk_mask",
@@ -78,6 +82,22 @@ def indexer_scores(q_idx, k_idx, w):
     return jnp.einsum("tjs,tj->ts", jax.nn.relu(a), w.astype(F32))
 
 
+def _chunk_scores(q_idx, k_idx, w, t0):
+    """`indexer_scores` of the chunk whose first query is t0: on TPU the
+    kernel pair of `ops/pallas/indexer_scores.py`, which leaves key tiles
+    beyond the chunk's last query unwritten; the expression above
+    elsewhere, and for a geometry the kernels do not take."""
+    use_kernel, interpret = routing.route(
+        "indexer_scores", _kernels.supports(q_idx.shape, k_idx.shape,
+                                            q_idx.dtype),
+        (f"q{tuple(q_idx.shape)}", f"k{tuple(k_idx.shape)}",
+         str(q_idx.dtype)))
+    if not use_kernel:
+        return indexer_scores(q_idx, k_idx, w)
+    return _kernels.causal_indexer_scores(q_idx, k_idx, w, t0,
+                                          interpret=interpret)
+
+
 def _sortable(x):
     """float32 -> uint32 with the same order (-0.0 counted as 0.0)."""
     u = jax.lax.bitcast_convert_type(jnp.where(x == 0, F32(0), x), _U)
@@ -120,7 +140,8 @@ def _select_sequence(q, k, q_idx, k_idx, w, topk, chunk, scale, tokens):
     def step(dk_acc, xs):
         t0, qc, qic, wc = xs
         valid = cols[None, :] <= (t0 + jnp.arange(chunk, dtype=jnp.int32))[:, None]
-        scores, pull = jax.vjp(indexer_scores, qic, k_idx, wc)
+        scores, pull = jax.vjp(
+            functools.partial(_chunk_scores, t0=t0), qic, k_idx, wc)
         keep = topk_mask(scores, valid, topk)
         # the main attention's probabilities over the kept keys, the
         # mean over heads: the indexer's target, cut from the graph

@@ -398,6 +398,73 @@ def test_head_mean_probs_kernels_match_xla():
     np.testing.assert_allclose(got.sum(-1), 1.0, atol=1e-5)
 
 
+@pytest.mark.parametrize("t0,dtype", [
+    (160, jnp.float32),     # the causal edge inside key tile 1; tile 2 skipped
+    (0, jnp.float32),       # a first chunk: every tile but one skipped
+    (352, jnp.float32),     # the last chunk: every tile visited
+    (160, jnp.bfloat16),    # bf16 operands: the cotangent as exact addends
+])
+def test_indexer_scores_kernels_match_xla_and_its_vjp(t0, dtype):
+    from paddle_tpu.ops.pallas import indexer_scores as isc
+
+    rng = np.random.default_rng(12)
+    t, s, j, d, bk = 32, 384, 4, 16, 128
+    q, k, w = (jnp.asarray(rng.standard_normal(shape), dtype)
+               for shape in ((t, j, d), (s, d), (t, j)))
+    valid = jnp.arange(s)[None, :] <= t0 + jnp.arange(t)[:, None]
+    g = jnp.where(valid, jnp.asarray(rng.standard_normal((t, s)),
+                                     jnp.float32), 0.0)
+    want, pull = jax.vjp(sa.indexer_scores,
+                         *(x.astype(jnp.float32) for x in (q, k, w)))
+    got = isc.indexer_scores_fwd(q, k, w, jnp.int32(t0), bk, True)
+    np.testing.assert_allclose(jnp.where(valid, got, 0.0),
+                               jnp.where(valid, want, 0.0), atol=1e-5)
+    grads = isc.indexer_scores_bwd(q, k, w, jnp.int32(t0), g, bk, True)
+    for a, b_ in zip(grads, pull(g)):
+        np.testing.assert_allclose(a, b_, atol=1e-4)
+    beyond = ((t0 + t - 1) // bk + 1) * bk
+    assert not np.asarray(grads[1])[beyond:].any()     # exact zeros
+    # and joined by the custom VJP, cotangents in the operands' types
+    out, pull_k = jax.vjp(lambda *a: isc.causal_indexer_scores(
+        *a, jnp.int32(t0), bk, True), q, k, w)
+    np.testing.assert_array_equal(out, got)
+    for a, b_ in zip(pull_k(g), grads):
+        assert a.dtype == dtype
+        np.testing.assert_array_equal(a, b_.astype(dtype))
+
+
+def test_the_selection_through_the_kernels_is_the_xla_paths():
+    """`indexer_select` with its chunk scores from the interpreted kernel
+    pair (four chunks, so skipped tiles and a traced t0): the same picks,
+    loss and gradients as with the XLA expression."""
+    from paddle_tpu.utils import flags
+
+    rng = np.random.default_rng(13)
+    b, s, h, kvh, d, j, di = 1, 256, 2, 1, 16, 2, 16
+    q, k, qi, ki, w = (jnp.asarray(rng.standard_normal(shape), jnp.float32)
+                       for shape in ((b, s, h, d), (b, s, kvh, d),
+                                     (b, s, j, di), (b, s, di), (b, s, j)))
+
+    def run():
+        def loss(qi, ki, w):
+            sel, li, kept = sa.indexer_select(q, k, qi, ki, w, 24, 64)
+            return li, (sel, kept)
+        return jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                  has_aux=True)(qi, ki, w)
+
+    (li_x, (sel_x, kept_x)), grads_x = run()
+    flags.set_flags({"FLAGS_pallas_force_interpret": True})
+    try:
+        (li_k, (sel_k, kept_k)), grads_k = run()
+    finally:
+        flags.set_flags({"FLAGS_pallas_force_interpret": False})
+    np.testing.assert_array_equal(sel_k, sel_x)
+    assert int(kept_k) == int(kept_x)
+    np.testing.assert_allclose(li_k, li_x, rtol=1e-5)
+    for a, b_ in zip(grads_k, grads_x):
+        np.testing.assert_allclose(a, b_, atol=1e-6)
+
+
 @pytest.fixture(scope="module")
 def v5e_chip():
     """One chip of a DESCRIBED v5e (tests/test_fused_scan_step.py has the
@@ -428,11 +495,13 @@ def v5e_chip():
         compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("kernel", ["attn_probs", "splash_selection"])
+@pytest.mark.parametrize("kernel", ["attn_probs", "splash_selection",
+                                    "indexer_scores"])
 def test_the_new_kernels_compile_for_a_v5e_at_published_widths(v5e_chip,
                                                                kernel):
     from paddle_tpu.ops.pallas import routing
     from paddle_tpu.ops.pallas.attention_probs import head_mean_probs
+    from paddle_tpu.ops.pallas.indexer_scores import causal_indexer_scores
     from paddle_tpu.ops.pallas.splash_attention import splash_attention
 
     def spec(shape, dtype):
@@ -444,6 +513,15 @@ def test_the_new_kernels_compile_for_a_v5e_at_published_widths(v5e_chip,
             spec((512, 32, 128), bf16), spec((8192, 4, 128), bf16),
             spec((512, 8192), jnp.int8)), {"attn_probs_stats",
                                            "attn_probs_mean"}
+    elif kernel == "indexer_scores":
+        def fn(q, k, w, t0, g):
+            out, pull = jax.vjp(lambda *a: causal_indexer_scores(*a, t0),
+                                q, k, w)
+            return out, pull(g)
+        args, want = (spec((512, 16, 64), bf16), spec((8192, 64), bf16),
+                      spec((512, 16), bf16), spec((), jnp.int32),
+                      spec((512, 8192), jnp.float32)), {
+                          "indexer_scores_fwd", "indexer_scores_bwd"}
     else:
         def fn(q, k, v, sel):
             return jax.grad(lambda *a: jnp.sum(splash_attention(
@@ -456,4 +534,7 @@ def test_the_new_kernels_compile_for_a_v5e_at_published_widths(v5e_chip,
                                                          "splash_bwd"}
     compiled = jax.jit(fn).trace(*args).lower(
         lowering_platforms=("tpu",)).compile()
-    assert set(routing.mosaic_kernels(compiled.as_text())) == want
+    text = compiled.as_text()
+    assert set(routing.mosaic_kernels(text)) == want
+    # no per-head score tensor outside the kernels
+    assert "f32[512,16,8192]" not in text

@@ -19,7 +19,10 @@ its set-up alone. Prints one JSON line:
   `backend_compile`, which is the cache load in a warm run), the largest
   programs by name;
 * Pallas kernels by name: calls, seconds tracing the body (at bind) and
-  seconds lowering it to Mosaic text (inside `jaxpr_to_mlir`).
+  seconds lowering it to Mosaic text (inside `jaxpr_to_mlir`);
+* `step_text`, for a tape `TrainStep`: the Mosaic calls of the compiled
+  step by kernel name, `routing.xla_fallbacks`, its widest float32
+  buffers (read after the clock has stopped).
 
 `--root` names another checkout of the repo (the parent, unpacked beside
 this one) to run in this file's place. A time comes only from a chip run.
@@ -29,6 +32,7 @@ from __future__ import annotations
 import argparse
 import collections
 import json
+import math
 import os
 import sys
 import threading
@@ -36,6 +40,30 @@ import threading
 
 class _Done(Exception):
     pass
+
+
+def step_text(step, batch):
+    """Which kernels the tape step's compiled program holds (after the
+    clock has stopped: the text is the compile cache's copy): Mosaic
+    calls by kernel name, geometries that asked for a kernel and took the
+    XLA path, and the widest float32 buffers of rank >= 3."""
+    import re
+
+    import jax.numpy as jnp
+    from paddle_tpu.jit import train_step
+    from paddle_tpu.ops.pallas import routing
+
+    if type(step) is not train_step.TrainStep or not batch:
+        return None
+    text = step._jitted.lower(
+        step._extract_state(), jnp.asarray(step._opt.get_lr(), jnp.float32),
+        train_step._tree_data(list(batch))).compile().as_text()
+    wide = set(re.findall(r"f32\[\d+(?:,\d+){2,}\]", text))
+    return {"mosaic_kernels": dict(routing.mosaic_kernels(text)),
+            "xla_fallbacks": {f"{k} {g}": n for (k, g), n
+                              in routing.xla_fallbacks.items()},
+            "widest_f32": sorted(wide, key=lambda shape: math.prod(
+                map(int, re.findall(r"\d+", shape[3:]))))[-6:]}
 
 
 def main(argv=None):
@@ -169,6 +197,7 @@ def main(argv=None):
             finally:
                 add(f"step {self.k} wait for the loss", now() - t)
 
+    held = {}        # the step object and its last batch, for step_text()
     real_first = getattr(runner, "first_steps", None)
     if real_first is not None:
         def first_steps(cell, model, opt, step, *rest, **kw):
@@ -178,6 +207,7 @@ def main(argv=None):
             def call(*a, **k):
                 n = calls[0]
                 calls[0] += 1
+                held["batch"] = a
                 t = now()
                 out = step(*a, **k)
                 add(f"step {n} call", now() - t)
@@ -197,7 +227,8 @@ def main(argv=None):
             return thread
         runner.compile_reference_ahead = ahead
 
-    def stop(*a, **kw):
+    def stop(step=None, *a, **kw):
+        held["step"] = step
         raise _Done
     # the first thing every runner does after its set-up
     runner.executables = stop
@@ -235,6 +266,7 @@ def main(argv=None):
                                   "lower_s": round(lo, 3)}
                            for name, (n, tr, lo) in sorted(kernels.items())},
     }
+    line["step_text"] = step_text(held.get("step"), held.get("batch"))
     print("setup_phases: " + json.dumps(line), flush=True)
     # the prefetcher's and the reference's threads are daemons of a run
     # that would go on; there is nothing to flush
