@@ -16,7 +16,8 @@ XLA, a query chunk of one sequence at a time, but the two things whose
 chunk's index scores with their pull-back (`ops/pallas/
 indexer_scores.py`, on TPU; `indexer_scores` below elsewhere) and the
 indexer's target (`ops/pallas/attention_probs.py`: the head-averaged
-probabilities of a chunk):
+probabilities of a chunk). Both are handed the chunk's first position and
+visit no key tile beyond its last query:
 
 * `topk_mask` finds each row's k-th largest score exactly, with no sort:
   32 counting passes over the order-preserving integer image of the
@@ -146,7 +147,7 @@ def _select_sequence(q, k, q_idx, k_idx, w, topk, chunk, scale, tokens):
         # the main attention's probabilities over the kept keys, the
         # mean over heads: the indexer's target, cut from the graph
         selection = keep.astype(jnp.int8)
-        target = head_mean_probs(qc, k, selection, scale)
+        target = head_mean_probs(qc, k, selection, scale, t0)
         log_mine = jax.nn.log_softmax(
             jnp.where(keep, scores, -jnp.inf), axis=-1)
         kl = jnp.sum(jnp.where(

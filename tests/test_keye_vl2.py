@@ -398,6 +398,61 @@ def test_head_mean_probs_kernels_match_xla():
     np.testing.assert_allclose(got.sum(-1), 1.0, atol=1e-5)
 
 
+@pytest.mark.parametrize("t0,poison", [
+    (0, False),       # the first chunk: one tile of four visited
+    (128, False),     # its last query (191) falls inside tile 1
+    (192, False),     # it ends on tile 1's edge (255)
+    (448, False),     # the last chunk: every tile visited
+    (0, True), (128, True), (192, True),
+])
+def test_head_mean_probs_stops_at_the_chunks_last_causal_tile(t0, poison):
+    """`head_mean_probs(t0=)` over a 512-key sequence in four tiles, GQA
+    4 : 1: the XLA expression's target, the kernels' own without `t0` to
+    the bit, exact zeros beyond the last causal tile. Poisoned: NaN keys
+    and a full selection beyond that tile change nothing (not read)."""
+    from paddle_tpu.ops.pallas.attention_probs import (
+        head_mean_probs, head_mean_probs_xla)
+
+    rng = np.random.default_rng(14)
+    t, s, h, kvh, d, bk = 64, 512, 4, 1, 32, 128
+    q = jnp.asarray(rng.standard_normal((t, h, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((s, kvh, d)), jnp.float32)
+    valid = np.arange(s)[None, :] <= t0 + np.arange(t)[:, None]
+    sel = jnp.asarray((rng.random((t, s)) < 0.3) & valid,
+                      jnp.int8).at[:, 0].set(1)
+    beyond = ((t0 + t - 1) // bk + 1) * bk
+    want = head_mean_probs_xla(q, k, sel, 1.0 / d ** 0.5)
+    every_tile = head_mean_probs(q, k, sel, interpret=True, use_kernel=True,
+                                 block_k=bk)
+    if poison:
+        k, sel = k.at[beyond:].set(jnp.nan), sel.at[:, beyond:].set(1)
+    got = jax.jit(lambda *a: head_mean_probs(
+        *a[:3], t0=a[3], interpret=True, use_kernel=True, block_k=bk))(
+            q, k, sel, jnp.int32(t0))
+    np.testing.assert_array_equal(got, every_tile)
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    assert not np.asarray(got)[:, beyond:].any()       # exact zeros
+
+
+def test_visited_pairs_by_hand():
+    from paddle_tpu.ops.pallas.attention_probs import visited_pairs
+
+    t, s = 512, 8192
+    causal = s * (s + 1) // 2           # a sequence's, one head
+
+    def tiles(bk, causal_only=True):
+        return sum(visited_pairs(t, s, bk, t0 if causal_only else None)
+                   for t0 in range(0, s, t)) // (t * bk)
+
+    # 1, 1, 2, 2, .. 8, 8 of the 8 tiles a chunk has: 72 of 128
+    assert tiles(1024) == 72 and tiles(1024, False) == 128
+    assert tiles(512) == 136 and tiles(512, False) == 256      # 17 / 32
+    assert visited_pairs(t, s, t0=3584) == t * 1024 * 4    # _pick_block's
+    assert round(72 * t * 1024 / causal, 3) == 1.125
+    assert round(136 * t * 512 / causal, 4) == 1.0624
+    assert round(128 * t * 1024 / causal, 3) == 2.0
+
+
 @pytest.mark.parametrize("t0,dtype", [
     (160, jnp.float32),     # the causal edge inside key tile 1; tile 2 skipped
     (0, jnp.float32),       # a first chunk: every tile but one skipped
@@ -509,10 +564,11 @@ def test_the_new_kernels_compile_for_a_v5e_at_published_widths(v5e_chip,
 
     bf16 = jnp.bfloat16
     if kernel == "attn_probs":
-        fn, args, want = head_mean_probs, (
-            spec((512, 32, 128), bf16), spec((8192, 4, 128), bf16),
-            spec((512, 8192), jnp.int8)), {"attn_probs_stats",
-                                           "attn_probs_mean"}
+        def fn(q, k, sel, t0):
+            return head_mean_probs(q, k, sel, t0=t0)
+        args, want = (spec((512, 32, 128), bf16), spec((8192, 4, 128), bf16),
+                      spec((512, 8192), jnp.int8), spec((), jnp.int32)), {
+                          "attn_probs_stats", "attn_probs_mean"}
     elif kernel == "indexer_scores":
         def fn(q, k, w, t0, g):
             out, pull = jax.vjp(lambda *a: causal_indexer_scores(*a, t0),
