@@ -1,0 +1,8 @@
+"""Device self time a step under `jax.named_scope("window_attention")`:
+the sliding layers' attention, forward (twice, with per-layer recompute)
+and backward (harness/attention_scopes.py)."""
+from harness import attention_scopes
+
+
+def read(ctx):
+    return attention_scopes.ms(ctx, "window_attention")

@@ -36,3 +36,8 @@ from .keye_vl2 import (  # noqa: F401
     KeyeVL2Model,
     KeyeVL2ForCausalLM,
 )
+from .mellum2 import (  # noqa: F401
+    Mellum2Config,
+    Mellum2Model,
+    Mellum2ForCausalLM,
+)

@@ -158,6 +158,18 @@ def _differentiable_branch(c):
     return branch
 
 
+def routing_totals(rows, config) -> dict:
+    """Totals of a step's per-layer mixture counters `rows` int [layers,
+    >= 3] (pairs routed to held experts, rows computed, the fullest held
+    expert's pairs)."""
+    lo, hi = config.held_experts or (0, config.num_experts)
+    mean = rows[:, 0] / float(hi - lo)
+    return {"routed_pairs": int(rows[:, 0].sum()),
+            "computed_rows": int(rows[:, 1].sum()),
+            "max_load_over_mean": float(np.max(
+                rows[:, 2] / np.maximum(mean, 1e-30)))}
+
+
 class KeyeIndexer(nn.Layer):
     def __init__(self, c: KeyeVL2Config):
         super().__init__()
@@ -392,10 +404,5 @@ class KeyeVL2ForCausalLM(nn.Layer):
         (the fullest held expert of any layer over the mean load) and
         `kept_keys` (query-key pairs the selection kept)."""
         r = np.asarray(self.routing._data, np.int64)
-        lo, hi = self.config.held_experts or (0, self.config.num_experts)
-        mean = r[:, 0] / float(hi - lo)
-        return {"routed_pairs": int(r[:, 0].sum()),
-                "computed_rows": int(r[:, 1].sum()),
-                "max_load_over_mean": float(np.max(
-                    r[:, 2] / np.maximum(mean, 1e-30))),
-                "kept_keys": int(r[:, 3].sum())}
+        return dict(routing_totals(r, self.config),
+                    kept_keys=int(r[:, 3].sum()))

@@ -60,11 +60,30 @@ def supports(vocab, hidden, dtype) -> bool:
     return vocab % _LANES == 0
 
 
-def _pick_block_v(vocab):
-    for bv in (512, 256, _LANES):
-        if vocab % bv == 0:
+# what the backward may keep in VMEM beside its [bn, bv] score tiles: a
+# v5e's scoped limit is 16 MiB
+_VMEM_BUDGET = 14 * 2 ** 20
+
+
+def _bwd_vmem_bytes(bn, bv, hidden, itemsize):
+    """Bytes the backward kernel's blocks hold: h, W and dh twice each
+    (the pipeline's two buffers), the fp32 dW accumulator in and out
+    twice each, and dh's fp32 scratch."""
+    return hidden * (4 * bn * itemsize + 2 * bv * itemsize + 16 * bv
+                     + 4 * bn)
+
+
+def _pick_block_v(vocab, hidden=None, itemsize=2, bn=256):
+    """The widest vocab tile that divides `vocab` and, when the caller
+    says how wide the rows are, whose backward fits VMEM: at hidden 2304
+    a 512-row tile's two fp32 accumulator blocks alone are 19 MiB. The
+    narrowest is returned when none fits."""
+    fits = [bv for bv in (512, 256, _LANES) if vocab % bv == 0]
+    for bv in fits:
+        if hidden is None or _bwd_vmem_bytes(bn, bv, hidden,
+                                             itemsize) <= _VMEM_BUDGET:
             return bv
-    return None
+    return fits[-1] if fits else None
 
 
 def _pick_block_n(n):
@@ -429,7 +448,7 @@ def fused_cross_entropy(hidden, weight, labels, ignore_index=-100,
         impl = "interpret" if interpret else "pallas"
         bn = block_n if block_n is not None else _pick_block_n(n)
         if block_v is None:
-            block_v = _pick_block_v(vocab)
+            block_v = _pick_block_v(vocab, h, hidden.dtype.itemsize, bn)
     else:
         impl, bn = "xla", 1
         if block_v is None:

@@ -16,6 +16,12 @@ needed by the packed-sequence pretraining path (ROADMAP open item 2):
   (and causal). Its (block_q, block_k) tile is read beside the score
   tile and serves a kv head's whole query group; a row whose tile
   holds no selected key is the segment path's "dead" row.
+* **Sliding window**: `window` (a static int, with `causal`) keeps for
+  query t the keys s with 0 <= t - s < window. The grid of a windowed
+  call is the band's — `_band_steps` k tiles a q tile, the index maps
+  offset by the q tile — not the causal grid with steps skipped. The
+  backward's dK/dV accumulators get one plane a grid step, so that a k
+  block's visits by neighbouring q tiles never meet in one buffer.
 * **GQA**: `num_heads` a multiple of `num_kv_heads`. The group dim is
   folded into the q-row axis — q is laid out [b*kvh, grp*sq, d] with a
   kv head's `grp` query heads stacked back to back — so one grid pass
@@ -88,7 +94,7 @@ def supports(q_shape, num_kv_heads, dtype, sk=None) -> bool:
 # ---------------------------------------------------------------------------
 
 def splash_attention_xla(q, k, v, causal=True, segment_ids=None,
-                         scale=None, selection=None):
+                         scale=None, selection=None, window=None):
     """Reference-parity path: one dense masked attention (GQA via a
     grouped einsum). Rows with no valid key get zero output AND zero
     gradient (the whole-row zeroing below keeps AD away from the
@@ -109,6 +115,9 @@ def splash_attention_xla(q, k, v, causal=True, segment_ids=None,
         mask = mask & (seg[:, :, None] == segk[:, None, :])
     if selection is not None:
         mask = mask & (selection != 0)
+    if window is not None:
+        mask = mask & ~jnp.tril(jnp.ones((sq, sk), bool),
+                                k=sk - sq - window)[None]
     m5 = mask[:, None, None]                          # [b, 1, 1, sq, sk]
     any_valid = jnp.any(m5, axis=-1, keepdims=True)
     s = jnp.where(m5, s, -jnp.inf)
@@ -126,7 +135,7 @@ def splash_attention_xla(q, k, v, causal=True, segment_ids=None,
 # maps and `computed_pairs` all take it from here.
 # ---------------------------------------------------------------------------
 
-def _strips(block_q, block_k, causal, num_k):
+def _strips(block_q, block_k, causal, num_k, window=None):
     """Strips the tile on the diagonal is cut into (1: one masked tile).
     A function of the shapes alone, measured on a v5e (PERF.md, PR 28):
 
@@ -140,7 +149,7 @@ def _strips(block_q, block_k, causal, num_k):
       the MXU streams a strip's rows past each key tile it loads, and
       time follows the pairs only while those rows stay in the hundreds.
     """
-    if not causal or block_q != block_k or num_k != 1:
+    if not causal or block_q != block_k or num_k != 1 or window is not None:
         return 1
     return 2 if block_q % (2 * _LANES) == 0 else 1
 
@@ -162,15 +171,53 @@ def _last_tile(i, nqs, block_q, block_k):
     return _div(_rem(i, nqs) * block_q + (block_q - 1), block_k)
 
 
-def computed_pairs(sq, block_q=None, block_k=None, causal=True):
+def _band_back(window, block):
+    """k tiles a windowed q tile reaches back beyond its own (square
+    tiles of `block`): its first row's oldest key is window - 1 before."""
+    return (window + block - 2) // block
+
+
+def _band_steps(window, block, nqs):
+    """Grid steps along k of a windowed call: the widest band's tiles."""
+    return min(nqs, _band_back(window, block) + 1)
+
+
+def _band_tile(i, nqs, steps, step):
+    """The k tile grid step `step` of the windowed q tile `i` stands on:
+    the band ENDS on the q tile's own, so a q tile near the sequence's
+    start begins below tile 0, on steps that have no body. (Begun at
+    tile 0 instead, the first q tiles would all meet k tile 0 on step 0:
+    one block of one plane of the backward's accumulators, visits a grid
+    step or two apart, which the chip's self-check refused.)"""
+    return _rem(i, nqs) - (steps - 1) + step
+
+
+def _window_block(seq):
+    """Tile side of a windowed call (q and k tiles are square there):
+    `_pick_block`'s, measured on a v5e at (4, 8192, 32 / 4 x 128) bf16,
+    window 1024, forward + backward a call (PERF.md, PR 35): 28.0 ms on
+    1024-key tiles (2.0 x the band's pairs formed) against 32.7 on
+    512-key tiles (1.5 x); the causal call 61.6."""
+    return _pick_block(seq)
+
+
+def computed_pairs(sq, block_q=None, block_k=None, causal=True,
+                   window=None):
     """Score entries the forward kernel (and the backward at the same
     blocks) forms for one head over a self-attention sequence of `sq`;
-    the pairs the mask can keep are sq (sq + 1) / 2 under `causal`."""
-    bq = block_q or _pick_block(sq)
-    bk = block_k or _pick_block(sq)
+    the pairs the mask can keep are sq (sq + 1) / 2 under `causal`, and
+    under a `window` the band's: sum_t min(t + 1, window)."""
+    if window is None:
+        bq, bk = block_q or _pick_block(sq), block_k or _pick_block(sq)
+    else:
+        bq = bk = block_q or block_k or _window_block(sq)
     nqs, num_k = sq // bq, sq // bk
     if not causal:
         return sq * sq
+    if window is not None:
+        steps = _band_steps(window, bq, nqs)
+        return sum(_band_tile(i, nqs, steps, j) >= 0 for i in range(nqs)
+                   for j in range(steps)) * bq * bk
     n = _strips(bq, bk, causal, num_k)
     if n > 1:       # the one tile there is
         return bq * bk * (n + 1) // (2 * n)
@@ -188,10 +235,22 @@ def _lower_triangle(s):
     return jnp.where(row >= col, s, -jnp.inf)
 
 
+def _band_mask(s, row0, col0, window):
+    """Keep 0 <= row - col < window of a tile whose corner is (row0,
+    col0). Every tile compares: on the square tiles `_window_block` picks
+    each of a q tile's k tiles is crossed by one of the band's two edges,
+    and on narrower ones a `lax.cond` around the compare cost more than
+    the compares it saved on the tiles inside (PERF.md, PR 35)."""
+    ahead = (row0 - col0) + (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                             - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+    return jnp.where((ahead >= 0) & (ahead < window), s, -jnp.inf)
+
+
 def _scores(refs, rows, cols, scale, diag):
     """Masked fp32 scores of the tile's q rows [r0, r1) x keys [c0, c1)
     (static bounds). `diag`: None = no causal compare; (pos0, k0) =
-    compare by sequence position, the tile's corner traced; "square" =
+    compare by sequence position, the tile's corner traced; (pos0, k0,
+    window) = the same with a sliding window's far edge; "square" =
     a strip of the tile on the diagonal, whose upper right corner is the
     only part compared: a square of the strip's shorter side.
     segq tile: [bq, LANES] lane-replicated; segk tile: [SUB, bk]
@@ -210,6 +269,8 @@ def _scores(refs, rows, cols, scale, diag):
                 [_lower_triangle(s[:nc]), s[nc:]], axis=0)
         else:
             s = _lower_triangle(s)
+    elif diag is not None and len(diag) == 3:
+        s = _band_mask(s, *diag)
     elif diag is not None:
         s = _causal_mask(s, diag[0], diag[1], nr, nc)    # a whole tile
     if segq_ref is not None:
@@ -237,12 +298,47 @@ def _split_refs(refs, n_in, with_seg, with_sel):
     return lead, segq, segk, sel, rest
 
 
+def _k_steps(window, block, nqs, num_k):
+    """Grid steps along k a q tile takes: every k tile, or the band's."""
+    return num_k if window is None else _band_steps(window, block, nqs)
+
+
+def _k_tile(qi, nqs, block, num_k, window):
+    """(this grid step along k, the k tile it stands on, the steps a q
+    tile takes): the causal grid walks every k tile, a windowed one the
+    band's (`_band_tile`)."""
+    step, steps = pl.program_id(2), _k_steps(window, block, nqs, num_k)
+    return (step, step if window is None
+            else _band_tile(qi, nqs, steps, step), steps)
+
+
+def _visited(ki, pos0, block_q, block_k, window):
+    """Whether a causal grid step has a body: its k tile lies on or below
+    the diagonal; under a window, on or after the sequence's start."""
+    if window is None:
+        return ki * block_k <= pos0 + block_q - 1
+    return ki >= 0
+
+
+def _window_arg(window):
+    """The kernels' `window=` keyword, absent from a call without one:
+    its `functools.partial` is then the one such a call always had."""
+    return {} if window is None else {"window": window}
+
+
+def _corner(pos0, k0, causal, window):
+    """`_scores`' `diag` of a whole tile."""
+    if not causal:
+        return None
+    return (pos0, k0) if window is None else (pos0, k0, window)
+
+
 # ---------------------------------------------------------------------------
 # forward: online softmax over kv tiles, grid (b*kvh, qi, ki)
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(*refs, scale, causal, block_q, block_k, nqs, num_k, strips,
-                with_seg, with_sel=False):
+                with_seg, with_sel=False, window=None):
     (q_ref, k_ref, v_ref), segq_ref, segk_ref, sel_ref, rest = _split_refs(
         refs, 3, with_seg, with_sel)
     o_ref, lse_ref = rest[:2]
@@ -250,8 +346,9 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, nqs, num_k, strips,
     # a piece can be FULLY masked under segments or a selection (unlike
     # pure causal, where a row's first piece always holds the diagonal),
     # so m_new may still be -inf: exp(-inf - -inf) would poison the stats
-    # with nan — pin those rows' exponentials to 0 instead
-    may_die = with_seg or with_sel
+    # with nan — pin those rows' exponentials to 0 instead; under a
+    # window a row's first tile may hold none of its keys
+    may_die = with_seg or with_sel or window is not None
 
     def update(state, rows, c1, diag):
         """Online-softmax step of (m [r, LANES], l [r, LANES], acc [r, d])
@@ -300,10 +397,10 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, nqs, num_k, strips,
 
     acc_ref, m_ref, l_ref = rest[2:]
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step, ki, steps = _k_tile(qi, nqs, block_q, num_k, window)
     pos0 = _rem(qi, nqs) * block_q  # sequence position of the tile's row 0
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -312,20 +409,20 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, nqs, num_k, strips,
     def _step():
         m_ref[...], l_ref[...], acc_ref[...] = update(
             (m_ref[...], l_ref[...], acc_ref[...]), (0, block_q), block_k,
-            (pos0, ki * block_k) if causal else None)
+            _corner(pos0, ki * block_k, causal, window))
 
     if causal:      # a tile above the diagonal: no body, no copy (`_specs`)
-        pl.when(ki * block_k <= pos0 + block_q - 1)(_step)
+        pl.when(_visited(ki, pos0, block_q, block_k, window))(_step)
     else:
         _step()
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(step == steps - 1)
     def _finish():
         finish(0, block_q, (m_ref[...], l_ref[...], acc_ref[...]))
 
 
 def _specs(bq, bk, d, nqs, kvh, with_seg, with_sel, causal, num_k,
-           qi_base=0):
+           qi_base=0, window=None):
     """Block specs shared by forward and fused backward. q-side tiles
     (q/do/o/lse) index the [bh, grp*sq, ...] layout by grid dim 1; the
     segment planes recover (batch, seq-position) as (g // kvh,
@@ -335,17 +432,34 @@ def _specs(bq, bk, d, nqs, kvh, with_seg, with_sel, causal, num_k,
     visited index, and the aliased dK/dV accumulators (`spec_acc`), whose
     blocks pass through every step, park on ONE spare block past the keys
     (index `num_k`; `_acc_zeros`) — held at a visited block instead, the
-    pass-through would write that block's stale input over its sum."""
-    def last(i):
-        return _last_tile(qi_base + i, nqs, bq, bk)
+    pass-through would write that block's stale input over its sum.
+    Under a `window` grid step j stands on k tile `_band_tile`, a step
+    below tile 0 copying nothing either, and the accumulators have one
+    plane of num_k + 1 blocks a step: a k block is then met in plane j by
+    ONE q tile of a head, where in one plane the q tile after would meet
+    it a grid step or two later, before its sum is written back."""
+    if window is not None:
+        steps = _band_steps(window, bq, nqs)
+        zero = np.int32(0)
 
-    def kv(i, j):
-        return jax.lax.min(j, last(i)) if causal else j
+        def kv(i, j):       # a step below tile 0 holds tile 0: the next
+            return jax.lax.max(_band_tile(qi_base + i, nqs, steps, j), zero)
 
-    def acc(i, j):
-        if not causal:
-            return j
-        return jax.lax.select(jax.lax.gt(j, last(i)), np.int32(num_k), j)
+        def acc(i, j):
+            t = _band_tile(qi_base + i, nqs, steps, j)
+            return (jax.lax.select(jax.lax.lt(t, zero), np.int32(num_k), t)
+                    + j * np.int32(num_k + 1))
+    else:
+        def last(i):
+            return _last_tile(qi_base + i, nqs, bq, bk)
+
+        def kv(i, j):
+            return jax.lax.min(j, last(i)) if causal else j
+
+        def acc(i, j):
+            if not causal:
+                return j
+            return jax.lax.select(jax.lax.gt(j, last(i)), np.int32(num_k), j)
 
     spec_q = pl.BlockSpec((1, bq, d), lambda g, i, j: (g, i, _Z))
     spec_k = pl.BlockSpec((1, bk, d), lambda g, i, j: (g, kv(i, j), _Z))
@@ -366,31 +480,41 @@ def _specs(bq, bk, d, nqs, kvh, with_seg, with_sel, causal, num_k,
     return spec_q, spec_k, spec_acc, spec_lse, seg
 
 
-def _acc_zeros(bh, sk, bk, d, causal):
+def _acc_zeros(bh, sk, bk, d, causal, planes=1):
     """A fresh fp32 dK or dV accumulator: the keys' rows and, under
-    `causal`, the spare block that steps above the diagonal park on."""
-    return jnp.zeros((bh, sk + (bk if causal else 0), d), jnp.float32)
+    `causal`, the spare block that steps above the diagonal park on;
+    `planes` of them back to back for a windowed call (`_specs`)."""
+    return jnp.zeros((bh, planes * (sk + (bk if causal else 0)), d),
+                     jnp.float32)
+
+
+def _acc_sum(acc, sk, planes, dtype):
+    """The keys' rows of an accumulator, its planes added."""
+    if planes > 1:
+        acc = jnp.sum(acc.reshape(acc.shape[0], planes, -1, acc.shape[2]), 1)
+    return acc[:, :sk].astype(dtype)
 
 
 def _fwd(q, k, v, segq, segk, scale, causal, bq, bk, sq, kvh, with_seg,
-         interpret, sel=None):
+         interpret, sel=None, window=None):
     bh, sq_all, d = q.shape
     sk = k.shape[1]
     nqs, num_k = sq // bq, sk // bk
     with_sel = sel is not None
     spec_q, spec_k, _, spec_lse, seg_specs = _specs(
-        bq, bk, d, nqs, kvh, with_seg, with_sel, causal, num_k)
-    strips = _strips(bq, bk, causal, num_k)
+        bq, bk, d, nqs, kvh, with_seg, with_sel, causal, num_k,
+        window=window)
+    strips = _strips(bq, bk, causal, num_k, window)
     kern = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
         nqs=nqs, num_k=num_k, strips=strips, with_seg=with_seg,
-        with_sel=with_sel)
+        with_sel=with_sel, **_window_arg(window))
     args = ([q, k, v] + ([segq, segk] if with_seg else [])
             + ([sel] if with_sel else []))
     out, lse = routing.pallas_call(
         kern,
         name="splash_fwd",
-        grid=(bh, sq_all // bq, num_k),
+        grid=(bh, sq_all // bq, _k_steps(window, bq, nqs, num_k)),
         in_specs=[spec_q, spec_k, spec_k] + seg_specs,
         out_specs=[spec_q, spec_lse],
         out_shape=[
@@ -414,7 +538,7 @@ def _fwd(q, k, v, segq, segk, scale, causal, bq, bk, sq, kvh, with_seg,
 # ---------------------------------------------------------------------------
 
 def _bwd_kernel(*refs, scale, causal, block_q, block_k, nqs, num_k, strips,
-                with_seg, qi_base, once, with_sel=False):
+                with_seg, qi_base, once, with_sel=False, window=None):
     lead, segq_ref, segk_ref, sel_ref, rest = _split_refs(
         refs, 6, with_seg, with_sel)
     q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref = lead
@@ -431,7 +555,7 @@ def _bwd_kernel(*refs, scale, causal, block_q, block_k, nqs, num_k, strips,
         dv_ref[0] = dvi_ref[0]
     mask_refs = (q_ref, k_ref, segq_ref, segk_ref, sel_ref)
     qi = qi_base + pl.program_id(1)
-    ki = pl.program_id(2)
+    step, ki, steps = _k_tile(qi, nqs, block_q, num_k, window)
     pos0 = _rem(qi, nqs) * block_q
 
     def gradients(rows, cols, diag):
@@ -475,14 +599,14 @@ def _bwd_kernel(*refs, scale, causal, block_q, block_k, nqs, num_k, strips,
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
         return
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
         _delta()
 
     def _step():
         dq, dk, dv = gradients((0, block_q), (0, block_k),
-                               (pos0, ki * block_k) if causal else None)
+                               _corner(pos0, ki * block_k, causal, window))
         if once:
             dk_ref[0] = dk.astype(dk_ref.dtype)
             dv_ref[0] = dv.astype(dv_ref.dtype)
@@ -492,18 +616,18 @@ def _bwd_kernel(*refs, scale, causal, block_q, block_k, nqs, num_k, strips,
         dq_acc[...] += dq
 
     if causal:
-        pl.when(ki * block_k <= pos0 + block_q - 1)(_step)
+        pl.when(_visited(ki, pos0, block_q, block_k, window))(_step)
     else:
         _step()
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(step == steps - 1)
     def _finish():
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _bwd_call(q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
               causal, bq, bk, sq, kvh, with_seg, num_q, qi_base,
-              interpret, sel=None):
+              interpret, sel=None, window=None):
     bh, _, d = q.shape
     sk = k.shape[1]
     nqs, num_k = sq // bq, sk // bk
@@ -517,12 +641,14 @@ def _bwd_call(q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
     once = dk_acc is None
     with_sel = sel is not None
     spec_q, spec_k, spec_acc, spec_lse, seg_specs = _specs(
-        bq, bk, d, nqs, kvh, with_seg, with_sel, causal, num_k, qi_base)
+        bq, bk, d, nqs, kvh, with_seg, with_sel, causal, num_k, qi_base,
+        window)
     kern = functools.partial(
         _bwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
         nqs=nqs, num_k=num_k,
-        strips=_strips(bq, bk, causal, num_k) if once else 1,
-        with_seg=with_seg, qi_base=qi_base, once=once, with_sel=with_sel)
+        strips=_strips(bq, bk, causal, num_k, window) if once else 1,
+        with_seg=with_seg, qi_base=qi_base, once=once, with_sel=with_sel,
+        **_window_arg(window))
     n_in = 6 + (2 if with_seg else 0) + (1 if with_sel else 0)
     args = ([q, k, v, do, out, lse]
             + ([segq, segk] if with_seg else [])
@@ -533,7 +659,7 @@ def _bwd_call(q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
     return routing.pallas_call(
         kern,
         name="splash_bwd",
-        grid=(bh, num_q, num_k),
+        grid=(bh, num_q, _k_steps(window, bq, nqs, num_k)),
         in_specs=[spec_q, spec_k, spec_k, spec_q, spec_q, spec_lse]
         + seg_specs + ([] if once else [spec_acc, spec_acc]),
         out_specs=[spec_q, spec_acc, spec_acc],
@@ -554,7 +680,7 @@ def _bwd_call(q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
 
 def _bwd_rowloop(q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
                  causal, bq, bk, sq, kvh, with_seg, num_q, interpret,
-                 sel=None):
+                 sel=None, window=None):
     """Hazard-free backward: one q-row per pallas call, threading dk/dv
     through as aliased call inputs (each aliased block visited once per
     call) — interpret mode replays revisited aliased blocks from the
@@ -574,7 +700,7 @@ def _bwd_rowloop(q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
         dq_row, dk_acc, dv_acc = _bwd_call(
             sl(q), k, v, sl(do), sl(out), sl(lse), sq_seg, segk,
             dk_acc, dv_acc, scale, causal, bq, bk, sq, kvh, with_seg,
-            1, qi, interpret, sel=sel_rows)
+            1, qi, interpret, sel=sel_rows, window=window)
         dq_rows.append(dq_row)
     return jnp.concatenate(dq_rows, axis=1), dk_acc, dv_acc
 
@@ -582,7 +708,7 @@ def _bwd_rowloop(q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
 _alias_checked: set = set()
 
 
-def _alias_selfcheck(dtype, d, scale, causal, bq, bk, sk):
+def _alias_selfcheck(dtype, d, scale, causal, bq, bk, sk, window=None):
     """One-time (per config, per process) on-device check of the fused
     full-grid backward against the hazard-free per-row path — the
     flash_attention.py guard applied to the splash kernels, so a Mosaic
@@ -590,30 +716,41 @@ def _alias_selfcheck(dtype, d, scale, causal, bq, bk, sk):
     fails loudly instead of training on wrong gradients."""
     from ...utils import flags as _flags
 
-    key = (str(dtype), d, causal, bq, bk, sk)
+    key = (str(dtype), d, causal, bq, bk, sk) + (
+        () if window is None else (window,))
     if key in _alias_checked or not _flags.get_flag(
             "FLAGS_pallas_alias_selfcheck"):
         return
-    sq = 2 * bq   # >= 2 q rows so every kv block is revisited
+    # >= 2 q rows so every kv block is revisited; under a window two
+    # heads (a plane's block is met once a head) of a sequence one tile
+    # longer than the band, so that no q tile's band is cut short
+    sq, grp = 2 * bq, 1
+    if window is not None:
+        sq = sk = min(sk, (_band_back(window, bq) + 2) * bq)
+        grp = 2
+    planes = _k_steps(window, bq, sq // bq, 1)
 
     def _run():
         rng = np.random.default_rng(0)
         mk = lambda s: jnp.asarray(  # noqa: E731
             rng.standard_normal((1, s, d)) * 0.5, dtype)
-        q, do = mk(sq), mk(sq)
+        q, do = mk(grp * sq), mk(grp * sq)
         k, v = mk(sk), mk(sk)
         out, lse = _fwd(q, k, v, None, None, scale, causal, bq, bk, sq,
-                        1, False, False)
-        z = lambda: _acc_zeros(1, sk, bk, d, causal)  # noqa: E731
+                        1, False, False, window=window)
+        z = lambda: _acc_zeros(1, sk, bk, d, causal, planes)  # noqa: E731
         f = _bwd_call(q, k, v, do, out, lse, None, None, z(), z(),
                       scale, causal, bq, bk, sq, 1, False,
-                      sq // bq, 0, False)
+                      grp * sq // bq, 0, False, window=window)
         r = _bwd_rowloop(q, k, v, do, out, lse, None, None, z(), z(),
                          scale, causal, bq, bk, sq, 1, False,
-                         sq // bq, False)
-        return {n: float(jnp.max(jnp.abs(a[:, :sk].astype(jnp.float32)
-                                         - b[:, :sk].astype(jnp.float32))))
-                for n, a, b in zip(("dq", "dk", "dv"), f, r)}
+                         grp * sq // bq, False, window=window)
+        f32 = jnp.float32
+        errs = {"dq": jnp.max(jnp.abs(f[0].astype(f32) - r[0].astype(f32)))}
+        for n, a, b in zip(("dk", "dv"), f[1:], r[1:]):
+            errs[n] = jnp.max(jnp.abs(_acc_sum(a, sk, planes, f32)
+                                      - _acc_sum(b, sk, planes, f32)))
+        return {n: float(e) for n, e in errs.items()}
 
     # run eagerly even when tracing (fresh thread has no trace context)
     import concurrent.futures
@@ -632,7 +769,7 @@ def _alias_selfcheck(dtype, d, scale, causal, bq, bk, sk):
 
 
 def _bwd(q, k, v, out, lse, do, segq, segk, scale, causal, bq, bk, sq,
-         kvh, with_seg, interpret, sel=None):
+         kvh, with_seg, interpret, sel=None, window=None):
     bh, sq_all, d = q.shape
     sk = k.shape[1]
     num_q = sq_all // bq
@@ -640,30 +777,40 @@ def _bwd(q, k, v, out, lse, do, segq, segk, scale, causal, bq, bk, sq,
         return _bwd_call(
             q, k, v, do, out, lse, segq, segk, None, None, scale,
             causal, bq, bk, sq, kvh, with_seg, num_q, 0, interpret,
-            sel=sel)
-    # shrink the backward k-block until the aliased-revisit distance is
-    # safe (the forward keeps its own block_k: no aliased accumulators)
-    bkb = bk
-    while sk // bkb < _REVISIT_MIN and bkb % 2 == 0 \
-            and (bkb // 2) % _LANES == 0 and sk % (bkb // 2) == 0:
-        bkb //= 2
-    fused = not interpret and sk // bkb >= _REVISIT_MIN
+            sel=sel, window=window)
+    planes = 1
+    if window is None:
+        # shrink the backward k-block until the aliased-revisit distance
+        # is safe (the forward keeps its own block_k: no aliased
+        # accumulators)
+        bkb = bk
+        while sk // bkb < _REVISIT_MIN and bkb % 2 == 0 \
+                and (bkb // 2) % _LANES == 0 and sk % (bkb // 2) == 0:
+            bkb //= 2
+        fused = not interpret and sk // bkb >= _REVISIT_MIN
+        if fused:
+            bk = bkb
+    else:
+        # a plane's k block is met once a head, by the same q tile of the
+        # next head of the group a whole head's grid steps later
+        planes = _band_steps(window, bq, sq // bq)
+        fused = not interpret and (
+            num_q == sq // bq or sq // bq * planes >= _REVISIT_MIN)
+    dk_acc = _acc_zeros(bh, sk, bk, d, causal, planes)
+    dv_acc = _acc_zeros(bh, sk, bk, d, causal, planes)
     if fused:
-        bk = bkb
-    dk_acc = _acc_zeros(bh, sk, bk, d, causal)
-    dv_acc = _acc_zeros(bh, sk, bk, d, causal)
-    if fused:
-        _alias_selfcheck(q.dtype, d, scale, causal, bq, bk, sk)
+        _alias_selfcheck(q.dtype, d, scale, causal, bq, bk, sk, window)
         dq, dk_acc, dv_acc = _bwd_call(
             q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
             causal, bq, bk, sq, kvh, with_seg, num_q, 0, interpret,
-            sel=sel)
+            sel=sel, window=window)
     else:
         dq, dk_acc, dv_acc = _bwd_rowloop(
             q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
-            causal, bq, bk, sq, kvh, with_seg, num_q, interpret, sel=sel)
-    return (dq, dk_acc[:, :sk].astype(k.dtype),
-            dv_acc[:, :sk].astype(v.dtype))
+            causal, bq, bk, sq, kvh, with_seg, num_q, interpret, sel=sel,
+            window=window)
+    return (dq, _acc_sum(dk_acc, sk, planes, k.dtype),
+            _acc_sum(dv_acc, sk, planes, v.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -671,26 +818,27 @@ def _bwd(q, k, v, out, lse, do, segq, segk, scale, causal, bq, bk, sq,
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11,
-                                                    12, 13))
+                                                    12, 13, 14))
 def _splash(q, k, v, segq, segk, sel, scale, causal, bq, bk, sq, kvh,
-            with_seg, interpret):
+            with_seg, interpret, window=None):
     out, _ = _fwd(q, k, v, segq, segk, scale, causal, bq, bk, sq, kvh,
-                  with_seg, interpret, sel=sel)
+                  with_seg, interpret, sel=sel, window=window)
     return out
 
 
 def _splash_fwd(q, k, v, segq, segk, sel, scale, causal, bq, bk, sq, kvh,
-                with_seg, interpret):
+                with_seg, interpret, window):
     out, lse = _fwd(q, k, v, segq, segk, scale, causal, bq, bk, sq, kvh,
-                    with_seg, interpret, sel=sel)
+                    with_seg, interpret, sel=sel, window=window)
     return out, (q, k, v, segq, segk, sel, out, lse)
 
 
-def _splash_bwd(scale, causal, bq, bk, sq, kvh, with_seg, interpret,
+def _splash_bwd(scale, causal, bq, bk, sq, kvh, with_seg, interpret, window,
                 res, do):
     q, k, v, segq, segk, sel, out, lse = res
     dq, dk, dv = _bwd(q, k, v, out, lse, do, segq, segk, scale, causal,
-                      bq, bk, sq, kvh, with_seg, interpret, sel=sel)
+                      bq, bk, sq, kvh, with_seg, interpret, sel=sel,
+                      window=window)
 
     def no_grad(ints):
         return (None if ints is None
@@ -704,10 +852,12 @@ _splash.defvjp(_splash_fwd, _splash_bwd)
 
 def splash_attention(q, k, v, causal=True, segment_ids=None, scale=None,
                      block_q=None, block_k=None, interpret=None,
-                     use_kernel=None, selection=None):
+                     use_kernel=None, selection=None, window=None):
     """Splash training attention (see module docstring for layouts).
     `selection`, int8 [batch, sq, sk], keeps for every head of a query
     only the keys where it is non-zero (and causal, when `causal`).
+    `window`, a static int with `causal`, keeps of query t's keys the
+    `window` latest: those with 0 <= t - s < window.
 
     Routes to the Pallas kernel on TPU when the geometry qualifies
     (`supports`), the XLA dense fallback otherwise. `interpret=True`
@@ -720,6 +870,13 @@ def splash_attention(q, k, v, causal=True, segment_ids=None, scale=None,
         raise ValueError("causal splash attention needs equal seq lens")
     if h % kvh:
         raise ValueError(f"num_heads {h} not a multiple of kv heads {kvh}")
+    if window is not None:
+        if selection is not None or not causal:
+            raise ValueError("a sliding window takes causal attention and "
+                             "no selection")
+        window = int(window)
+        if window < 1:
+            raise ValueError(f"window {window} keeps no key")
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     use_kernel, interpret = routing.route(
@@ -729,8 +886,10 @@ def splash_attention(q, k, v, causal=True, segment_ids=None, scale=None,
     if not use_kernel:
         return splash_attention_xla(q, k, v, causal=causal,
                                     segment_ids=segment_ids, scale=scale,
-                                    selection=selection)
+                                    selection=selection, window=window)
     grp = h // kvh
+    if window is not None:      # square tiles (`_band_back`)
+        block_q = block_k = block_q or block_k or _window_block(sq)
     if block_q is None:
         block_q = _pick_block(sq)
     if block_k is None:
@@ -753,5 +912,5 @@ def splash_attention(q, k, v, causal=True, segment_ids=None, scale=None,
     sel = None if selection is None else selection.astype(jnp.int8)
     out2 = _splash(q2, k2, v2, segq, segk, sel, float(scale), bool(causal),
                    int(block_q), int(block_k), int(sq), int(kvh),
-                   with_seg, bool(interpret))
+                   with_seg, bool(interpret), window)
     return jnp.transpose(out2.reshape(b, kvh * grp, sq, d), (0, 2, 1, 3))

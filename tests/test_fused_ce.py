@@ -183,6 +183,22 @@ def test_supports_gate():
     assert not fce.supports(256, 32, jnp.int32)
 
 
+@pytest.mark.parametrize("vocab,hidden,itemsize,want", [
+    (50304, 2048, 2, 128),      # the GPT cells' head: 128 alone divides it
+    (50304, 1024, 2, 128),
+    (24576, 1024, 2, 512),      # narrow rows: the widest tile fits
+    (24576, 2304, 2, 128),      # 512 rows: 25.5 MiB of a v5e's 16 (PR 35)
+    (24576, 2304, 4, 128),      # nothing fits: the narrowest
+    (1000, 64, 4, None),
+])
+def test_the_vocab_tile_fits_the_backwards_vmem(vocab, hidden, itemsize,
+                                                want):
+    assert fce._pick_block_v(vocab, hidden, itemsize) == want
+    if want is not None:        # a caller that gives no width: as before
+        assert fce._pick_block_v(vocab) == max(
+            bv for bv in (512, 256, 128) if vocab % bv == 0)
+
+
 def test_cross_entropy_soft_label_ignore_index_raises():
     """Reference parity regression (ISSUE 7 satellite): ignore_index has
     no meaning for soft labels — the reference raises, we silently

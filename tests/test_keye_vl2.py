@@ -551,10 +551,17 @@ def v5e_chip():
 
 
 @pytest.mark.parametrize("kernel", ["attn_probs", "splash_selection",
-                                    "indexer_scores"])
+                                    "indexer_scores", "splash_window",
+                                    "fused_ce_2304"])
 def test_the_new_kernels_compile_for_a_v5e_at_published_widths(v5e_chip,
                                                                kernel):
+    """The two last are the window + mixture block's (tests/
+    test_mellum2.py; kept here because one process describes the chip):
+    the banded splash kernels at its geometry, and fused CE at hidden
+    2304 over a 24,576-row head, where a 512-row vocabulary tile's
+    backward asked for 25.5 MiB of a v5e's 16."""
     from paddle_tpu.ops.pallas import routing
+    from paddle_tpu.ops.pallas.fused_cross_entropy import fused_cross_entropy
     from paddle_tpu.ops.pallas.attention_probs import head_mean_probs
     from paddle_tpu.ops.pallas.indexer_scores import causal_indexer_scores
     from paddle_tpu.ops.pallas.splash_attention import splash_attention
@@ -578,6 +585,22 @@ def test_the_new_kernels_compile_for_a_v5e_at_published_widths(v5e_chip,
                       spec((512, 16), bf16), spec((), jnp.int32),
                       spec((512, 8192), jnp.float32)), {
                           "indexer_scores_fwd", "indexer_scores_bwd"}
+    elif kernel == "splash_window":
+        def fn(q, k, v):
+            return jax.grad(lambda *a: jnp.sum(splash_attention(
+                *a, causal=True, window=1024).astype(jnp.float32)),
+                argnums=(0, 1, 2))(q, k, v)
+        args, want = (spec((4, 8192, 32, 128), bf16),
+                      spec((4, 8192, 4, 128), bf16),
+                      spec((4, 8192, 4, 128), bf16)), {"splash_fwd",
+                                                       "splash_bwd"}
+    elif kernel == "fused_ce_2304":
+        def fn(h, w, labels):
+            return jax.grad(lambda h, w: jnp.sum(fused_cross_entropy(
+                h, w, labels)), argnums=(0, 1))(h, w)
+        args, want = (spec((32768, 2304), bf16), spec((24576, 2304), bf16),
+                      spec((32768,), jnp.int32)), {"fused_ce_fwd",
+                                                   "fused_ce_bwd"}
     else:
         def fn(q, k, v, sel):
             return jax.grad(lambda *a: jnp.sum(splash_attention(

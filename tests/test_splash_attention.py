@@ -375,6 +375,175 @@ class TestSetUpBudget:
         assert fwd <= ceiling[0] and bwd <= ceiling[1], (fwd, bwd)
 
 
+# -- a sliding window (PR 35): the band's grid, its two edges, the planes of
+# the backward's accumulators ----------------------------------------------
+
+def _dense_window(q, k, v, window, seg=None):
+    """Dense masked softmax attention of keys 0 <= t - s < window, in
+    plain jnp: neither of the module's two paths."""
+    b, s, h, d = q.shape
+    grp = h // k.shape[2]
+    ahead = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
+    mask = ((ahead >= 0) & (ahead < window))[None]
+    if seg is not None:
+        mask = mask & (seg[:, :, None] == seg[:, None, :])
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, grp, 2),
+                        precision=HP) / d ** 0.5
+    prob = jax.nn.softmax(jnp.where(mask[:, None], scores, -jnp.inf), -1)
+    return jnp.einsum("bhqk,bkhd->bqhd", prob, jnp.repeat(v, grp, 2),
+                      precision=HP)
+
+
+class TestSlidingWindow:
+    # tiles of 128 in a sequence of 512: a window smaller than the tile,
+    # equal to it, no multiple of it, wider than the sequence, and of one
+    @pytest.mark.parametrize("window", [64, 128, 200, 384, 600, 1])
+    @pytest.mark.parametrize("group,masks", [(1, "plain"), (4, "plain"),
+                                             (2, "segments")])
+    def test_kernel_xla_and_dense_agree(self, window, group, masks):
+        q, k, v = _rand(1, 512, group, 1, 64, seed=21)
+        seg = _segments(1, 512, 3, seed=22) if masks == "segments" else None
+        got = _assert_parity(q, k, v, causal=True, window=window,
+                             segment_ids=seg, block_q=128, block_k=128)
+        want = _grads(lambda q, k, v: _dense_window(q, k, v, window, seg),
+                      q, k, v)
+        for a, b in zip(got, want):
+            assert float(jnp.max(jnp.abs(a - b))) < 5e-4
+
+    def test_two_batch_rows_and_kv_heads(self):
+        q, k, v = _rand(2, 256, 4, 2, 32, seed=23)
+        _assert_parity(q, k, v, causal=True, window=100, block_q=128,
+                       block_k=128)
+
+    def test_a_window_of_the_whole_sequence_is_causal_attention(self):
+        q, k, v = _rand(1, 256, 2, 1, 64, seed=24)
+        got = _grads(lambda q, k, v: sa.splash_attention(
+            q, k, v, window=256, interpret=True, block_q=128, block_k=128),
+            q, k, v)
+        want = _grads(lambda q, k, v: sa.splash_attention(
+            q, k, v, interpret=True, block_q=128, block_k=128), q, k, v)
+        for a, b in zip(got, want):
+            assert float(jnp.max(jnp.abs(a - b))) < 1e-5
+
+    @pytest.mark.parametrize("masks", ["plain", "segments"])
+    def test_no_window_is_bit_equal_to_the_call_without_the_argument(
+            self, masks):
+        q, k, v = _rand(1, 512, 2, 1, 64, seed=25)
+        kw = ({"segment_ids": _segments(1, 512, 3, seed=26)}
+              if masks == "segments" else {})
+        for blocks in ({}, {"block_q": 128, "block_k": 128}):
+            a = _grads(lambda q, k, v: sa.splash_attention(
+                q, k, v, interpret=True, window=None, **blocks, **kw),
+                q, k, v)
+            b = _grads(lambda q, k, v: sa.splash_attention(
+                q, k, v, interpret=True, **blocks, **kw), q, k, v)
+            for x, y in zip(a, b):
+                assert np.asarray(x).tobytes() == np.asarray(y).tobytes()
+
+    def test_no_window_traces_the_kernels_the_parent_traced(self):
+        """`window=None` adds no keyword to the kernels' partials and no
+        equation to their bodies (the three cells' programs must not
+        change)."""
+        q, k, v = (jax.ShapeDtypeStruct((1, 512, 2, 64), jnp.float32),) * 3
+
+        def run(window):
+            return str(jax.make_jaxpr(lambda q, k, v: jax.vjp(
+                lambda q, k, v: sa.splash_attention(
+                    q, k, v, interpret=True, block_q=128, block_k=128,
+                    **window), q, k, v)[1](q))(q, k, v))
+
+        assert run({"window": None}) == run({})
+        assert "window" not in run({})
+
+    def test_computed_pairs_by_hand(self):
+        tile = 1024 * 1024
+        # seq 8192 on 1024-key tiles: q tile 0 meets one k tile, the seven
+        # after it two each, 15 of the causal grid's 36
+        assert sa.computed_pairs(8192, 1024, 1024, window=1024) == 15 * tile
+        assert sa.computed_pairs(8192, 1024, 1024) == 36 * tile
+        # on 512-key tiles 1 + 2 + 14 x 3 = 45 of 136
+        assert sa.computed_pairs(8192, 512, 512, window=1024) == 45 * 512 ** 2
+        assert sa.computed_pairs(8192, 512, 512) == 136 * 512 ** 2
+        # the band itself: sum_t min(t + 1, 1024)
+        band = 1024 * 1025 // 2 + (8192 - 1024) * 1024
+        assert band == 7_864_832
+        assert sa.computed_pairs(8192, 1024, 1024, window=1024) / band < 2.01
+        # a window of one key: the diagonal tiles alone; of one more than a
+        # tile: three tiles a row; wider than the sequence: the causal grid
+        assert sa.computed_pairs(512, 128, 128, window=1) == 4 * 128 ** 2
+        assert sa.computed_pairs(512, 128, 128, window=130) == 9 * 128 ** 2
+        assert sa.computed_pairs(512, 128, 128, window=129) == 7 * 128 ** 2
+        assert sa.computed_pairs(512, 128, 128, window=4096) == \
+            sa.computed_pairs(512, 128, 128)
+        # the caller's rule for a windowed call's tile
+        assert sa.computed_pairs(8192, window=1024) == sa.computed_pairs(
+            8192, *(sa._window_block(8192),) * 2, window=1024)
+
+    def test_the_grid_is_the_bands(self):
+        """A windowed call's grid has `_band_steps` steps along k, not the
+        causal grid's with steps skipped; the backward's accumulators one
+        plane a step."""
+        q, k, v = (jax.ShapeDtypeStruct((1, 1024, 2, 64), jnp.float32),) * 3
+        grids = []
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    grids.append(tuple(eqn.params["grid_mapping"].grid))
+                for sub in _eqn_jaxprs(eqn):
+                    walk(sub)
+
+        from paddle_tpu.utils import flags
+        selfcheck = flags.get_flag("FLAGS_pallas_alias_selfcheck")
+        flags.set_flags({"FLAGS_pallas_alias_selfcheck": False})
+        try:
+            walk(jax.make_jaxpr(lambda q, k, v: jax.vjp(
+                lambda q, k, v: sa.splash_attention(
+                    q, k, v, window=256, block_q=128, block_k=128,
+                    use_kernel=True, interpret=False), q, k, v)[1](q))(
+                        q, k, v).jaxpr)
+        finally:
+            flags.set_flags({"FLAGS_pallas_alias_selfcheck": selfcheck})
+        assert sa._band_steps(256, 128, 8) == 3
+        assert grids == [(2, 8, 3), (2, 8, 3)]      # (b kv heads, q tiles, band)
+
+    @pytest.mark.parametrize("window,block,nqs", [
+        (1024, 1024, 8), (1024, 512, 16), (200, 128, 4), (1, 128, 4),
+        (600, 128, 4)])
+    def test_a_plane_meets_a_k_block_once_a_head(self, window, block, nqs):
+        """What the chip's self-check refused in this PR's first kernel:
+        bands begun at tile 0 put the first q tiles' step 0 on ONE block
+        of one plane of the backward's accumulators, a grid step or two
+        apart. Ended on the q tile's own tile, no (plane, block) is met
+        twice by a head, and every tile met lies in the band."""
+        steps = sa._band_steps(window, block, nqs)
+        seen = set()
+        for i in range(nqs):
+            met = [sa._band_tile(i, nqs, steps, j) for j in range(steps)]
+            assert met[-1] == i and met == sorted(met)
+            for j, t in enumerate(met):
+                if t >= 0:
+                    assert (j, t) not in seen
+                    seen.add((j, t))
+                    assert (i - t - 1) * block + 1 < window or t == i
+        # and every tile the band touches is met
+        for i in range(nqs):
+            first = max(0, (i * block - (window - 1)) // block)
+            assert {t for j in range(steps) if (t := sa._band_tile(
+                i, nqs, steps, j)) >= 0} >= set(range(first, i + 1))
+
+    def test_a_window_takes_causal_attention_and_no_selection(self):
+        q, k, v = _rand(1, 256, 2, 1, 32)
+        with pytest.raises(ValueError, match="window"):
+            sa.splash_attention(q, k, v, window=64, interpret=True,
+                                selection=jnp.ones((1, 256, 256), jnp.int8))
+        with pytest.raises(ValueError, match="window"):
+            sa.splash_attention(q, k, v, window=64, causal=False,
+                                interpret=True)
+        with pytest.raises(ValueError, match="window"):
+            sa.splash_attention(q, k, v, window=0, interpret=True)
+
+
 class TestFunctionalRouting:
     def test_sdpa_segments_route_to_splash(self):
         import paddle_tpu as paddle
