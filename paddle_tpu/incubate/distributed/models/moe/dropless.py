@@ -23,6 +23,17 @@ buffer of the worst routing's size exists beyond the int32 row tables
 ([T * top_k + held * tile_rows]) and the work follows the routing that
 happened. The loop's trip count is data; its backward is a second loop
 (custom VJP).
+
+How a tile's rows move. The gather is XLA's (`x[idx]`). The add-back on
+TPU, for a row of whole lanes, is by row DMAs (`ops/pallas/moe_rows.py
+row_adds`): the float32 accumulator is born as [T, 1, K], where a row is
+one contiguous copy, `moe_add_rows` adds a tile's rows into it in place,
+and it is reshaped once after the loop. On CPU, and for any other width,
+XLA's scatter-add on [T, K]. Either way the additions within a token keep
+the tiles' order and their float32 width. A padding row names token 0
+with weight 0: it is computed, and the kernel never writes it (the first
+`tile_real` rows of a tile are real), for under read-modify-write its copy
+back would meet token 0's own row; XLA's scatter adds its 0.0.
 """
 from __future__ import annotations
 
@@ -33,6 +44,7 @@ import jax.numpy as jnp
 
 from ..... import nn
 from .....ops._dispatch import nary
+from .....ops.pallas.moe_rows import row_adds
 
 __all__ = ["DroplessMoE", "dropless_moe", "route_topk", "dispatch_plan",
            "grouped_ffn"]
@@ -92,17 +104,20 @@ def _dot(a, b, dims):
                                preferred_element_type=F32)
 
 
-def _tile(i, tile, row_token, row_w, tile_expert):
+def _tile(i, tile, row_token, row_w, tile_expert, tile_real):
     with jax.named_scope("moe/route"):
         idx = jax.lax.dynamic_slice(row_token, (i * tile,), (tile,))
         w = jax.lax.dynamic_slice(row_w, (i * tile,), (tile,))
-    return idx, w, tile_expert[i]
+    return idx, w, tile_expert[i], tile_real[i]
 
 
-def _ffn_fwd_loop(x, wg, wu, wd, row_token, row_w, tile_expert, n_tiles,
-                  tile):
+def _ffn_fwd_loop(x, wg, wu, wd, row_token, row_w, tile_expert, tile_real,
+                  n_tiles, tile):
+    adds = row_adds(x.shape[1], tile)
+
     def body(i, out):
-        idx, w, e = _tile(i, tile, row_token, row_w, tile_expert)
+        idx, w, e, n_real = _tile(i, tile, row_token, row_w, tile_expert,
+                                  tile_real)
         with jax.named_scope("moe/route"):
             h = x[idx]
         with jax.named_scope("moe/experts"):
@@ -110,36 +125,44 @@ def _ffn_fwd_loop(x, wg, wu, wd, row_token, row_w, tile_expert, n_tiles,
                  * _dot(h, wu[e], ((1,), (0,)))).astype(x.dtype)
             y = _dot(a, wd[e], ((1,), (0,))) * w[:, None]
         with jax.named_scope("moe/route"):
-            return out.at[idx].add(y)
+            return adds.add(out, idx, y, n_real)
 
-    return jax.lax.fori_loop(0, n_tiles, body, jnp.zeros(x.shape, F32))
+    with jax.named_scope("moe/route"):
+        return adds.whole(jax.lax.fori_loop(0, n_tiles, body,
+                                            adds.zeros(x.shape)))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
-def grouped_ffn(x, wg, wu, wd, row_token, row_w, tile_expert, n_tiles,
-                tile):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9,))
+def grouped_ffn(x, wg, wu, wd, row_token, row_w, tile_expert, tile_real,
+                n_tiles, tile):
     """sum over the rows r of a routing of row_w[r] * FFN_e(x[row_token[r]])
     added at row_token[r] -> float32 [T, K]. x [T, K]; wg, wu [G, K, N];
-    wd [G, N, K]; the tables are `dispatch_plan`'s. Tiles from `n_tiles`
-    on are not computed."""
+    wd [G, N, K]; the tables are `dispatch_plan`'s, `tile_real` [M / tile]
+    the rows of each tile that hold a routed pair (its first rows: the
+    tokens of a tile's real rows are distinct, and the rest are padding,
+    which is computed and never added back). Tiles from `n_tiles` on are
+    not computed."""
     return _ffn_fwd_loop(x, wg, wu, wd, row_token, row_w, tile_expert,
-                         n_tiles, tile)
+                         tile_real, n_tiles, tile)
 
 
-def _grouped_ffn_fwd(x, wg, wu, wd, row_token, row_w, tile_expert, n_tiles,
-                     tile):
+def _grouped_ffn_fwd(x, wg, wu, wd, row_token, row_w, tile_expert, tile_real,
+                     n_tiles, tile):
     out = _ffn_fwd_loop(x, wg, wu, wd, row_token, row_w, tile_expert,
-                        n_tiles, tile)
-    return out, (x, wg, wu, wd, row_token, row_w, tile_expert, n_tiles)
+                        tile_real, n_tiles, tile)
+    return out, (x, wg, wu, wd, row_token, row_w, tile_expert, tile_real,
+                 n_tiles)
 
 
 def _grouped_ffn_bwd(tile, res, dout):
-    x, wg, wu, wd, row_token, row_w, tile_expert, n_tiles = res
+    x, wg, wu, wd, row_token, row_w, tile_expert, tile_real, n_tiles = res
+    adds = row_adds(x.shape[1], tile)
     dout = dout.astype(F32)
 
     def body(i, carry):
         dx, dwg, dwu, dwd, drow = carry
-        idx, w, e = _tile(i, tile, row_token, row_w, tile_expert)
+        idx, w, e, n_real = _tile(i, tile, row_token, row_w, tile_expert,
+                                  tile_real)
         with jax.named_scope("moe/route"):
             h = x[idx]
             dy_rows = dout[idx]
@@ -161,16 +184,18 @@ def _grouped_ffn_bwd(tile, res, dout):
             dwu = dwu.at[e].add(_dot(h, du, ((0,), (0,))))
             dwd = dwd.at[e].add(_dot(a, dy, ((0,), (0,))))
         with jax.named_scope("moe/route"):
-            dx = dx.at[idx].add(dh)
+            dx = adds.add(dx, idx, dh, n_real)
             drow = jax.lax.dynamic_update_slice(drow, drow_i, (i * tile,))
         return dx, dwg, dwu, dwd, drow
 
-    init = (jnp.zeros(x.shape, F32), jnp.zeros(wg.shape, F32),
+    init = (adds.zeros(x.shape), jnp.zeros(wg.shape, F32),
             jnp.zeros(wu.shape, F32), jnp.zeros(wd.shape, F32),
             jnp.zeros(row_w.shape, F32))
     dx, dwg, dwu, dwd, drow = jax.lax.fori_loop(0, n_tiles, body, init)
+    with jax.named_scope("moe/route"):
+        dx = adds.whole(dx)
     return (dx.astype(x.dtype), dwg.astype(wg.dtype), dwu.astype(wu.dtype),
-            dwd.astype(wd.dtype), None, drow.astype(row_w.dtype), None,
+            dwd.astype(wd.dtype), None, drow.astype(row_w.dtype), None, None,
             None)
 
 
@@ -199,8 +224,10 @@ def dropless_moe(h, wr, wg, wu, wd, *, top_k, held, tile_rows,
             experts, held, tile_rows)
         row_w = jnp.concatenate([gates.reshape(-1),
                                  jnp.zeros((1,), F32)])[row_pair]
-    y = grouped_ffn(h, wg, wu, wd, row_token, row_w, tile_expert, n_tiles,
-                    tile_rows)
+        tile_real = jnp.sum(
+            (row_pair < experts.size).reshape(-1, tile_rows), 1, dtype=I32)
+    y = grouped_ffn(h, wg, wu, wd, row_token, row_w, tile_expert, tile_real,
+                    n_tiles, tile_rows)
     load = counts.astype(F32)
     stats = jnp.stack([jnp.sum(load), (n_tiles * tile_rows).astype(F32),
                        jnp.max(load)])

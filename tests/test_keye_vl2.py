@@ -552,14 +552,20 @@ def v5e_chip():
 
 @pytest.mark.parametrize("kernel", ["attn_probs", "splash_selection",
                                     "indexer_scores", "splash_window",
-                                    "fused_ce_2304"])
+                                    "fused_ce_2304", "moe_rows_2048",
+                                    "moe_rows_2304"])
 def test_the_new_kernels_compile_for_a_v5e_at_published_widths(v5e_chip,
                                                                kernel):
-    """The two last are the window + mixture block's (tests/
-    test_mellum2.py; kept here because one process describes the chip):
-    the banded splash kernels at its geometry, and fused CE at hidden
-    2304 over a 24,576-row head, where a 512-row vocabulary tile's
-    backward asked for 25.5 MiB of a v5e's 16."""
+    """`splash_window` and `fused_ce_2304` are the window + mixture
+    block's (tests/test_mellum2.py; kept here because one process
+    describes the chip): the banded splash kernels at its geometry, and
+    fused CE at hidden 2304 over a 24,576-row head, where a 512-row
+    vocabulary tile's backward asked for 25.5 MiB of a v5e's 16.
+    `moe_rows_*`: one dropless layer's forward and backward tile loops at
+    both blocks' widths, 32,768 tokens in 512-row tiles: `moe_add_rows`
+    asks its two float32 tiles and 2 MiB of VMEM (10.0 and 11.0 MiB)."""
+    from paddle_tpu.incubate.distributed.models.moe import dropless
+    from paddle_tpu.ops.pallas import moe_rows
     from paddle_tpu.ops.pallas import routing
     from paddle_tpu.ops.pallas.fused_cross_entropy import fused_cross_entropy
     from paddle_tpu.ops.pallas.attention_probs import head_mean_probs
@@ -601,6 +607,26 @@ def test_the_new_kernels_compile_for_a_v5e_at_published_widths(v5e_chip,
         args, want = (spec((32768, 2304), bf16), spec((24576, 2304), bf16),
                       spec((32768,), jnp.int32)), {"fused_ce_fwd",
                                                    "fused_ce_bwd"}
+    elif kernel.startswith("moe_rows"):
+        k = int(kernel.rsplit("_", 1)[1])
+        t, g, tile, n = 32768, 16, 512, {2048: 768, 2304: 896}[k]
+        m = dropless.plan_rows(t * 8, g, tile)
+        vmem = moe_rows._add_rows_vmem(tile, k)
+        assert vmem == {2048: 10_485_760, 2304: 11_534_336}[k]
+
+        def fn(x, wg, wu, wd, row_w, tokens, tile_expert, tile_real, n_tiles,
+               dout):
+            out, pull = jax.vjp(
+                lambda *a: dropless.grouped_ffn(
+                    *a[:4], tokens, a[4], tile_expert, tile_real, n_tiles,
+                    tile), x, wg, wu, wd, row_w)
+            return out, pull(dout)
+        args, want = (spec((t, k), bf16), spec((g, k, n), bf16),
+                      spec((g, k, n), bf16), spec((g, n, k), bf16),
+                      spec((m,), jnp.float32), spec((m,), jnp.int32),
+                      spec((m // tile,), jnp.int32),
+                      spec((m // tile,), jnp.int32), spec((), jnp.int32),
+                      spec((t, k), jnp.float32)), {"moe_add_rows"}
     else:
         def fn(q, k, v, sel):
             return jax.grad(lambda *a: jnp.sum(splash_attention(
@@ -617,3 +643,9 @@ def test_the_new_kernels_compile_for_a_v5e_at_published_widths(v5e_chip,
     assert set(routing.mosaic_kernels(text)) == want
     # no per-head score tensor outside the kernels
     assert "f32[512,16,8192]" not in text
+    if kernel.startswith("moe_rows"):
+        # the forward loop's add-back and the backward's, at the VMEM
+        # asked, in XLA's scatter-add's place
+        assert routing.mosaic_kernels(text) == {"moe_add_rows": 2}
+        assert str(vmem) in text
+        assert "moe/route/scatter-add" not in text

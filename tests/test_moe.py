@@ -797,3 +797,165 @@ class TestFusedEcMoe:
         assert tuple(out.shape) == (1, 32, 8)
         (out ** 2).mean().backward()
         assert layer.bmm_weight0.grad is not None
+
+
+# -- the dropless layer's add-back (ops/pallas/moe_rows.py) ---------------
+# Interpret mode runs the kernel's asynchronous copies and semaphores on
+# the CPU; XLA's scatter-add on the [T, K] array is the oracle.
+
+def _row_tables(experts, held, tile):
+    from paddle_tpu.incubate.distributed.models.moe import dropless
+    row_token, row_pair, tile_expert, n_tiles, _ = dropless.dispatch_plan(
+        jnp.asarray(experts, jnp.int32), held, tile)
+    real = row_pair < experts.size
+    return (row_token, real, tile_expert,
+            jnp.sum(real.reshape(-1, tile), 1, dtype=jnp.int32), n_tiles)
+
+
+def _grouped(x, wg, wu, wd, row_w, tables, tile, interpret):
+    """grouped_ffn's output and its five gradients, through the row-DMA
+    kernel (interpreted) or the XLA scatter-add the CPU takes."""
+    from paddle_tpu.incubate.distributed.models.moe import dropless
+    from paddle_tpu.utils import flags
+    row_token, _, tile_expert, tile_real, n_tiles = tables
+
+    def f(x, wg, wu, wd, row_w):
+        return dropless.grouped_ffn(x, wg, wu, wd, row_token, row_w,
+                                    tile_expert, tile_real, n_tiles, tile)
+
+    flags.set_flags({"FLAGS_pallas_force_interpret": interpret})
+    try:
+        out, pull = jax.vjp(f, x, wg, wu, wd, row_w)
+        return (out,) + pull(jnp.cos(jnp.arange(out.size, dtype=jnp.float32)
+                                     ).reshape(out.shape))
+    finally:
+        flags.set_flags({"FLAGS_pallas_force_interpret": False})
+
+
+def _ffn_operands(t, k, n, g, rows, dtype, seed):
+    rng = np.random.default_rng(seed)
+    mk = lambda shape, s=1.0: jnp.asarray(  # noqa: E731
+        rng.standard_normal(shape) * s, dtype)
+    return (mk((t, k)), mk((g, k, n), 0.1), mk((g, k, n), 0.1),
+            mk((g, n, k), 0.1),
+            jnp.asarray(rng.random((rows,)), jnp.float32))
+
+
+class TestMoeRows:
+    @pytest.mark.parametrize("k", [256, 2304])
+    @pytest.mark.parametrize("n_real", [16, 11, 0])
+    def test_add_rows_is_xlas_scatter_add_bit_for_bit(self, n_real, k):
+        from paddle_tpu.ops.pallas import moe_rows
+        rng = np.random.default_rng(1)
+        acc = jnp.asarray(rng.standard_normal((40, k)), jnp.float32)
+        y = jnp.asarray(rng.standard_normal((16, k)), jnp.float32)
+        idx = jnp.asarray(np.sort(rng.permutation(40)[:16]), jnp.int32)
+        adds = moe_rows.row_adds(k, 16, interpret=True)
+        assert adds is not moe_rows._XLA
+        got = adds.whole(adds.add(adds.zeros(acc.shape) + acc[:, None],
+                                  idx, y, jnp.int32(n_real)))
+        np.testing.assert_array_equal(
+            got, acc.at[idx[:n_real]].add(y[:n_real]))
+
+    def test_a_padding_row_is_never_written(self):
+        """Token 0 is in the tile, and the tile's padding rows name token 0
+        too: read-modify-write of a padding row would put token 0's old
+        row back over the sum."""
+        from paddle_tpu.ops.pallas import moe_rows
+        rng = np.random.default_rng(2)
+        acc = jnp.asarray(rng.standard_normal((24, 256)), jnp.float32)
+        y = jnp.asarray(rng.standard_normal((8, 256)), jnp.float32)
+        y = y.at[3:].set(0.0)                    # what a padding row adds
+        idx = jnp.asarray([0, 5, 9, 0, 0, 0, 0, 0], jnp.int32)
+        got = moe_rows.add_rows(acc.reshape(24, 1, 256), idx, y,
+                                jnp.int32(3), interpret=True)
+        want = acc.at[idx[:3]].add(y[:3])
+        assert not np.array_equal(want[0], acc[0])
+        np.testing.assert_array_equal(got.reshape(24, 256), want)
+
+    @pytest.mark.parametrize("width,tile,taken", [
+        (2304, 512, True), (2048, 512, True), (256, 8, True),
+        (200, 512, False),       # no whole lanes
+        (8192, 512, False),      # two float32 tiles do not fit VMEM
+        (2304, 1024, False)])
+    def test_which_widths_the_kernel_takes(self, width, tile, taken):
+        from paddle_tpu.ops.pallas import moe_rows, routing
+        assert moe_rows.supports(width, tile) is taken
+        before = sum(routing.xla_fallbacks.values())
+        adds = moe_rows.row_adds(width, tile, interpret=True)
+        assert (adds is moe_rows._XLA) is not taken
+        assert sum(routing.xla_fallbacks.values()) == before + (not taken)
+
+    def test_on_the_cpu_xlas_scatter_add_is_the_path(self):
+        from paddle_tpu.ops.pallas import moe_rows
+        assert moe_rows.row_adds(2304, 512) is moe_rows._XLA
+
+    def test_the_alias_selfcheck_passes_and_catches_a_padding_row(self):
+        """The chip's one-time check, here on the interpreted kernel: it
+        passes as the kernel is, and raises once padding rows are
+        written."""
+        import functools
+        from paddle_tpu.ops.pallas import moe_rows
+        real = moe_rows.add_rows
+        try:
+            moe_rows.add_rows = functools.partial(real, interpret=True)
+            moe_rows._alias_selfcheck(256, 16)
+            assert (256, 16) in moe_rows._alias_checked
+            moe_rows._alias_checked.discard((256, 16))
+            moe_rows.add_rows = lambda acc, idx, y, n: real(
+                acc, idx, y, jnp.int32(idx.shape[0]), interpret=True)
+            with pytest.raises(RuntimeError, match="self-check FAILED"):
+                moe_rows._alias_selfcheck(256, 16)
+            assert (256, 16) not in moe_rows._alias_checked
+        finally:
+            moe_rows.add_rows = real
+            moe_rows._alias_checked.discard((256, 16))
+
+    @pytest.mark.parametrize("case", ["token0_before_padding",
+                                      "token_on_two_experts", "no_tiles",
+                                      "unequal_loads_bf16",
+                                      "unequal_loads_f32"])
+    def test_grouped_ffn_through_the_kernel_is_the_xla_loop(self, case):
+        """Output and all five gradients bit for bit: the kernel moves
+        rows, the additions keep their order and their float32 width."""
+        tile, t, k, n, g = 8, 32, 256, 128, 4
+        dtype = jnp.float32 if case.endswith("f32") else jnp.bfloat16
+        rng = np.random.default_rng(5)
+        if case == "token0_before_padding":
+            # expert 1 holds tokens 0, 3, 4: its one tile is padded with
+            # five rows that name token 0
+            experts = np.full((t, 2), 7)
+            experts[[0, 3, 4], 0] = 1
+        elif case == "token_on_two_experts":
+            # tokens 0..7 fill expert 0's tile and expert 1's, the next
+            experts = np.full((t, 2), 7)
+            experts[:8] = [0, 1]
+        elif case == "no_tiles":
+            experts = np.full((t, 2), 7)
+        else:
+            # loads 0 to 20 over the four held experts of eight
+            p = np.array([0, .05, .3, .15, .1, .1, .2, .1])
+            experts = np.stack([rng.choice(8, 2, replace=False, p=p)
+                                for _ in range(t)])
+        tables = _row_tables(experts, (0, g), tile)
+        row_token, real, _, tile_real, n_tiles = tables
+        if case == "token0_before_padding":
+            assert int(n_tiles) == 1 and int(tile_real[0]) == 3
+            assert list(np.asarray(row_token[:8])) == [0, 3, 4, 0, 0, 0, 0, 0]
+        if case == "token_on_two_experts":
+            assert int(n_tiles) == 2
+            np.testing.assert_array_equal(row_token[:8], row_token[8:16])
+        if case == "no_tiles":
+            assert int(n_tiles) == 0
+        x, wg, wu, wd, row_w = _ffn_operands(t, k, n, g, real.shape[0],
+                                             dtype, 6)
+        row_w = jnp.where(real, row_w, 0.0)
+        want = _grouped(x, wg, wu, wd, row_w, tables, tile, False)
+        got = _grouped(x, wg, wu, wd, row_w, tables, tile, True)
+        if case != "no_tiles":
+            assert float(jnp.max(jnp.abs(want[0]))) > 0
+        for name, a, b in zip(("out", "dx", "dwg", "dwu", "dwd", "drow"),
+                              got, want):
+            assert a.dtype == b.dtype, name
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32), name)

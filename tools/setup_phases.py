@@ -13,8 +13,8 @@ its set-up alone. Prints one JSON line:
   `weights`, `first_steps`: seconds each phase took);
 * inside `first_steps`: each step call (the first one traces, lowers and
   compiles or loads the step), each wait for its loss, the readers
-  between them, the splash self-check, and `ahead.join()` where the
-  runner compiles its reference on a thread;
+  between them, the splash and `moe_add_rows` self-checks, and
+  `ahead.join()` where the runner compiles its reference on a thread;
 * JAX's own compile events by thread (`tracing`, `jaxpr_to_mlir`,
   `backend_compile`, which is the cache load in a warm run), the largest
   programs by name;
@@ -164,6 +164,13 @@ def main(argv=None):
     if hasattr(splash, "_alias_selfcheck"):
         splash._alias_selfcheck = timed(splash._alias_selfcheck,
                                         "splash self-check")
+    try:        # --root may name a checkout from before the kernel
+        from paddle_tpu.ops.pallas import moe_rows
+    except ImportError:
+        moe_rows = None
+    if moe_rows is not None:
+        moe_rows._alias_selfcheck = timed(moe_rows._alias_selfcheck,
+                                          "moe_add_rows self-check")
 
     runner = load.module("runners", cell["traffic"]["kind"])
 
