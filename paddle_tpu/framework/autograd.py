@@ -9,6 +9,7 @@ shape-keyed-executable-cache bet flagged in SURVEY.md §7 "hard parts".
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 from collections import defaultdict
 
@@ -265,6 +266,32 @@ def set_op_observer(observer):
     return prev
 
 
+_op_scope = threading.local()
+
+
+@contextlib.contextmanager
+def op_scope(name):
+    """Every op applied inside runs under `jax.named_scope(name)` in its
+    forward AND its backward. A `with jax.named_scope(...)` around a
+    layer's call names the forward only: the tape pulls an op's vjp back
+    later, outside the `with`, so the scope has to stand inside the
+    function `apply_op` differentiates. The innermost `op_scope` holds."""
+    prev = getattr(_op_scope, "name", None)
+    _op_scope.name = name
+    try:
+        yield
+    finally:
+        _op_scope.name = prev
+
+
+def _in_scope(fn, scope):
+    def scoped(*args, **kwargs):
+        with jax.named_scope(scope):
+            return fn(*args, **kwargs)
+
+    return scoped
+
+
 def apply_op(fn, inputs, attrs=None, name="", num_outputs=None):
     """Execute `fn(*jax_arrays, **attrs)` and record a GradNode if needed.
 
@@ -277,6 +304,9 @@ def apply_op(fn, inputs, attrs=None, name="", num_outputs=None):
 
     if _OP_OBSERVER is not None:
         _OP_OBSERVER(name or getattr(fn, "__name__", "op"), inputs)
+    scope = getattr(_op_scope, "name", None)
+    if scope is not None:
+        fn = _in_scope(fn, scope)
     attrs = attrs or {}
     datas = [t._data for t in inputs]
     needs_grad = is_grad_enabled() and any(not t.stop_gradient for t in inputs)

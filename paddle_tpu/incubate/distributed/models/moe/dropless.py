@@ -34,6 +34,21 @@ the tiles' order and their float32 width. A padding row names token 0
 with weight 0: it is computed, and the kernel never writes it (the first
 `tile_real` rows of a tile are real), for under read-modify-write its copy
 back would meet token 0's own row; XLA's scatter adds its 0.0.
+
+In a device trace (xprof, Perfetto) every operation of the layer carries
+one of these `jax.named_scope` paths, forward and backward (they are part
+of `paddle_tpu.profiler.DEVICE_SCOPES`):
+  moe/route/router    the router product, softmax, top-k, renormalised
+                      gates, the picks' count and the balance term
+  moe/route/plan      `dispatch_plan` (counts, the stable sort, the row
+                      tables), `row_w`, `tile_real`, the counters
+  moe/route/gather    a tile's slices of the tables, `x[idx]`, `dout[idx]`
+  moe/route/add_back  the accumulator's zeros and final reshape, a tile's
+                      add-back, `drow`'s update, and both tile loops'
+                      `while` with the copies of its carry
+  moe/experts         the three products of a tile and their gradients
+  moe/cast            float32 staging round the loops: the cotangent cast
+                      up, the output and the gradients cast back
 """
 from __future__ import annotations
 
@@ -105,10 +120,10 @@ def _dot(a, b, dims):
 
 
 def _tile(i, tile, row_token, row_w, tile_expert, tile_real):
-    with jax.named_scope("moe/route"):
+    with jax.named_scope("moe/route/gather"):
         idx = jax.lax.dynamic_slice(row_token, (i * tile,), (tile,))
         w = jax.lax.dynamic_slice(row_w, (i * tile,), (tile,))
-    return idx, w, tile_expert[i], tile_real[i]
+        return idx, w, tile_expert[i], tile_real[i]
 
 
 def _ffn_fwd_loop(x, wg, wu, wd, row_token, row_w, tile_expert, tile_real,
@@ -118,16 +133,16 @@ def _ffn_fwd_loop(x, wg, wu, wd, row_token, row_w, tile_expert, tile_real,
     def body(i, out):
         idx, w, e, n_real = _tile(i, tile, row_token, row_w, tile_expert,
                                   tile_real)
-        with jax.named_scope("moe/route"):
+        with jax.named_scope("moe/route/gather"):
             h = x[idx]
         with jax.named_scope("moe/experts"):
             a = (jax.nn.silu(_dot(h, wg[e], ((1,), (0,))))
                  * _dot(h, wu[e], ((1,), (0,)))).astype(x.dtype)
             y = _dot(a, wd[e], ((1,), (0,))) * w[:, None]
-        with jax.named_scope("moe/route"):
+        with jax.named_scope("moe/route/add_back"):
             return adds.add(out, idx, y, n_real)
 
-    with jax.named_scope("moe/route"):
+    with jax.named_scope("moe/route/add_back"):
         return adds.whole(jax.lax.fori_loop(0, n_tiles, body,
                                             adds.zeros(x.shape)))
 
@@ -157,13 +172,14 @@ def _grouped_ffn_fwd(x, wg, wu, wd, row_token, row_w, tile_expert, tile_real,
 def _grouped_ffn_bwd(tile, res, dout):
     x, wg, wu, wd, row_token, row_w, tile_expert, tile_real, n_tiles = res
     adds = row_adds(x.shape[1], tile)
-    dout = dout.astype(F32)
+    with jax.named_scope("moe/cast"):
+        dout = dout.astype(F32)
 
     def body(i, carry):
         dx, dwg, dwu, dwd, drow = carry
         idx, w, e, n_real = _tile(i, tile, row_token, row_w, tile_expert,
                                   tile_real)
-        with jax.named_scope("moe/route"):
+        with jax.named_scope("moe/route/gather"):
             h = x[idx]
             dy_rows = dout[idx]
         with jax.named_scope("moe/experts"):
@@ -183,20 +199,23 @@ def _grouped_ffn_bwd(tile, res, dout):
             dwg = dwg.at[e].add(_dot(h, dg, ((0,), (0,))))
             dwu = dwu.at[e].add(_dot(h, du, ((0,), (0,))))
             dwd = dwd.at[e].add(_dot(a, dy, ((0,), (0,))))
-        with jax.named_scope("moe/route"):
+        with jax.named_scope("moe/route/add_back"):
             dx = adds.add(dx, idx, dh, n_real)
             drow = jax.lax.dynamic_update_slice(drow, drow_i, (i * tile,))
         return dx, dwg, dwu, dwd, drow
 
-    init = (adds.zeros(x.shape), jnp.zeros(wg.shape, F32),
-            jnp.zeros(wu.shape, F32), jnp.zeros(wd.shape, F32),
-            jnp.zeros(row_w.shape, F32))
-    dx, dwg, dwu, dwd, drow = jax.lax.fori_loop(0, n_tiles, body, init)
-    with jax.named_scope("moe/route"):
+    # the loop stands under a leaf, so that its `while` and the copies of
+    # its carry are the add-back's, whose accumulator they move
+    with jax.named_scope("moe/route/add_back"):
+        init = (adds.zeros(x.shape), jnp.zeros(wg.shape, F32),
+                jnp.zeros(wu.shape, F32), jnp.zeros(wd.shape, F32),
+                jnp.zeros(row_w.shape, F32))
+        dx, dwg, dwu, dwd, drow = jax.lax.fori_loop(0, n_tiles, body, init)
         dx = adds.whole(dx)
-    return (dx.astype(x.dtype), dwg.astype(wg.dtype), dwu.astype(wu.dtype),
-            dwd.astype(wd.dtype), None, drow.astype(row_w.dtype), None, None,
-            None)
+    with jax.named_scope("moe/cast"):
+        return (dx.astype(x.dtype), dwg.astype(wg.dtype),
+                dwu.astype(wu.dtype), dwd.astype(wd.dtype), None,
+                drow.astype(row_w.dtype), None, None, None)
 
 
 grouped_ffn.defvjp(_grouped_ffn_fwd, _grouped_ffn_bwd)
@@ -214,12 +233,13 @@ def dropless_moe(h, wr, wg, wu, wd, *, top_k, held, tile_rows,
     router: f_e the share of tokens that picked e (no gradient), P_e the
     mean of p[:, e]."""
     n_experts = wr.shape[-1]
-    with jax.named_scope("moe/route"):
+    with jax.named_scope("moe/route/router"):
         p, experts, gates = route_topk(
             _dot(h, wr, ((1,), (0,))), top_k, renormalise)
         picked = jnp.zeros((n_experts,), F32).at[experts.reshape(-1)].add(1.0)
         balance = balance_coef * n_experts * jnp.sum(
             jax.lax.stop_gradient(picked / h.shape[0]) * jnp.mean(p, 0))
+    with jax.named_scope("moe/route/plan"):
         row_token, row_pair, tile_expert, n_tiles, counts = dispatch_plan(
             experts, held, tile_rows)
         row_w = jnp.concatenate([gates.reshape(-1),
@@ -228,10 +248,12 @@ def dropless_moe(h, wr, wg, wu, wd, *, top_k, held, tile_rows,
             (row_pair < experts.size).reshape(-1, tile_rows), 1, dtype=I32)
     y = grouped_ffn(h, wg, wu, wd, row_token, row_w, tile_expert, tile_real,
                     n_tiles, tile_rows)
-    load = counts.astype(F32)
-    stats = jnp.stack([jnp.sum(load), (n_tiles * tile_rows).astype(F32),
-                       jnp.max(load)])
-    return y.astype(h.dtype), balance, stats, experts
+    with jax.named_scope("moe/route/plan"):
+        load = counts.astype(F32)
+        stats = jnp.stack([jnp.sum(load), (n_tiles * tile_rows).astype(F32),
+                           jnp.max(load)])
+    with jax.named_scope("moe/cast"):
+        return y.astype(h.dtype), balance, stats, experts
 
 
 class DroplessMoE(nn.Layer):

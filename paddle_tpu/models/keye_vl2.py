@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import nn
+from ..framework.autograd import op_scope
 from ..framework.tensor import Tensor
 from ..incubate.distributed.models.moe.dropless import DroplessMoE
 from ..ops import sparse_attention as sa
@@ -115,20 +116,22 @@ def _indexer_branch(c, idx, x, main, positions):
     wqi, wki, knw, knb, ww = idx
     ln, wq, wk, qn, kn = main
     b, s, _ = x.shape
-    h = _rms(x, ln, c.rms_norm_eps)
-    cos, sin = sa.mrope_angles(positions, c.head_dim, c.rope_theta,
-                               c.mrope_section)
-    q, k = _queries_keys(c, h, wq, wk, qn, kn, cos, sin)
-    icos, isin = sa.mrope_angles(positions, c.index_head_dim, c.rope_theta,
-                                 c.index_sections())
-    q_idx = sa.apply_rotary(
-        (h @ wqi).reshape(b, s, c.index_n_heads, c.index_head_dim),
-        icos, isin)
-    k_idx = sa.apply_rotary(
-        _layer_norm(h @ wki, knw, knb, c.rms_norm_eps), icos, isin)
-    w = (h @ ww) * (c.index_n_heads * c.index_head_dim) ** -0.5
-    return sa.indexer_select(q, k, q_idx, k_idx, w.astype(h.dtype),
-                             c.index_topk, c.index_q_chunk)
+    with jax.named_scope("indexer/project"):
+        h = _rms(x, ln, c.rms_norm_eps)
+        cos, sin = sa.mrope_angles(positions, c.head_dim, c.rope_theta,
+                                   c.mrope_section)
+        q, k = _queries_keys(c, h, wq, wk, qn, kn, cos, sin)
+        icos, isin = sa.mrope_angles(positions, c.index_head_dim,
+                                     c.rope_theta, c.index_sections())
+        q_idx = sa.apply_rotary(
+            (h @ wqi).reshape(b, s, c.index_n_heads, c.index_head_dim),
+            icos, isin)
+        k_idx = sa.apply_rotary(
+            _layer_norm(h @ wki, knw, knb, c.rms_norm_eps), icos, isin)
+        w = ((h @ ww) * (c.index_n_heads * c.index_head_dim) ** -0.5
+             ).astype(h.dtype)
+    return sa.indexer_select(q, k, q_idx, k_idx, w, c.index_topk,
+                             c.index_q_chunk)
 
 
 def _differentiable_branch(c):
@@ -156,6 +159,16 @@ def _differentiable_branch(c):
 
     branch.defvjp(fwd, bwd)
     return branch
+
+
+def _mixture(layer, x):
+    """x + the mixture of the layer's post-attention norm of x -> (x,
+    balance term, stats, picks)."""
+    with op_scope("moe/norm"):
+        h = layer.post_attention_layernorm(x)
+    y, balance, stats, picks = layer.mlp(h)
+    with op_scope("moe/residual"):
+        return x + y, balance, stats, picks
 
 
 def routing_totals(rows, config) -> dict:
@@ -242,25 +255,25 @@ class KeyeDecoderLayer(nn.Layer):
             from ..ops.pallas.splash_attention import splash_attention
 
             b, s, _ = x.shape
-            h = _rms(x, ln, c.rms_norm_eps)
-            cos, sin = sa.mrope_angles(positions, c.head_dim, c.rope_theta,
-                                       c.mrope_section)
-            q, k = _queries_keys(c, h, wq, wk, qn, kn, cos, sin)
-            v = (h @ wv).reshape(b, s, c.num_key_value_heads, c.head_dim)
+            with jax.named_scope("attention/projections"):
+                h = _rms(x, ln, c.rms_norm_eps)
+                cos, sin = sa.mrope_angles(positions, c.head_dim,
+                                           c.rope_theta, c.mrope_section)
+                q, k = _queries_keys(c, h, wq, wk, qn, kn, cos, sin)
+                v = (h @ wv).reshape(b, s, c.num_key_value_heads,
+                                     c.head_dim)
             with jax.named_scope("sparse_attention"):
                 o = splash_attention(q, k, v, causal=True,
                                      selection=selection)
-            return x + o.reshape(b, s, -1) @ wo
+            with jax.named_scope("attention/projections"):
+                return x + o.reshape(b, s, -1) @ wo
 
         return nary(run, [x, selection, positions]
                     + self._main_parameters()
                     + [a.v_proj.weight, a.o_proj.weight], "keye_attention")
 
     def _rest(self, x, selection, positions):
-        x = self._attend(x, selection, positions)
-        y, balance, stats, picks = self.mlp(
-            self.post_attention_layernorm(x))
-        return x + y, balance, stats, picks
+        return _mixture(self, self._attend(x, selection, positions))
 
     def forward(self, x, positions):
         """-> (x, balance term, L_I, counters int32 [4]: pairs routed to
@@ -275,10 +288,11 @@ class KeyeDecoderLayer(nn.Layer):
                                                  positions)
         else:
             x, balance, stats, picks = self._rest(x, selection, positions)
-        counters = nary(
-            lambda st, kept: jnp.concatenate(
-                [st.astype(jnp.int32), kept[None]]),
-            [stats, kept], "keye_counters")
+        with op_scope("picks"):
+            counters = nary(
+                lambda st, kept: jnp.concatenate(
+                    [st.astype(jnp.int32), kept[None]]),
+                [stats, kept], "keye_counters")
         return x, balance, index_loss, counters, (selection, picks)
 
 
@@ -313,7 +327,8 @@ class KeyeVL2Model(nn.Layer):
             b, s = input_ids.shape
             position_ids = Tensor._wrap(jnp.broadcast_to(
                 jnp.arange(s, dtype=jnp.int32), (3, b, s)))
-        x = self.embed_tokens(input_ids)
+        with op_scope("embed"):
+            x = self.embed_tokens(input_ids)
         balance, index_loss, counters, picks = [], [], [], []
         for layer in self.layers:
             x, bal, li, cnt, picked = layer(x, position_ids)
@@ -321,7 +336,8 @@ class KeyeVL2Model(nn.Layer):
             index_loss.append(li)
             counters.append(cnt)
             picks.append(picked)
-        return self.norm(x), balance, index_loss, counters, picks
+        with op_scope("head"):
+            return self.norm(x), balance, index_loss, counters, picks
 
 
 class KeyeVL2ForCausalLM(nn.Layer):
@@ -381,15 +397,18 @@ class KeyeVL2ForCausalLM(nn.Layer):
 
         hidden, balance, index_loss, counters, picks = self.model(
             input_ids, position_ids)
-        self.routing._data = jnp.stack([c._data for c in counters])
-        if "selection_bits" in self._buffers:
-            self.selection_bits._data = jnp.stack(
-                [jnp.packbits(s._data.astype(bool), axis=-1)
-                 for s, _ in picks])
-            self.expert_picks._data = jnp.stack([e._data for _, e in picks])
+        with jax.named_scope("picks"):
+            self.routing._data = jnp.stack([c._data for c in counters])
+            if "selection_bits" in self._buffers:
+                self.selection_bits._data = jnp.stack(
+                    [jnp.packbits(s._data.astype(bool), axis=-1)
+                     for s, _ in picks])
+                self.expert_picks._data = jnp.stack(
+                    [e._data for _, e in picks])
         n = float(len(balance))
-        return (fused_lm_loss(hidden, self.lm_head, True, labels),
-                sum(balance[1:], balance[0]) / n,
+        with op_scope("head"):
+            lm = fused_lm_loss(hidden, self.lm_head, True, labels)
+        return (lm, sum(balance[1:], balance[0]) / n,
                 sum(index_loss[1:], index_loss[0]) / n)
 
     def loss(self, input_ids, labels, position_ids=None):

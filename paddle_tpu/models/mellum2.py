@@ -30,11 +30,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import nn
+from ..framework.autograd import op_scope
 from ..framework.tensor import Tensor
 from ..incubate.distributed.models.moe.dropless import DroplessMoE
 from ..ops._dispatch import nary
-from .keye_vl2 import (KeyeAttention, KeyeVL2Model, _queries_keys, _rms,
-                       routing_totals)
+from .keye_vl2 import (KeyeAttention, KeyeVL2Model, _mixture, _queries_keys,
+                       _rms, routing_totals)
 from .llama import LlamaRMSNorm
 
 __all__ = ["Mellum2Config", "Mellum2Model", "Mellum2ForCausalLM",
@@ -142,13 +143,16 @@ class Mellum2DecoderLayer(nn.Layer):
             from ..ops.pallas.splash_attention import splash_attention
 
             b, s, _ = x.shape
-            h = _rms(x, ln, c.rms_norm_eps)
-            cos, sin = rotary_table(c, kind, positions)
-            q, k = _queries_keys(c, h, wq, wk, qn, kn, cos, sin)
-            v = (h @ wv).reshape(b, s, c.num_key_value_heads, c.head_dim)
+            with jax.named_scope("attention/projections"):
+                h = _rms(x, ln, c.rms_norm_eps)
+                cos, sin = rotary_table(c, kind, positions)
+                q, k = _queries_keys(c, h, wq, wk, qn, kn, cos, sin)
+                v = (h @ wv).reshape(b, s, c.num_key_value_heads,
+                                     c.head_dim)
             with jax.named_scope(scope):
                 o = splash_attention(q, k, v, causal=True, window=window)
-            return x + o.reshape(b, s, -1) @ wo
+            with jax.named_scope("attention/projections"):
+                return x + o.reshape(b, s, -1) @ wo
 
         return nary(run, [x, positions, self.input_layernorm.weight,
                           a.q_proj.weight, a.k_proj.weight, a.q_norm.weight,
@@ -156,10 +160,7 @@ class Mellum2DecoderLayer(nn.Layer):
                     "mellum2_attention")
 
     def _whole(self, x, positions):
-        x = self._attend(x, positions)
-        y, balance, stats, picks = self.mlp(
-            self.post_attention_layernorm(x))
-        return x + y, balance, stats, picks
+        return _mixture(self, self._attend(x, positions))
 
     def forward(self, x, positions):
         """-> (x, balance term, the mixture's stats float32 [3] (pairs
@@ -190,14 +191,16 @@ class Mellum2Model(nn.Layer):
             b, s = input_ids.shape
             position_ids = Tensor._wrap(jnp.broadcast_to(
                 jnp.arange(s, dtype=jnp.int32), (b, s)))
-        x = self.embed_tokens(input_ids)
+        with op_scope("embed"):
+            x = self.embed_tokens(input_ids)
         balance, stats, picks = [], [], []
         for layer in self.layers:
             x, bal, st, picked = layer(x, position_ids)
             balance.append(bal)
             stats.append(st)
             picks.append(picked)
-        return self.norm(x), balance, stats, picks
+        with op_scope("head"):
+            return self.norm(x), balance, stats, picks
 
 
 class Mellum2ForCausalLM(nn.Layer):
@@ -248,12 +251,15 @@ class Mellum2ForCausalLM(nn.Layer):
         from .gpt import fused_lm_loss
 
         hidden, balance, stats, picks = self.model(input_ids, position_ids)
-        self.routing._data = jnp.stack(
-            [s._data.astype(jnp.int32) for s in stats])
-        if "expert_picks" in self._buffers:
-            self.expert_picks._data = jnp.stack([e._data for e in picks])
-        return (fused_lm_loss(hidden, self.lm_head, True, labels),
-                sum(balance[1:], balance[0]) / float(len(balance)))
+        with jax.named_scope("picks"):
+            self.routing._data = jnp.stack(
+                [s._data.astype(jnp.int32) for s in stats])
+            if "expert_picks" in self._buffers:
+                self.expert_picks._data = jnp.stack(
+                    [e._data for e in picks])
+        with op_scope("head"):
+            lm = fused_lm_loss(hidden, self.lm_head, True, labels)
+        return lm, sum(balance[1:], balance[0]) / float(len(balance))
 
     def loss(self, input_ids, labels, position_ids=None):
         lm, balance = self.loss_terms(input_ids, labels, position_ids)

@@ -28,6 +28,17 @@ visit no key tile beyond its last query:
   L_I alone: the pass forms L_I's gradient with respect to (qI, kI, w)
   while the chunk's scores are at hand (a custom VJP hands them out
   scaled), and q and k, the main attention's side, get none.
+
+In a device trace the chunk loop's operations carry these
+`jax.named_scope` paths (`paddle_tpu.profiler.DEVICE_SCOPES`; the model
+stands the whole branch under `indexer` and its projections under
+`indexer/project`):
+  indexer/scores   a chunk's index scores and their pull-back
+  indexer/select   the causal mask, `topk_mask`, the kept count
+  indexer/target   the selection's int8 form and `head_mean_probs`
+  indexer/loss     log-softmax over the kept scores, the KL sum, dL_I/dI
+What is left under bare `indexer` is the chunk loop's own slicing, copies
+and sums.
 """
 from __future__ import annotations
 
@@ -140,25 +151,34 @@ def _select_sequence(q, k, q_idx, k_idx, w, topk, chunk, scale, tokens):
 
     def step(dk_acc, xs):
         t0, qc, qic, wc = xs
-        valid = cols[None, :] <= (t0 + jnp.arange(chunk, dtype=jnp.int32))[:, None]
-        scores, pull = jax.vjp(
-            functools.partial(_chunk_scores, t0=t0), qic, k_idx, wc)
-        keep = topk_mask(scores, valid, topk)
+        with jax.named_scope("indexer/select"):
+            valid = cols[None, :] <= (
+                t0 + jnp.arange(chunk, dtype=jnp.int32))[:, None]
+        with jax.named_scope("indexer/scores"):
+            scores, pull = jax.vjp(
+                functools.partial(_chunk_scores, t0=t0), qic, k_idx, wc)
+        with jax.named_scope("indexer/select"):
+            keep = topk_mask(scores, valid, topk)
         # the main attention's probabilities over the kept keys, the
         # mean over heads: the indexer's target, cut from the graph
-        selection = keep.astype(jnp.int8)
-        target = head_mean_probs(qc, k, selection, scale, t0)
-        log_mine = jax.nn.log_softmax(
-            jnp.where(keep, scores, -jnp.inf), axis=-1)
-        kl = jnp.sum(jnp.where(
-            keep & (target > 0),
-            target * (jnp.log(jnp.where(target > 0, target, 1.0))
-                      - jnp.where(keep, log_mine, 0.0)), 0.0))
-        d_scores = jnp.where(keep, jnp.exp(log_mine) - target,
-                             0.0) / tokens
-        dqi, dki, dwc = pull(d_scores)
-        return dk_acc + dki.astype(F32), (
-            selection, kl, jnp.sum(keep, dtype=jnp.int32), dqi, dwc)
+        with jax.named_scope("indexer/target"):
+            selection = keep.astype(jnp.int8)
+            target = head_mean_probs(qc, k, selection, scale, t0)
+        with jax.named_scope("indexer/loss"):
+            log_mine = jax.nn.log_softmax(
+                jnp.where(keep, scores, -jnp.inf), axis=-1)
+            kl = jnp.sum(jnp.where(
+                keep & (target > 0),
+                target * (jnp.log(jnp.where(target > 0, target, 1.0))
+                          - jnp.where(keep, log_mine, 0.0)), 0.0))
+            d_scores = jnp.where(keep, jnp.exp(log_mine) - target,
+                                 0.0) / tokens
+        with jax.named_scope("indexer/scores"):
+            dqi, dki, dwc = pull(d_scores)
+            dk_acc = dk_acc + dki.astype(F32)
+        with jax.named_scope("indexer/select"):
+            kept = jnp.sum(keep, dtype=jnp.int32)
+        return dk_acc, (selection, kl, kept, dqi, dwc)
 
     dk_idx, (mask, kl, kept, dq_idx, dw) = jax.lax.scan(
         step, jnp.zeros(k_idx.shape, F32),

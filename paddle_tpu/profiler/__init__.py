@@ -31,7 +31,7 @@ from jax.profiler import TraceAnnotation
 
 __all__ = [
     "ProfilerState", "ProfilerTarget", "Profiler", "RecordEvent", "Span",
-    "spans",
+    "spans", "DEVICE_SCOPES",
     "make_scheduler", "export_chrome_tracing", "load_profiler_result",
 ]
 
@@ -184,6 +184,45 @@ class RecordEvent:
 
     def __exit__(self, *exc):
         self.end()
+
+
+# The device side's names: every `jax.named_scope` path the mixture models
+# (models/keye_vl2.py, models/mellum2.py and what they call) put on their
+# compiled step's operations. It is what an operator sees in xprof or
+# Perfetto as part of each operation's name, after the step's phase
+# (`jit(step_fn)/forward/...`, `.../backward/transpose(jvp(<path>))/...`),
+# and what benchmark/harness/scope_tree.py builds its tree from: a path
+# `a/b` is a leaf of `a`, the innermost path on an operation's name holds
+# its time. No part of a path is a phase (`forward`, `backward`,
+# `optimizer`). A model that adds a scope adds its path here.
+DEVICE_SCOPES = (
+    "embed",                    # the token embedding and its gradient
+    "attention/projections",    # input norm, q / k / v products, q/k norm,
+                                # rotary, output product and residual
+    "indexer",                  # bare: the query-chunk loop's own copies
+    "indexer/project",          # the branch's norm, projections, rotary
+    "indexer/scores",           # a chunk's index scores and their pull-back
+    "indexer/select",           # causal mask, exact top-k, kept count
+    "indexer/target",           # the head-averaged attention probabilities
+    "indexer/loss",             # log-softmax, KL and d(L_I)/d(scores)
+    "sparse_attention",         # attention over the selection
+    "window_attention",         # a sliding layer's attention
+    "full_attention",           # a full layer's attention
+    "moe/norm",                 # the post-attention norm
+    "moe/route",                # bare: what XLA adds between the leaves
+    "moe/route/router",         # router product, softmax, top-k, balance
+    "moe/route/plan",           # sort into row tables, weights, counters
+    "moe/route/gather",         # a tile's table slices and row gathers
+    "moe/route/add_back",       # the accumulator, a tile's add-back, the
+                                # tile loops' own carries
+    "moe/experts",              # the grouped products
+    "moe/cast",                 # float32 staging round the tile loops: the
+                                # cotangent cast up, outputs and gradients
+                                # cast back to the parameters' type
+    "moe/residual",             # the mixture's output added to the stream
+    "head",                     # final norm, cross entropy over the head
+    "picks",                    # the step's counters and recorded picks
+)
 
 
 _gc_span = []       # the open full-collection span, if any
