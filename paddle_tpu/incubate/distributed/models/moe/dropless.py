@@ -15,14 +15,22 @@ own part: what the absent experts would add is left out, and the partial
 sum goes on (in an expert-parallel deployment the other chips' parts are
 added by the combine). No token is dropped, whatever the imbalance.
 
-How: the (token, expert) pairs of held experts are sorted by expert, each
-expert's rows padded to a multiple of `tile_rows`, and a loop runs over
-the tiles that hold a routed row — a tile gathers its tokens, runs the
-three products with its expert's weights and adds its rows back, so no
-buffer of the worst routing's size exists beyond the int32 row tables
-([T * top_k + held * tile_rows]) and the work follows the routing that
-happened. The loop's trip count is data; its backward is a second loop
-(custom VJP).
+How: the (token, expert) pairs are sorted by expert once, stably, with
+their pair index and gate weight as the sort's payloads (`sort_pairs`):
+the held experts' pairs become one compact list in expert order. Only the
+per-tile tables (`dispatch_plan`: a tile's expert, where it starts in the
+list, how many of its rows are real; prefix sums over the held experts)
+pad an expert's rows to a multiple of `tile_rows`, so no table of the
+worst routing's size is written. A loop runs over the tiles that hold a
+routed row — a tile takes `tile_rows` CONSECUTIVE entries of the list,
+masks those past its real rows, gathers its tokens, runs the three
+products with its expert's weights and adds its rows back: the work
+follows the routing that happened. The loop's trip count is data; its
+backward is a second loop (custom VJP) that leaves the gate weights'
+gradient in the list's order, and a second sort on the pair index takes
+it back. Only vector operations move the routing (compare-and-sum
+histograms, two sorts, prefix sums, slices): on TPU an indexed scatter or
+gather over the pairs runs an element at a time, ~9 ns each.
 
 How a tile's rows move. The gather is XLA's (`x[idx]`). The add-back on
 TPU, for a row of whole lanes, is by row DMAs (`ops/pallas/moe_rows.py
@@ -30,19 +38,21 @@ row_adds`): the float32 accumulator is born as [T, 1, K], where a row is
 one contiguous copy, `moe_add_rows` adds a tile's rows into it in place,
 and it is reshaped once after the loop. On CPU, and for any other width,
 XLA's scatter-add on [T, K]. Either way the additions within a token keep
-the tiles' order and their float32 width. A padding row names token 0
-with weight 0: it is computed, and the kernel never writes it (the first
-`tile_real` rows of a tile are real), for under read-modify-write its copy
-back would meet token 0's own row; XLA's scatter adds its 0.0.
+the tiles' order and their float32 width. A row past a tile's real ones
+(in the list, the next expert's first pair) is masked to token 0 with
+weight 0: it is computed, and the kernel never writes it (a tile's first
+`tile_real` rows are real), for under read-modify-write its copy back
+would meet token 0's own row; XLA's scatter adds its 0.0.
 
 In a device trace (xprof, Perfetto) every operation of the layer carries
 one of these `jax.named_scope` paths, forward and backward (they are part
 of `paddle_tpu.profiler.DEVICE_SCOPES`):
   moe/route/router    the router product, softmax, top-k, renormalised
                       gates, the picks' count and the balance term
-  moe/route/plan      `dispatch_plan` (counts, the stable sort, the row
-                      tables), `row_w`, `tile_real`, the counters
-  moe/route/gather    a tile's slices of the tables, `x[idx]`, `dout[idx]`
+  moe/route/plan      `dispatch_plan` (`sort_pairs` and its pull-back,
+                      the counts, the per-tile tables), the counters
+  moe/route/gather    a tile's slices of the list and their mask,
+                      `x[idx]`, `dout[idx]`
   moe/route/add_back  the accumulator's zeros and final reshape, a tile's
                       add-back, `drow`'s update, and both tile loops'
                       `while` with the copies of its carry
@@ -61,8 +71,8 @@ from ..... import nn
 from .....ops._dispatch import nary
 from .....ops.pallas.moe_rows import row_adds
 
-__all__ = ["DroplessMoE", "dropless_moe", "route_topk", "dispatch_plan",
-           "grouped_ffn"]
+__all__ = ["DroplessMoE", "dropless_moe", "route_topk", "sort_pairs",
+           "dispatch_plan", "grouped_ffn"]
 
 F32 = jnp.float32
 I32 = jnp.int32
@@ -78,40 +88,75 @@ def route_topk(logits, top_k, renormalise=True):
 
 
 def plan_rows(pairs: int, held: int, tile: int) -> int:
-    """Rows of the row tables: every pair on a held expert, and each held
-    expert's last tile padded."""
+    """Most rows a routing can ask of the tile loops: every pair on a held
+    expert, and each held expert's last tile padded."""
     return -(-(pairs + held * (tile - 1)) // tile) * tile
 
 
-def dispatch_plan(experts, held, tile):
-    """Row tables of one routing. experts int32 [T, k]; held = (lo, hi).
+def _count(ids, n):
+    """Histogram of `ids` over 0..n-1 as a compare and a sum, int32 [n]."""
+    return jnp.sum(ids[..., None] == jnp.arange(n, dtype=I32),
+                   tuple(range(ids.ndim)), dtype=I32)
 
-    -> row_token [M] (the token each row computes; 0 on padding),
-       row_pair [M] (its flat pair index t * k + slot; T * k on padding),
-       tile_expert [M / tile] (local expert of each tile), n_tiles,
-       counts [hi - lo] (pairs routed to each held expert)."""
+
+@jax.custom_vjp
+def sort_pairs(local, gates):
+    """local int32 [P], gates float32 [P] -> (pair index, gate) in `local`'s
+    order, by one stable sort that carries both. The gates' pull-back is
+    the sort back on the pair index: JAX's own (`lax._sort_jvp`) gathers
+    the tangents, and that gather's transpose is a scatter-add over P."""
+    pair = jnp.arange(local.shape[0], dtype=I32)
+    return jax.lax.sort((local, pair, gates), num_keys=1, is_stable=True)[1:]
+
+
+def _sort_pairs_fwd(local, gates):
+    pair, gates = sort_pairs(local, gates)
+    return (pair, gates), pair
+
+
+def _sort_pairs_bwd(pair, cts):
+    with jax.named_scope("moe/route/plan"):
+        # the keys are distinct: a stable sort would carry an iota more
+        return None, jax.lax.sort((pair, cts[1]), num_keys=1,
+                                  is_stable=False)[1]
+
+
+sort_pairs.defvjp(_sort_pairs_fwd, _sort_pairs_bwd)
+
+
+def dispatch_plan(experts, gates, held, tile):
+    """One routing as the tile loops take it. experts int32, gates float32
+    [T, k]; held = (lo, hi).
+
+    -> row_token, row_w [T * k + tile]: the pairs' tokens and gate weights
+       in expert order, the held experts' first (a tile's slice may run
+       past the pairs, into `tile` more entries),
+       tile_expert, tile_start, tile_real [plan_rows / tile]: a tile's
+       local expert, its first entry in the lists, how many of its rows
+       (its first) hold a routed pair,
+       n_tiles, counts [hi - lo] (pairs routed to each held expert)."""
     lo, hi = held
     g = hi - lo
     t, k = experts.shape
-    pairs = t * k
-    m = plan_rows(pairs, g, tile)
-    flat = experts.reshape(-1)
-    local = jnp.where((flat >= lo) & (flat < hi), flat - lo, g)
-    counts = jnp.zeros((g + 1,), I32).at[local].add(1)
-    order = jnp.argsort(local, stable=True).astype(I32)
-    sorted_local = local[order]
+    local = jnp.where((experts >= lo) & (experts < hi), experts - lo, g)
+    pair, row_w = sort_pairs(local.reshape(-1), gates.reshape(-1))
+    counts = _count(local, g)
     starts = jnp.cumsum(counts) - counts
-    padded = -(-counts[:g] // tile) * tile
+    padded = -(-counts // tile) * tile
     ends = jnp.cumsum(padded)
-    offsets = jnp.concatenate([ends - padded, jnp.full((1,), m, I32)])
-    rank = jnp.arange(pairs, dtype=I32) - starts[sorted_local]
-    dest = jnp.where(sorted_local < g, offsets[sorted_local] + rank, m)
-    row_token = jnp.zeros((m,), I32).at[dest].set(order // k, mode="drop")
-    row_pair = jnp.full((m,), pairs, I32).at[dest].set(order, mode="drop")
+    # a tile's first row, were each expert's rows padded in one table
+    rows = jnp.arange(plan_rows(t * k, g, tile) // tile, dtype=I32) * tile
     tile_expert = jnp.minimum(
-        jnp.searchsorted(ends, jnp.arange(m // tile, dtype=I32) * tile,
-                         side="right"), g - 1).astype(I32)
-    return row_token, row_pair, tile_expert, ends[-1] // tile, counts[:g]
+        jnp.sum(ends <= rows[:, None], 1, dtype=I32), g - 1)
+    own = tile_expert[:, None] == jnp.arange(g, dtype=I32)
+
+    def of_tile(per_expert):
+        return jnp.sum(jnp.where(own, per_expert, 0), 1, dtype=I32)
+
+    return (jnp.pad(pair // k, (0, tile)), jnp.pad(row_w, (0, tile)),
+            tile_expert, of_tile(starts - ends + padded) + rows,
+            jnp.clip(of_tile(counts + ends - padded) - rows, 0, tile),
+            ends[-1] // tile, counts)
 
 
 def _dot(a, b, dims):
@@ -119,20 +164,21 @@ def _dot(a, b, dims):
                                preferred_element_type=F32)
 
 
-def _tile(i, tile, row_token, row_w, tile_expert, tile_real):
+def _tile(i, tile, row_token, row_w, tile_expert, tile_start, tile_real):
     with jax.named_scope("moe/route/gather"):
-        idx = jax.lax.dynamic_slice(row_token, (i * tile,), (tile,))
-        w = jax.lax.dynamic_slice(row_w, (i * tile,), (tile,))
-        return idx, w, tile_expert[i], tile_real[i]
+        start, n_real = tile_start[i], tile_real[i]
+        real = jnp.arange(tile, dtype=I32) < n_real
+        idx = jax.lax.dynamic_slice(row_token, (start,), (tile,))
+        w = jax.lax.dynamic_slice(row_w, (start,), (tile,))
+        return (jnp.where(real, idx, 0), jnp.where(real, w, 0.0),
+                tile_expert[i], n_real, real)
 
 
-def _ffn_fwd_loop(x, wg, wu, wd, row_token, row_w, tile_expert, tile_real,
-                  n_tiles, tile):
+def _ffn_fwd_loop(x, wg, wu, wd, *tables, n_tiles, tile):
     adds = row_adds(x.shape[1], tile)
 
     def body(i, out):
-        idx, w, e, n_real = _tile(i, tile, row_token, row_w, tile_expert,
-                                  tile_real)
+        idx, w, e, n_real, _ = _tile(i, tile, *tables)
         with jax.named_scope("moe/route/gather"):
             h = x[idx]
         with jax.named_scope("moe/experts"):
@@ -147,38 +193,37 @@ def _ffn_fwd_loop(x, wg, wu, wd, row_token, row_w, tile_expert, tile_real,
                                             adds.zeros(x.shape)))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(9,))
-def grouped_ffn(x, wg, wu, wd, row_token, row_w, tile_expert, tile_real,
-                n_tiles, tile):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(10,))
+def grouped_ffn(x, wg, wu, wd, row_token, row_w, tile_expert, tile_start,
+                tile_real, n_tiles, tile):
     """sum over the rows r of a routing of row_w[r] * FFN_e(x[row_token[r]])
     added at row_token[r] -> float32 [T, K]. x [T, K]; wg, wu [G, K, N];
-    wd [G, N, K]; the tables are `dispatch_plan`'s, `tile_real` [M / tile]
-    the rows of each tile that hold a routed pair (its first rows: the
-    tokens of a tile's real rows are distinct, and the rest are padding,
-    which is computed and never added back). Tiles from `n_tiles` on are
-    not computed."""
+    wd [G, N, K]; the lists and tables are `dispatch_plan`'s: tile i
+    computes `tile` entries from tile_start[i] with expert tile_expert[i];
+    its first tile_real[i] rows are real (their tokens are distinct), the
+    rest computed as token 0 with weight 0 and never added back. Tiles
+    from `n_tiles` on are not computed. `row_w`'s gradient is in the
+    lists' order, 0 where no tile has a real row."""
     return _ffn_fwd_loop(x, wg, wu, wd, row_token, row_w, tile_expert,
-                         tile_real, n_tiles, tile)
+                         tile_start, tile_real, n_tiles=n_tiles, tile=tile)
 
 
-def _grouped_ffn_fwd(x, wg, wu, wd, row_token, row_w, tile_expert, tile_real,
-                     n_tiles, tile):
-    out = _ffn_fwd_loop(x, wg, wu, wd, row_token, row_w, tile_expert,
-                        tile_real, n_tiles, tile)
-    return out, (x, wg, wu, wd, row_token, row_w, tile_expert, tile_real,
-                 n_tiles)
+def _grouped_ffn_fwd(x, wg, wu, wd, *rest):
+    *tables, n_tiles, tile = rest
+    out = _ffn_fwd_loop(x, wg, wu, wd, *tables, n_tiles=n_tiles, tile=tile)
+    return out, (x, wg, wu, wd, tables, n_tiles)
 
 
 def _grouped_ffn_bwd(tile, res, dout):
-    x, wg, wu, wd, row_token, row_w, tile_expert, tile_real, n_tiles = res
+    x, wg, wu, wd, tables, n_tiles = res
+    _, row_w, _, tile_start, _ = tables
     adds = row_adds(x.shape[1], tile)
     with jax.named_scope("moe/cast"):
         dout = dout.astype(F32)
 
     def body(i, carry):
         dx, dwg, dwu, dwd, drow = carry
-        idx, w, e, n_real = _tile(i, tile, row_token, row_w, tile_expert,
-                                  tile_real)
+        idx, w, e, n_real, real = _tile(i, tile, *tables)
         with jax.named_scope("moe/route/gather"):
             h = x[idx]
             dy_rows = dout[idx]
@@ -201,7 +246,10 @@ def _grouped_ffn_bwd(tile, res, dout):
             dwd = dwd.at[e].add(_dot(a, dy, ((0,), (0,))))
         with jax.named_scope("moe/route/add_back"):
             dx = adds.add(dx, idx, dh, n_real)
-            drow = jax.lax.dynamic_update_slice(drow, drow_i, (i * tile,))
+            # a row past the real ones is the next tile's entry: tiles run
+            # in order, so its 0 is overwritten there or is nobody's
+            drow = jax.lax.dynamic_update_slice(
+                drow, jnp.where(real, drow_i, 0.0), (tile_start[i],))
         return dx, dwg, dwu, dwd, drow
 
     # the loop stands under a leaf, so that its `while` and the copies of
@@ -215,7 +263,7 @@ def _grouped_ffn_bwd(tile, res, dout):
     with jax.named_scope("moe/cast"):
         return (dx.astype(x.dtype), dwg.astype(wg.dtype),
                 dwu.astype(wu.dtype), dwd.astype(wd.dtype), None,
-                drow.astype(row_w.dtype), None, None, None)
+                drow.astype(row_w.dtype), None, None, None, None)
 
 
 grouped_ffn.defvjp(_grouped_ffn_fwd, _grouped_ffn_bwd)
@@ -236,18 +284,13 @@ def dropless_moe(h, wr, wg, wu, wd, *, top_k, held, tile_rows,
     with jax.named_scope("moe/route/router"):
         p, experts, gates = route_topk(
             _dot(h, wr, ((1,), (0,))), top_k, renormalise)
-        picked = jnp.zeros((n_experts,), F32).at[experts.reshape(-1)].add(1.0)
+        picked = _count(experts, n_experts).astype(F32)
         balance = balance_coef * n_experts * jnp.sum(
             jax.lax.stop_gradient(picked / h.shape[0]) * jnp.mean(p, 0))
     with jax.named_scope("moe/route/plan"):
-        row_token, row_pair, tile_expert, n_tiles, counts = dispatch_plan(
-            experts, held, tile_rows)
-        row_w = jnp.concatenate([gates.reshape(-1),
-                                 jnp.zeros((1,), F32)])[row_pair]
-        tile_real = jnp.sum(
-            (row_pair < experts.size).reshape(-1, tile_rows), 1, dtype=I32)
-    y = grouped_ffn(h, wg, wu, wd, row_token, row_w, tile_expert, tile_real,
-                    n_tiles, tile_rows)
+        *tables, n_tiles, counts = dispatch_plan(experts, gates, held,
+                                                 tile_rows)
+    y = grouped_ffn(h, wg, wu, wd, *tables, n_tiles, tile_rows)
     with jax.named_scope("moe/route/plan"):
         load = counts.astype(F32)
         stats = jnp.stack([jnp.sum(load), (n_tiles * tile_rows).astype(F32),

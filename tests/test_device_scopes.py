@@ -97,8 +97,9 @@ def test_the_innermost_path_on_an_operations_name(path, node):
 @pytest.mark.parametrize("held", [(0, 8), (2, 6)])
 def test_every_equation_of_the_dropless_layer_stands_under_a_leaf(held):
     """Forward and backward (the custom VJP's second loop, the router's
-    and the tables' pull-back): no equation under bare `moe/route`, none
-    under no name, and the tile loops' `while`s under the add-back."""
+    pull-back and the sort's, the un-sort): no equation under bare
+    `moe/route`, none under no name, and the only `while`s the two tile
+    loops', under the add-back; both sorts stand under the plan."""
     t, k, n, e = 64, 32, 24, 8
     rng = np.random.default_rng(0)
     # bfloat16, as the cells run it: the float32 staging's casts are real
@@ -120,10 +121,11 @@ def test_every_equation_of_the_dropless_layer_stands_under_a_leaf(held):
     leaves = {"moe/route/router", "moe/route/plan", "moe/route/gather",
               "moe/route/add_back", "moe/experts", "moe/cast"}
     assert {node_of(path) for _, path in found} == leaves
-    loops = [path for prim, path in found if prim == "while"
-             and "searchsorted" not in path]
+    loops = [path for prim, path in found if prim == "while"]
     assert len(loops) == 2
     assert all(node_of(p) == "moe/route/add_back" for p in loops)
+    sorts = [node_of(path) for prim, path in found if prim == "sort"]
+    assert sorts == ["moe/route/plan"] * 2
 
 
 def test_every_equation_of_the_indexer_branch_stands_under_a_leaf():

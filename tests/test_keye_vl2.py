@@ -614,16 +614,18 @@ def test_the_new_kernels_compile_for_a_v5e_at_published_widths(v5e_chip,
         vmem = moe_rows._add_rows_vmem(tile, k)
         assert vmem == {2048: 10_485_760, 2304: 11_534_336}[k]
 
-        def fn(x, wg, wu, wd, row_w, tokens, tile_expert, tile_real, n_tiles,
-               dout):
+        def fn(x, wg, wu, wd, row_w, tokens, tile_expert, tile_start,
+               tile_real, n_tiles, dout):
             out, pull = jax.vjp(
                 lambda *a: dropless.grouped_ffn(
-                    *a[:4], tokens, a[4], tile_expert, tile_real, n_tiles,
-                    tile), x, wg, wu, wd, row_w)
+                    *a[:4], tokens, a[4], tile_expert, tile_start, tile_real,
+                    n_tiles, tile), x, wg, wu, wd, row_w)
             return out, pull(dout)
         args, want = (spec((t, k), bf16), spec((g, k, n), bf16),
                       spec((g, k, n), bf16), spec((g, n, k), bf16),
-                      spec((m,), jnp.float32), spec((m,), jnp.int32),
+                      spec((t * 8 + tile,), jnp.float32),
+                      spec((t * 8 + tile,), jnp.int32),
+                      spec((m // tile,), jnp.int32),
                       spec((m // tile,), jnp.int32),
                       spec((m // tile,), jnp.int32), spec((), jnp.int32),
                       spec((t, k), jnp.float32)), {"moe_add_rows"}

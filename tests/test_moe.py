@@ -803,13 +803,15 @@ class TestFusedEcMoe:
 # Interpret mode runs the kernel's asynchronous copies and semaphores on
 # the CPU; XLA's scatter-add on the [T, K] array is the oracle.
 
-def _row_tables(experts, held, tile):
+def _row_tables(experts, held, tile, gates=None):
+    """`dispatch_plan`'s lists and per-tile tables; the gate of pair j is
+    j + 1 by default, so a list entry says which pair it is."""
     from paddle_tpu.incubate.distributed.models.moe import dropless
-    row_token, row_pair, tile_expert, n_tiles, _ = dropless.dispatch_plan(
-        jnp.asarray(experts, jnp.int32), held, tile)
-    real = row_pair < experts.size
-    return (row_token, real, tile_expert,
-            jnp.sum(real.reshape(-1, tile), 1, dtype=jnp.int32), n_tiles)
+    experts = jnp.asarray(experts, jnp.int32)
+    if gates is None:
+        gates = jnp.arange(1, experts.size + 1, dtype=jnp.float32)
+    return dropless.dispatch_plan(experts, gates.reshape(experts.shape),
+                                  held, tile)
 
 
 def _grouped(x, wg, wu, wd, row_w, tables, tile, interpret):
@@ -817,11 +819,12 @@ def _grouped(x, wg, wu, wd, row_w, tables, tile, interpret):
     kernel (interpreted) or the XLA scatter-add the CPU takes."""
     from paddle_tpu.incubate.distributed.models.moe import dropless
     from paddle_tpu.utils import flags
-    row_token, _, tile_expert, tile_real, n_tiles = tables
+    row_token, _, tile_expert, tile_start, tile_real, n_tiles, _ = tables
 
     def f(x, wg, wu, wd, row_w):
         return dropless.grouped_ffn(x, wg, wu, wd, row_token, row_w,
-                                    tile_expert, tile_real, n_tiles, tile)
+                                    tile_expert, tile_start, tile_real,
+                                    n_tiles, tile)
 
     flags.set_flags({"FLAGS_pallas_force_interpret": interpret})
     try:
@@ -938,18 +941,20 @@ class TestMoeRows:
             experts = np.stack([rng.choice(8, 2, replace=False, p=p)
                                 for _ in range(t)])
         tables = _row_tables(experts, (0, g), tile)
-        row_token, real, _, tile_real, n_tiles = tables
+        row_token, _, _, tile_start, tile_real, n_tiles, _ = tables
         if case == "token0_before_padding":
+            # the tile's five other rows are pairs of no held expert,
+            # masked in the loops to token 0
             assert int(n_tiles) == 1 and int(tile_real[0]) == 3
-            assert list(np.asarray(row_token[:8])) == [0, 3, 4, 0, 0, 0, 0, 0]
+            assert list(np.asarray(row_token[:3])) == [0, 3, 4]
         if case == "token_on_two_experts":
             assert int(n_tiles) == 2
+            assert list(np.asarray(tile_start[:2])) == [0, 8]
             np.testing.assert_array_equal(row_token[:8], row_token[8:16])
         if case == "no_tiles":
             assert int(n_tiles) == 0
-        x, wg, wu, wd, row_w = _ffn_operands(t, k, n, g, real.shape[0],
+        x, wg, wu, wd, row_w = _ffn_operands(t, k, n, g, row_token.shape[0],
                                              dtype, 6)
-        row_w = jnp.where(real, row_w, 0.0)
         want = _grouped(x, wg, wu, wd, row_w, tables, tile, False)
         got = _grouped(x, wg, wu, wd, row_w, tables, tile, True)
         if case != "no_tiles":
@@ -959,3 +964,318 @@ class TestMoeRows:
             assert a.dtype == b.dtype, name
             np.testing.assert_array_equal(np.asarray(a, np.float32),
                                           np.asarray(b, np.float32), name)
+
+
+# -- the dropless layer's routing (dropless.py sort_pairs, dispatch_plan) --
+# A NumPy counting sort is the oracle of the plan; the parent's form of the
+# routing (an [M]-row table scattered into, the gates gathered through it)
+# is the oracle of the layer, kept here and nowhere in the package.
+
+def _np_tiles(experts, gates, held, tile):
+    """[(local expert, tokens, gates)] of each tile in order, and the held
+    experts' counts, by a counting sort."""
+    lo, hi = held
+    flat, k = experts.reshape(-1), experts.shape[1]
+    tiles = []
+    for e in range(lo, hi):
+        pairs = np.flatnonzero(flat == e)          # ascending: stable
+        tiles += [(e - lo, pairs[j:j + tile] // k,
+                   gates.reshape(-1)[pairs[j:j + tile]])
+                  for j in range(0, len(pairs), tile)]
+    return tiles, np.bincount(flat, minlength=hi)[lo:hi]
+
+
+def _routing(case):
+    """-> (experts int [T, k], held, tile, router width)."""
+    rng = np.random.default_rng(7)
+    if case == "top8_of_64":
+        return (np.stack([rng.permutation(64)[:8] for _ in range(512)]),
+                (0, 16), 32, 64)
+    if case == "an_expert_without_a_pair":
+        experts = np.stack([rng.permutation(7)[:2] for _ in range(40)])
+        return np.where(experts >= 3, experts + 1, experts), (1, 6), 8, 8
+    if case == "loads_512_and_513":
+        experts = np.full((600, 2), 5)
+        experts[:512, 0], experts[:513, 1] = 0, 1
+        return experts, (0, 4), 512, 8
+    if case == "all_on_one_expert":
+        return np.full((50, 1), 2), (1, 4), 16, 6
+    if case == "held_is_all":
+        # no pair of an expert not held behind the last tile's real rows
+        return (np.stack([rng.permutation(4)[:2] for _ in range(21)]),
+                (0, 4), 8, 4)
+    if case == "held_is_all_one_row_in_the_last_tile":
+        experts = np.zeros((17, 1), np.int64)
+        experts[16] = 1
+        return experts, (0, 2), 16, 2
+    if case == "nothing_held":
+        return np.full((12, 2), 5) + np.arange(2), (0, 4), 8, 8
+    assert case == "a_tile_that_divides_nothing"
+    return (np.stack([rng.permutation(5)[:3] for _ in range(33)]),
+            (1, 4), 7, 5)
+
+
+class TestDroplessPlan:
+    @pytest.mark.parametrize("case", [
+        "top8_of_64", "an_expert_without_a_pair", "loads_512_and_513",
+        "all_on_one_expert", "held_is_all",
+        "held_is_all_one_row_in_the_last_tile", "nothing_held",
+        "a_tile_that_divides_nothing"])
+    def test_the_plan_is_a_counting_sort(self, case):
+        """Per tile the expert, the count of real rows, and the tokens and
+        gate weights of its rows as the loops take them (`_tile`: the
+        slice and its mask); the histograms are `np.bincount`."""
+        from paddle_tpu.incubate.distributed.models.moe import dropless
+        experts, held, tile, width = _routing(case)
+        rng = np.random.default_rng(8)
+        gates = rng.random(experts.shape).astype(np.float32) + 0.5
+        want, counts = _np_tiles(experts, gates, held, tile)
+        *tables, n_tiles, got_counts = _row_tables(
+            experts, held, tile, jnp.asarray(gates))
+        row_token, row_w, tile_expert, tile_start, tile_real = tables
+        pairs, g = experts.size, held[1] - held[0]
+        assert row_token.shape == row_w.shape == (pairs + tile,)
+        assert tile_expert.shape == tile_start.shape == tile_real.shape == (
+            dropless.plan_rows(pairs, g, tile) // tile,)
+        assert {a.dtype for a in tables} == {
+            jnp.dtype("int32"), jnp.dtype("float32")}
+        np.testing.assert_array_equal(got_counts, counts)
+        np.testing.assert_array_equal(
+            dropless._count(jnp.asarray(experts, jnp.int32), width),
+            np.bincount(experts.reshape(-1), minlength=width))
+        assert int(n_tiles) == len(want) <= tile_real.shape[0]
+        assert not np.asarray(tile_real[len(want):]).any()
+        if case == "loads_512_and_513":
+            assert list(np.asarray(tile_real[:4])) == [512, 512, 1, 0]
+            assert list(np.asarray(tile_start[:3])) == [0, 512, 1024]
+        if case == "nothing_held":
+            assert len(want) == 0
+        if case.startswith("held_is_all"):
+            # the last tile's slice runs past the pairs, into the tail
+            assert int(tile_start[len(want) - 1]) + tile > pairs
+        for i, (e, tokens, w) in enumerate(want):
+            assert int(tile_start[i]) + tile <= row_token.shape[0]
+            idx, got_w, got_e, n_real, real = dropless._tile(
+                jnp.int32(i), tile, *tables)
+            assert (int(got_e), int(n_real)) == (e, len(tokens)), i
+            pad = tile - len(tokens)
+            np.testing.assert_array_equal(real, np.arange(tile) < len(tokens))
+            np.testing.assert_array_equal(idx, np.pad(tokens, (0, pad)))
+            np.testing.assert_array_equal(got_w, np.pad(w, (0, pad)))
+
+    def test_the_sort_carries_pair_and_gate_and_sorts_the_gradient_back(self):
+        from paddle_tpu.incubate.distributed.models.moe import dropless
+        rng = np.random.default_rng(9)
+        local = jnp.asarray(rng.integers(0, 5, 200), jnp.int32)
+        gates = jnp.asarray(rng.random(200), jnp.float32)
+        order = np.argsort(np.asarray(local), kind="stable")
+        (pair, sorted_gates), pull = jax.vjp(
+            lambda g: dropless.sort_pairs(local, g), gates)
+        np.testing.assert_array_equal(pair, order)
+        np.testing.assert_array_equal(sorted_gates, np.asarray(gates)[order])
+        cot = jnp.asarray(rng.standard_normal(200), jnp.float32)
+        zero = np.zeros((200,), jax.dtypes.float0)
+        want = np.zeros(200, np.float32)
+        want[order] = np.asarray(cot)
+        np.testing.assert_array_equal(pull((zero, cot))[0], want)
+
+
+def _parents_layer(h, wr, wg, wu, wd, *, top_k, held, tile_rows,
+                   balance_coef):
+    """`dropless_moe` as the parent of PR 38 routed it: `picked` and the
+    counts as scatter-adds, an argsort, row tables of `plan_rows` rows
+    scattered into, the gates gathered through `row_pair` (their gradient
+    its transpose, a scatter-add). The tile loops are the package's, which
+    take these tables with tile i starting at row i * tile."""
+    from paddle_tpu.incubate.distributed.models.moe import dropless as d
+    f32, i32 = jnp.float32, jnp.int32
+    n_experts, tile = wr.shape[-1], tile_rows
+    p, experts, gates = d.route_topk(d._dot(h, wr, ((1,), (0,))), top_k)
+    picked = jnp.zeros((n_experts,), f32).at[experts.reshape(-1)].add(1.0)
+    balance = balance_coef * n_experts * jnp.sum(
+        jax.lax.stop_gradient(picked / h.shape[0]) * jnp.mean(p, 0))
+    lo, hi = held
+    g = hi - lo
+    t, k = experts.shape
+    pairs = t * k
+    m = d.plan_rows(pairs, g, tile)
+    flat = experts.reshape(-1)
+    local = jnp.where((flat >= lo) & (flat < hi), flat - lo, g)
+    counts = jnp.zeros((g + 1,), i32).at[local].add(1)
+    order = jnp.argsort(local, stable=True).astype(i32)
+    sorted_local = local[order]
+    starts = jnp.cumsum(counts) - counts
+    padded = -(-counts[:g] // tile) * tile
+    ends = jnp.cumsum(padded)
+    offsets = jnp.concatenate([ends - padded, jnp.full((1,), m, i32)])
+    rank = jnp.arange(pairs, dtype=i32) - starts[sorted_local]
+    dest = jnp.where(sorted_local < g, offsets[sorted_local] + rank, m)
+    row_token = jnp.zeros((m,), i32).at[dest].set(order // k, mode="drop")
+    row_pair = jnp.full((m,), pairs, i32).at[dest].set(order, mode="drop")
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(ends, jnp.arange(m // tile, dtype=i32) * tile,
+                         side="right"), g - 1).astype(i32)
+    n_tiles = ends[-1] // tile
+    row_w = jnp.concatenate([gates.reshape(-1),
+                             jnp.zeros((1,), f32)])[row_pair]
+    tile_real = jnp.sum((row_pair < pairs).reshape(-1, tile), 1, dtype=i32)
+    y = d.grouped_ffn(h, wg, wu, wd, row_token, row_w, tile_expert,
+                      jnp.arange(m // tile, dtype=i32) * tile, tile_real,
+                      n_tiles, tile)
+    load = counts[:g].astype(f32)
+    stats = jnp.stack([jnp.sum(load), (n_tiles * tile).astype(f32),
+                       jnp.max(load)])
+    return y.astype(h.dtype), balance, stats, experts
+
+
+def _layer_operands(routing, dtype):
+    """h, wr and three expert stacks of width 256 (whole lanes: the
+    interpreted kernel takes it) whose router picks `routing` [T, 2]: the
+    first features of a token are its logits, the router reads them off."""
+    t, (k, n, e) = routing.shape[0], (256, 128, 8)
+    rng = np.random.default_rng(11)
+    h = rng.standard_normal((t, k)) * 0.5
+    h[:, :e] = rng.random((t, e)) * 0.25
+    h[np.arange(t), routing[:, 0]] = 3.0
+    h[np.arange(t), routing[:, 1]] = 2.0
+    wr = rng.standard_normal((k, e)) * 0.01
+    wr[:e] = np.eye(e) * 4.0
+    return [jnp.asarray(a, dtype) for a in (
+        h, wr, rng.standard_normal((4, k, n)) * 0.1,
+        rng.standard_normal((4, k, n)) * 0.1,
+        rng.standard_normal((4, n, k)) * 0.1)]
+
+
+class TestDroplessIsTheParentsLayer:
+    @pytest.mark.parametrize("interpret", [False, True],
+                             ids=["xla_loop", "kernel"])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("case", ["unequal_loads", "two_tiles_one_token",
+                                      "boundary_in_a_masked_tail"])
+    def test_bit_for_bit(self, case, dtype, interpret):
+        """Output, balance term, stats, picks and the gradients of h, the
+        router and the three expert stacks, through XLA's scatter-add and
+        through the interpreted row-DMA kernel."""
+        from paddle_tpu.incubate.distributed.models.moe import dropless
+        from paddle_tpu.utils import flags
+        t, tile, held = 32, 8, (2, 6)
+        rng = np.random.default_rng(12)
+        if case == "unequal_loads":
+            p = np.array([.1, .1, 0, .05, .3, .25, .1, .1])
+            routing = np.stack([rng.choice(8, 2, replace=False, p=p)
+                                for _ in range(t)])
+        elif case == "two_tiles_one_token":
+            # tokens 0..7 fill a tile of expert 2 and the next, of expert 3
+            routing = np.tile([0, 7], (t, 1))
+            routing[:8] = [2, 3]
+        else:
+            # expert 2's one tile has 3 real rows: the five behind them in
+            # the list are expert 4's pairs, and tile 1 starts at entry 3
+            routing = np.tile([0, 7], (t, 1))
+            routing[[1, 5, 9], 0] = 2
+            routing[10:15, 1] = 4
+        args = _layer_operands(routing, jnp.dtype(dtype))
+        kw = dict(top_k=2, held=held, tile_rows=tile, balance_coef=0.01)
+
+        def run(layer):
+            def f(*a):
+                y, balance, stats, picks = layer(*a, **kw)
+                return (y, balance), (stats, picks)
+            out, pull, aux = jax.vjp(f, *args, has_aux=True)
+            cot = jnp.cos(jnp.arange(out[0].size, dtype=jnp.float32))
+            return out, aux, pull((cot.reshape(out[0].shape).astype(
+                out[0].dtype), jnp.float32(1.5)))
+
+        flags.set_flags({"FLAGS_pallas_force_interpret": interpret})
+        try:
+            got, want = run(dropless.dropless_moe), run(_parents_layer)
+        finally:
+            flags.set_flags({"FLAGS_pallas_force_interpret": False})
+        np.testing.assert_array_equal(want[1][1], routing)
+        if case != "unequal_loads":         # rows computed: two tiles
+            assert float(want[1][0][1]) == 2 * tile
+        assert float(jnp.max(jnp.abs(want[2][1].astype(jnp.float32)))) > 0
+        names = ("y", "balance", "stats", "picks", "dh", "dwr", "dwg", "dwu",
+                 "dwd")
+        for name, a, b in zip(names, jax.tree_util.tree_leaves(got),
+                              jax.tree_util.tree_leaves(want), strict=True):
+            assert a.dtype == b.dtype and a.shape == b.shape, name
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32), name)
+
+
+def _indexed_moves(text):
+    """(name, its operand and result types) of every gather and scatter of
+    a lowered program (a scatter's types stand behind its region)."""
+    import re
+    types = re.compile(r"[>)] : (\(tensor[^\n]*)\n")
+    return [(m.group(1), types.search(text, m.end()).group(1))
+            for m in re.finditer(r'"stablehlo\.(gather|scatter)"\(', text)]
+
+
+class TestNoIndexedMoveOverThePairs:
+    T, K, E, TOP, TILE, HELD = 48, 32, 8, 3, 8, (2, 6)
+
+    def _operands(self):
+        rng = np.random.default_rng(13)
+        g = self.HELD[1] - self.HELD[0]
+        return [jnp.asarray(rng.standard_normal(s), jnp.bfloat16) for s in (
+            (self.T, self.K), (self.K, self.E), (g, self.K, 24),
+            (g, self.K, 24), (g, 24, self.K))]
+
+    def test_router_and_plan_and_their_pull_back(self):
+        """Forward: no gather, no scatter. The pull-back of a gate
+        cotangent: ONE scatter, `lax.top_k`'s own into p [T, E] (the
+        router's, not the plan's), and no gather."""
+        from paddle_tpu.incubate.distributed.models.moe import dropless as d
+        h, wr = self._operands()[:2]
+
+        def route(h, wr):
+            p, experts, gates = d.route_topk(
+                d._dot(h, wr, ((1,), (0,))), self.TOP)
+            picked = d._count(experts, self.E)
+            balance = jnp.sum(jax.lax.stop_gradient(
+                picked.astype(jnp.float32)) * jnp.mean(p, 0))
+            row_token, row_w, *tables = d.dispatch_plan(
+                experts, gates, self.HELD, self.TILE)
+            return (row_w, balance), (row_token, tables)
+
+        def pull_back(h, wr, cot):
+            return jax.vjp(route, h, wr, has_aux=True)[1](cot)
+
+        forward = jax.jit(route).lower(h, wr).as_text()
+        assert "stablehlo.sort" in forward
+        assert _indexed_moves(forward) == []
+        cot = (jnp.ones((self.T * self.TOP + self.TILE,), jnp.float32),
+               jnp.ones((), jnp.float32))
+        moves = _indexed_moves(jax.jit(pull_back).lower(h, wr, cot).as_text())
+        assert [name for name, _ in moves] == ["scatter"], moves
+        assert moves[0][1].endswith(f"-> tensor<{self.T}x{self.E}xf32>")
+
+    def test_the_whole_layer_and_its_pull_back(self):
+        """With the tile loops: the indexed moves left are a tile's rows
+        (`x[idx]`, `dout[idx]`, the add-back, an expert's dW) and top-k's
+        pull-back; none has a dimension of the pairs' size."""
+        from paddle_tpu.incubate.distributed.models.moe import dropless as d
+        import re
+        args = self._operands()
+        cot = (jnp.ones((self.T, self.K), jnp.bfloat16),
+               jnp.ones((), jnp.float32))
+        pairs = self.T * self.TOP
+        over = {pairs, pairs + 1, pairs + self.TILE, d.plan_rows(
+            pairs, self.HELD[1] - self.HELD[0], self.TILE)}
+
+        def over_the_pairs(layer):
+            def both(cot, *a):
+                out, pull = jax.vjp(lambda *a: layer(
+                    *a, top_k=self.TOP, held=self.HELD, tile_rows=self.TILE,
+                    balance_coef=0.01)[:2], *a)
+                return out, pull(cot)
+            moves = _indexed_moves(jax.jit(both).lower(cot, *args).as_text())
+            assert {"gather", "scatter"} == {name for name, _ in moves}
+            return [(name, types) for name, types in moves if over & {
+                int(n) for n in re.findall(r"(\d+)x", types)}]
+
+        assert over_the_pairs(d.dropless_moe) == []
+        # what the guard is there to see: the parent's form has nine
+        assert len(over_the_pairs(_parents_layer)) == 9
