@@ -5,9 +5,20 @@ token over capacity is dropped. At tens of thousands of tokens and a
 hundred experts a mask that drops nothing cannot exist, so this layer
 routes by index instead:
 
-  p = softmax(h Wr) over ALL `num_experts`, in float32
-  E_t = the top `top_k` of p[t]; g[t, e] = p[t, e] / sum_{E_t} p
-  y[t] = sum_{e in E_t and held} g[t, e] * Wd_e(silu(Wg_e h) * Wu_e h)
+  p = score(h Wr) over ALL `num_experts`, in float32
+  E_t = the top `top_k` of p[t] (+ a selection bias); g[t, e] from p[t, E_t]
+  y[t] = sum_{e in E_t and held} g[t, e] * FFN_e(h)
+
+The layer is told the router's score and the experts' form, two of each:
+
+  score "softmax": p = softmax(logits); g = p[E_t], divided by its sum
+      when `renormalise`
+  score "sigmoid": p = sigmoid(logits); E_t = the top k of p + `bias` (a
+      buffer no gradient reaches; it moves the selection only);
+      g = p[E_t] / (sum + 1e-20) when `renormalise`, times `scale`; the
+      balance term reads p / sum_e p
+  gated (three weights):   FFN_e(h) = Wd_e (silu(Wg_e h) * Wu_e h)
+  ungated (`wg` is None):  FFN_e(h) = Wd_e relu(Wu_e h)^2
 
 `held_experts=(lo, hi)` names the contiguous experts whose weights this
 layer holds (all by default). It routes over all of them and computes its
@@ -23,8 +34,8 @@ list, how many of its rows are real; prefix sums over the held experts)
 pad an expert's rows to a multiple of `tile_rows`, so no table of the
 worst routing's size is written. A loop runs over the tiles that hold a
 routed row — a tile takes `tile_rows` CONSECUTIVE entries of the list,
-masks those past its real rows, gathers its tokens, runs the three
-products with its expert's weights and adds its rows back: the work
+masks those past its real rows, gathers its tokens, runs the three (two,
+ungated) products with its expert's weights and adds its rows back: the work
 follows the routing that happened. The loop's trip count is data; its
 backward is a second loop (custom VJP) that leaves the gate weights'
 gradient in the list's order, and a second sort on the pair index takes
@@ -56,7 +67,7 @@ of `paddle_tpu.profiler.DEVICE_SCOPES`):
   moe/route/add_back  the accumulator's zeros and final reshape, a tile's
                       add-back, `drow`'s update, and both tile loops'
                       `while` with the copies of its carry
-  moe/experts         the three products of a tile and their gradients
+  moe/experts         the products of a tile and their gradients
   moe/cast            float32 staging round the loops: the cotangent cast
                       up, the output and the gradients cast back
 """
@@ -78,13 +89,28 @@ F32 = jnp.float32
 I32 = jnp.int32
 
 
-def route_topk(logits, top_k, renormalise=True):
-    """-> (p [T, E] float32, experts [T, k] int32, gates [T, k] float32)."""
-    p = jax.nn.softmax(logits.astype(F32), axis=-1)
-    top, experts = jax.lax.top_k(p, top_k)         # ties: lower index
+def route_topk(logits, top_k, renormalise=True, score="softmax", bias=None,
+               scale=1.0):
+    """-> (p [T, E] float32 (what the balance term reads), experts [T, k]
+    int32, gates [T, k] float32). `score`, `bias`, `scale`: module
+    docstring."""
+    if score == "softmax":
+        p = jax.nn.softmax(logits.astype(F32), axis=-1)
+        top, experts = jax.lax.top_k(p, top_k)     # ties: lower index
+        if renormalise:
+            top = top / jnp.sum(top, axis=-1, keepdims=True)
+        return p, experts.astype(I32), top
+    if score != "sigmoid":
+        raise ValueError(f"route_topk: unknown score {score!r}")
+    s = jax.nn.sigmoid(logits.astype(F32))
+    chosen = s if bias is None else s + jax.lax.stop_gradient(
+        bias.astype(F32))
+    experts = jax.lax.top_k(chosen, top_k)[1]      # ties: lower index
+    top = jnp.take_along_axis(s, experts, axis=-1)
     if renormalise:
-        top = top / jnp.sum(top, axis=-1, keepdims=True)
-    return p, experts.astype(I32), top
+        top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+    return (s / jnp.sum(s, axis=-1, keepdims=True), experts.astype(I32),
+            top * scale)
 
 
 def plan_rows(pairs: int, held: int, tile: int) -> int:
@@ -182,9 +208,12 @@ def _ffn_fwd_loop(x, wg, wu, wd, *tables, n_tiles, tile):
         with jax.named_scope("moe/route/gather"):
             h = x[idx]
         with jax.named_scope("moe/experts"):
-            a = (jax.nn.silu(_dot(h, wg[e], ((1,), (0,))))
-                 * _dot(h, wu[e], ((1,), (0,)))).astype(x.dtype)
-            y = _dot(a, wd[e], ((1,), (0,))) * w[:, None]
+            if wg is None:
+                a = jnp.square(jnp.maximum(_dot(h, wu[e], ((1,), (0,))), 0.0))
+            else:
+                a = (jax.nn.silu(_dot(h, wg[e], ((1,), (0,))))
+                     * _dot(h, wu[e], ((1,), (0,))))
+            y = _dot(a.astype(x.dtype), wd[e], ((1,), (0,))) * w[:, None]
         with jax.named_scope("moe/route/add_back"):
             return adds.add(out, idx, y, n_real)
 
@@ -198,7 +227,7 @@ def grouped_ffn(x, wg, wu, wd, row_token, row_w, tile_expert, tile_start,
                 tile_real, n_tiles, tile):
     """sum over the rows r of a routing of row_w[r] * FFN_e(x[row_token[r]])
     added at row_token[r] -> float32 [T, K]. x [T, K]; wg, wu [G, K, N];
-    wd [G, N, K]; the lists and tables are `dispatch_plan`'s: tile i
+    wd [G, N, K] (`wg` None: the ungated relu^2 expert); the lists and tables are `dispatch_plan`'s: tile i
     computes `tile` entries from tile_start[i] with expert tile_expert[i];
     its first tile_real[i] rows are real (their tokens are distinct), the
     rest computed as token 0 with weight 0 and never added back. Tiles
@@ -221,6 +250,8 @@ def _grouped_ffn_bwd(tile, res, dout):
     with jax.named_scope("moe/cast"):
         dout = dout.astype(F32)
 
+    gated = wg is not None
+
     def body(i, carry):
         dx, dwg, dwu, dwd, drow = carry
         idx, w, e, n_real, real = _tile(i, tile, *tables)
@@ -228,20 +259,28 @@ def _grouped_ffn_bwd(tile, res, dout):
             h = x[idx]
             dy_rows = dout[idx]
         with jax.named_scope("moe/experts"):
-            g = _dot(h, wg[e], ((1,), (0,)))
-            u = _dot(h, wu[e], ((1,), (0,)))
-            sg = jax.nn.sigmoid(g)
-            act = g * sg
-            a = (act * u).astype(x.dtype)
+            if gated:
+                g = _dot(h, wg[e], ((1,), (0,)))
+                u = _dot(h, wu[e], ((1,), (0,)))
+                sg = jax.nn.sigmoid(g)
+                act = g * sg
+                a = (act * u).astype(x.dtype)
+            else:
+                act = jnp.maximum(_dot(h, wu[e], ((1,), (0,))), 0.0)
+                a = jnp.square(act).astype(x.dtype)
             # the gate weight's gradient needs the unweighted output
             drow_i = jnp.sum(dy_rows * _dot(a, wd[e], ((1,), (0,))), -1)
             dy = (dy_rows * w[:, None]).astype(x.dtype)
             da = _dot(dy, wd[e], ((1,), (1,)))
-            du = (da * act).astype(x.dtype)
-            dg = (da * u * sg * (1.0 + g * (1.0 - sg))).astype(x.dtype)
-            dh = (_dot(dg, wg[e], ((1,), (1,)))
-                  + _dot(du, wu[e], ((1,), (1,))))
-            dwg = dwg.at[e].add(_dot(h, dg, ((0,), (0,))))
+            if gated:
+                du = (da * act).astype(x.dtype)
+                dg = (da * u * sg * (1.0 + g * (1.0 - sg))).astype(x.dtype)
+                dh = (_dot(dg, wg[e], ((1,), (1,)))
+                      + _dot(du, wu[e], ((1,), (1,))))
+                dwg = dwg.at[e].add(_dot(h, dg, ((0,), (0,))))
+            else:
+                du = (da * 2.0 * act).astype(x.dtype)
+                dh = _dot(du, wu[e], ((1,), (1,)))
             dwu = dwu.at[e].add(_dot(h, du, ((0,), (0,))))
             dwd = dwd.at[e].add(_dot(a, dy, ((0,), (0,))))
         with jax.named_scope("moe/route/add_back"):
@@ -255,13 +294,14 @@ def _grouped_ffn_bwd(tile, res, dout):
     # the loop stands under a leaf, so that its `while` and the copies of
     # its carry are the add-back's, whose accumulator they move
     with jax.named_scope("moe/route/add_back"):
-        init = (adds.zeros(x.shape), jnp.zeros(wg.shape, F32),
+        init = (adds.zeros(x.shape),
+                jnp.zeros(wg.shape, F32) if gated else None,
                 jnp.zeros(wu.shape, F32), jnp.zeros(wd.shape, F32),
                 jnp.zeros(row_w.shape, F32))
         dx, dwg, dwu, dwd, drow = jax.lax.fori_loop(0, n_tiles, body, init)
         dx = adds.whole(dx)
     with jax.named_scope("moe/cast"):
-        return (dx.astype(x.dtype), dwg.astype(wg.dtype),
+        return (dx.astype(x.dtype), dwg.astype(wg.dtype) if gated else None,
                 dwu.astype(wu.dtype), dwd.astype(wd.dtype), None,
                 drow.astype(row_w.dtype), None, None, None, None)
 
@@ -270,10 +310,12 @@ grouped_ffn.defvjp(_grouped_ffn_fwd, _grouped_ffn_bwd)
 
 
 def dropless_moe(h, wr, wg, wu, wd, *, top_k, held, tile_rows,
-                 renormalise=True, balance_coef=0.0):
+                 renormalise=True, balance_coef=0.0, score="softmax",
+                 bias=None, scale=1.0):
     """The layer on arrays: h [T, K] -> (y [T, K] in h's type, the
     load-balancing term, stats float32 [3], the picked experts int32
-    [T, top_k]).
+    [T, top_k]). `wg` None: ungated experts; `score`, `bias`, `scale`:
+    `route_topk`'s.
 
     stats = (pairs routed to held experts, rows the grouped product
     computed, the held experts' largest load). The
@@ -283,7 +325,8 @@ def dropless_moe(h, wr, wg, wu, wd, *, top_k, held, tile_rows,
     n_experts = wr.shape[-1]
     with jax.named_scope("moe/route/router"):
         p, experts, gates = route_topk(
-            _dot(h, wr, ((1,), (0,))), top_k, renormalise)
+            _dot(h, wr, ((1,), (0,))), top_k, renormalise, score, bias,
+            scale)
         picked = _count(experts, n_experts).astype(F32)
         balance = balance_coef * n_experts * jnp.sum(
             jax.lax.stop_gradient(picked / h.shape[0]) * jnp.mean(p, 0))
@@ -300,17 +343,23 @@ def dropless_moe(h, wr, wg, wu, wd, *, top_k, held, tile_rows,
 
 
 class DroplessMoE(nn.Layer):
-    """Top-k dropless mixture of SiLU-gated experts (module docstring).
+    """Top-k dropless mixture of experts, SiLU-gated or ungated relu^2,
+    routed by softmax or by sigmoid scores (module docstring).
 
     Args:
       d_model, d_expert: token width and each expert's inner width.
       num_experts, top_k: the router's width and picks per token.
       held_experts: (lo, hi), the contiguous experts this layer holds and
         computes; None holds all. The router is always whole.
-      renormalise: divide the picked probabilities by their sum.
+      renormalise: divide the picked scores by their sum.
       balance_coef: weight of the load-balancing term (0: none).
       tile_rows: rows of one step of the grouped product; each held
         expert's rows are padded to a multiple of it.
+      gated: True, three weights an expert (`gate_proj`, `up_proj`,
+        `down_proj`); False, two (no `gate_proj`), relu^2 between them.
+      score: "softmax" or "sigmoid"; with "sigmoid" the layer holds the
+        buffer `score_bias` [num_experts] (zeros; selection only) and
+        multiplies the gates by `gate_scale`.
 
     forward(x [..., d_model]) -> (y, balance term, stats [3], picks int32
     [tokens, top_k]); `stats` is `dropless_moe`'s.
@@ -318,7 +367,8 @@ class DroplessMoE(nn.Layer):
 
     def __init__(self, d_model, d_expert, num_experts, top_k,
                  held_experts=None, renormalise=True, balance_coef=0.0,
-                 tile_rows=512):
+                 tile_rows=512, gated=True, score="softmax",
+                 gate_scale=1.0):
         super().__init__()
         lo, hi = held_experts or (0, num_experts)
         if not 0 <= lo < hi <= num_experts:
@@ -327,22 +377,36 @@ class DroplessMoE(nn.Layer):
         self.held_experts = (int(lo), int(hi))
         self.top_k, self.tile_rows = int(top_k), int(tile_rows)
         self.renormalise, self.balance_coef = renormalise, balance_coef
+        self.score, self.gate_scale = score, float(gate_scale)
         held = hi - lo
         self.router = self.create_parameter([d_model, num_experts])
-        self.gate_proj = self.create_parameter([held, d_model, d_expert])
+        if score == "sigmoid":
+            from .....framework.tensor import Tensor
+
+            self.register_buffer("score_bias", Tensor._wrap(
+                jnp.zeros((num_experts,), F32)))
+        elif score != "softmax":
+            raise ValueError(f"DroplessMoE: unknown score {score!r}")
+        self.gate_proj = (self.create_parameter([held, d_model, d_expert])
+                          if gated else None)
         self.up_proj = self.create_parameter([held, d_model, d_expert])
         self.down_proj = self.create_parameter([held, d_expert, d_model])
 
     def forward(self, x):
         shape = x.shape
 
-        def run(h, wr, wg, wu, wd):
+        gated, biased = self.gate_proj is not None, self.score == "sigmoid"
+
+        def run(h, wr, *rest):
+            wg, wu, wd = rest[:3] if gated else (None,) + rest[:2]
             y, balance, stats, picks = dropless_moe(
                 h.reshape(-1, shape[-1]), wr, wg, wu, wd, top_k=self.top_k,
                 held=self.held_experts, tile_rows=self.tile_rows,
                 renormalise=self.renormalise,
-                balance_coef=self.balance_coef)
+                balance_coef=self.balance_coef, score=self.score,
+                bias=rest[-1] if biased else None, scale=self.gate_scale)
             return y.reshape(shape), balance, stats, picks
 
-        return nary(run, [x, self.router, self.gate_proj, self.up_proj,
-                          self.down_proj], "dropless_moe")
+        ins = [x, self.router, self.gate_proj, self.up_proj, self.down_proj,
+               self.score_bias if biased else None]
+        return nary(run, [t for t in ins if t is not None], "dropless_moe")

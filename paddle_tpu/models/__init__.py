@@ -41,3 +41,8 @@ from .mellum2 import (  # noqa: F401
     Mellum2Model,
     Mellum2ForCausalLM,
 )
+from .nemotron_h import (  # noqa: F401
+    NemotronHConfig,
+    NemotronHModel,
+    NemotronHForCausalLM,
+)

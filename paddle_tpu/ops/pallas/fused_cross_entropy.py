@@ -204,10 +204,19 @@ def _bwd_call(h, w, lbl_b, lse_b, g_b, dw_acc, bn, bv, interpret):
     spec_h = pl.BlockSpec((1, bn, hidden), lambda i, j: (_Z, i, _Z))
     spec_w = pl.BlockSpec((1, bv, hidden), lambda i, j: (_Z, j, _Z))
     spec_r = pl.BlockSpec((1, bn, _LANES), lambda i, j: (_Z, i, _Z))
+    # rows so wide that even the narrowest vocabulary tile's blocks pass
+    # the scoped default (hidden 2688: 16.3 MiB of a v5e's 16) ask for
+    # what they hold and their score tiles' room; any other call asks for
+    # nothing and compiles as before
+    need = _bwd_vmem_bytes(bn, bv, hidden, h.dtype.itemsize)
+    params = ({} if need <= _VMEM_BUDGET else {
+        "compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=need + 8 * 2 ** 20)})
     dh, dw = routing.pallas_call(
         functools.partial(_bwd_kernel, block_v=bv),
         name="fused_ce_bwd",
         grid=(n // bn, vocab // bv),
+        **params,
         in_specs=[spec_h, spec_w, spec_r, spec_r, spec_r, spec_w],
         out_specs=[spec_h, spec_w],
         out_shape=[

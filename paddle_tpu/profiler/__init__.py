@@ -208,6 +208,12 @@ DEVICE_SCOPES = (
     "sparse_attention",         # attention over the selection
     "window_attention",         # a sliding layer's attention
     "full_attention",           # a full layer's attention
+    "ssm/project",              # a Mamba layer's input norm and W_in
+    "ssm/conv",                 # the depthwise causal convolution and silu
+    "ssm/scan",                 # softplus, the running sums, the chunked
+                                # scan's two kernels and D x
+    "ssm/gate_norm",            # y * silu(z) and the grouped RMSNorm
+    "ssm/out",                  # W_out and the residual
     "moe/norm",                 # the post-attention norm
     "moe/route",                # bare: what XLA adds between the leaves
     "moe/route/router",         # router product, softmax, top-k, balance
@@ -216,6 +222,7 @@ DEVICE_SCOPES = (
     "moe/route/add_back",       # the accumulator, a tile's add-back, the
                                 # tile loops' own carries
     "moe/experts",              # the grouped products
+    "moe/shared",               # the shared expert's two products
     "moe/cast",                 # float32 staging round the tile loops: the
                                 # cotangent cast up, outputs and gradients
                                 # cast back to the parameters' type
