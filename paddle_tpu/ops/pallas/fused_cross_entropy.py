@@ -25,8 +25,16 @@ the head matmul through **vocab tiles** instead:
 Two paths, one contract (the `paged_attention.py` routing pattern):
 
 * **Pallas kernel** — TPU (or `interpret=True` for hermetic CPU parity).
-  Requires vocab % 128 == 0 (the bench vocab 50304 = 393 * 128).
-* **XLA path** (`impl="xla"`) — CPU / odd vocabs: a
+  Any vocabulary: the grid has `cdiv(vocab, bv)` vocab tiles, and a last
+  tile that runs past the head (a vocabulary-parallel slice of 18,992 =
+  148 * 128 + 48 rows) is masked INSIDE the kernels: its columns beyond
+  `vocab` are -inf before the running max, the sum and the picked logit,
+  so p = 0 and d_logits = 0 there, and W's rows beyond `vocab` (whatever
+  an out-of-range block holds) are read as 0. The head stays
+  [vocab, hidden] and so does dW: the boundary block's rows beyond
+  `vocab` are never written. A vocabulary that is whole tiles (the bench
+  vocab 50304 = 393 * 128) traces none of this.
+* **XLA path** (`impl="xla"`) — CPU / unsupported dtypes: a
   `lax.scan` over the same vocab tiles in the same order with the same
   fp32 accumulation, so kernel-vs-fallback parity is tight; handles
   arbitrary vocab sizes by padding the last tile (padded columns are
@@ -54,10 +62,9 @@ __all__ = ["fused_cross_entropy", "sharded_fused_cross_entropy",
 
 
 def supports(vocab, hidden, dtype) -> bool:
-    """Whether the Pallas kernel can take this head (else XLA tiles)."""
-    if dtype not in (jnp.float32, jnp.bfloat16, jnp.float16):
-        return False
-    return vocab % _LANES == 0
+    """Whether the Pallas kernel can take this head (else XLA tiles): any
+    vocabulary, a last partial tile is masked inside the kernel."""
+    return dtype in (jnp.float32, jnp.bfloat16, jnp.float16)
 
 
 # what the backward may keep in VMEM beside its [bn, bv] score tiles: a
@@ -74,16 +81,18 @@ def _bwd_vmem_bytes(bn, bv, hidden, itemsize):
 
 
 def _pick_block_v(vocab, hidden=None, itemsize=2, bn=256):
-    """The widest vocab tile that divides `vocab` and, when the caller
-    says how wide the rows are, whose backward fits VMEM: at hidden 2304
-    a 512-row tile's two fp32 accumulator blocks alone are 19 MiB. The
-    narrowest is returned when none fits."""
-    fits = [bv for bv in (512, 256, _LANES) if vocab % bv == 0]
+    """The widest vocab tile that divides `vocab` rounded up to whole
+    lanes (18,992 -> 19,072 = 149 * 128: only the last tile is partial)
+    and, when the caller says how wide the rows are, whose backward fits
+    VMEM: at hidden 2304 a 512-row tile's two fp32 accumulator blocks
+    alone are 19 MiB. The narrowest is returned when none fits."""
+    fits = [bv for bv in (512, 256, _LANES)
+            if pl.cdiv(vocab, _LANES) * _LANES % bv == 0]
     for bv in fits:
         if hidden is None or _bwd_vmem_bytes(bn, bv, hidden,
                                              itemsize) <= _VMEM_BUDGET:
             return bv
-    return fits[-1] if fits else None
+    return fits[-1]
 
 
 def _pick_block_n(n):
@@ -98,7 +107,7 @@ def _pick_block_n(n):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(h_ref, w_ref, lbl_ref, loss_ref, lse_ref, m_ref, l_ref,
-                pk_ref, *, block_v, ignore_index):
+                pk_ref, *, block_v, ignore_index, vocab):
     vi = pl.program_id(1)
     nv = pl.num_programs(1)
 
@@ -114,6 +123,10 @@ def _fwd_kernel(h_ref, w_ref, lbl_ref, loss_ref, lse_ref, m_ref, l_ref,
     lbl = lbl_ref[0][:, :1]                              # [bn, 1] int32
     col = vi * block_v + jax.lax.broadcasted_iota(
         jnp.int32, logits.shape, 1)
+    if vocab % block_v:                 # a test on a shape, at trace time
+        # columns of the last tile beyond the head: whatever the
+        # out-of-range rows of the W block held, they weigh nothing
+        logits = jnp.where(col < vocab, logits, -jnp.inf)
     m_prev = m_ref[...]                                  # [bn, LANES]
     l_prev = l_ref[...]
     m_cur = jnp.max(logits, axis=1, keepdims=True)
@@ -135,17 +148,19 @@ def _fwd_kernel(h_ref, w_ref, lbl_ref, loss_ref, lse_ref, m_ref, l_ref,
         lse_ref[0] = lse
 
 
-def _fwd_pallas(h, w, lbl_b, bn, bv, ignore_index, interpret):
+def _fwd_pallas(h, w, lbl_b, bn, bv, ignore_index, interpret, vocab=None):
+    """`vocab`: the head's rows where `w`'s storage holds more (a test
+    that plants rows beyond them); the grid covers `vocab` alone."""
     n, hidden = h.shape
-    vocab = w.shape[0]
+    vocab = w.shape[0] if vocab is None else vocab
     spec_h = pl.BlockSpec((1, bn, hidden), lambda i, j: (_Z, i, _Z))
     spec_w = pl.BlockSpec((1, bv, hidden), lambda i, j: (_Z, j, _Z))
     spec_r = pl.BlockSpec((1, bn, _LANES), lambda i, j: (_Z, i, _Z))
     loss, lse = routing.pallas_call(
         functools.partial(_fwd_kernel, block_v=bv,
-                          ignore_index=ignore_index),
+                          ignore_index=ignore_index, vocab=vocab),
         name="fused_ce_fwd",
-        grid=(n // bn, vocab // bv),
+        grid=(n // bn, pl.cdiv(vocab, bv)),
         in_specs=[spec_h, spec_w, spec_r],
         out_specs=[spec_r, spec_r],
         out_shape=[
@@ -168,7 +183,7 @@ def _fwd_pallas(h, w, lbl_b, bn, bv, ignore_index, interpret):
 # ---------------------------------------------------------------------------
 
 def _bwd_kernel(h_ref, w_ref, lbl_ref, lse_ref, g_ref, dwi_ref,
-                dh_ref, dw_ref, dh_acc, *, block_v):
+                dh_ref, dw_ref, dh_acc, *, block_v, vocab):
     vi = pl.program_id(1)
     nv = pl.num_programs(1)
 
@@ -181,6 +196,14 @@ def _bwd_kernel(h_ref, w_ref, lbl_ref, lse_ref, g_ref, dwi_ref,
 
     h = h_ref[0]                                         # [bn, H]
     w = w_ref[0]                                         # [bv, H]
+    if vocab % block_v:
+        # rows of the last tile beyond the head: d is 0 in their columns,
+        # and 0 times whatever an out-of-range block holds may be NaN.
+        # Compared on every tile: under a `lax.cond` that spares the 148
+        # whole tiles the pair took 108.8 ms for 103.0 at keye's head
+        row = vi * block_v + jax.lax.broadcasted_iota(
+            jnp.int32, (block_v, 1), 0)
+        w = jnp.where(row < vocab, w, jnp.zeros_like(w))
     lse = lse_ref[0][:, :1]                              # [bn, 1]
     g = g_ref[0][:, :1]                                  # [bn, 1] fp32
     lbl = lbl_ref[0][:, :1]
@@ -188,6 +211,8 @@ def _bwd_kernel(h_ref, w_ref, lbl_ref, lse_ref, g_ref, dwi_ref,
     p = jnp.exp(logits - lse)
     col = vi * block_v + jax.lax.broadcasted_iota(
         jnp.int32, logits.shape, 1)
+    if vocab % block_v:
+        p = jnp.where(col < vocab, p, 0.0)     # as exp(-inf): d = 0 there
     d = (p - jnp.where(col == lbl, 1.0, 0.0)) * g        # [bn, bv] fp32
     dlow = d.astype(h.dtype)       # grads ride the MXU in the op dtype
     dh_acc[...] += _dot(dlow, w, ((1,), (0,)))           # [bn, H]
@@ -198,9 +223,11 @@ def _bwd_kernel(h_ref, w_ref, lbl_ref, lse_ref, g_ref, dwi_ref,
         dh_ref[0] = dh_acc[...].astype(dh_ref.dtype)
 
 
-def _bwd_call(h, w, lbl_b, lse_b, g_b, dw_acc, bn, bv, interpret):
+def _bwd_call(h, w, lbl_b, lse_b, g_b, dw_acc, bn, bv, interpret,
+              vocab=None):
+    """`dw_acc` has `w`'s rows; `vocab` as `_fwd_pallas`'s."""
     n, hidden = h.shape
-    vocab = w.shape[0]
+    vocab = w.shape[0] if vocab is None else vocab
     spec_h = pl.BlockSpec((1, bn, hidden), lambda i, j: (_Z, i, _Z))
     spec_w = pl.BlockSpec((1, bv, hidden), lambda i, j: (_Z, j, _Z))
     spec_r = pl.BlockSpec((1, bn, _LANES), lambda i, j: (_Z, i, _Z))
@@ -213,15 +240,15 @@ def _bwd_call(h, w, lbl_b, lse_b, g_b, dw_acc, bn, bv, interpret):
         "compiler_params": pltpu.CompilerParams(
             vmem_limit_bytes=need + 8 * 2 ** 20)})
     dh, dw = routing.pallas_call(
-        functools.partial(_bwd_kernel, block_v=bv),
+        functools.partial(_bwd_kernel, block_v=bv, vocab=vocab),
         name="fused_ce_bwd",
-        grid=(n // bn, vocab // bv),
+        grid=(n // bn, pl.cdiv(vocab, bv)),
         **params,
         in_specs=[spec_h, spec_w, spec_r, spec_r, spec_r, spec_w],
         out_specs=[spec_h, spec_w],
         out_shape=[
             jax.ShapeDtypeStruct((1, n, hidden), h.dtype),
-            jax.ShapeDtypeStruct((1, vocab, hidden), jnp.float32),
+            jax.ShapeDtypeStruct((1,) + dw_acc.shape, jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bn, hidden), jnp.float32)],
         # dW accumulator aliases its input (position 5 -> output 1)
@@ -234,17 +261,20 @@ def _bwd_call(h, w, lbl_b, lse_b, g_b, dw_acc, bn, bv, interpret):
 _alias_checked: set = set()
 
 
-def _alias_selfcheck(dtype, hidden, bn, bv):
+def _alias_selfcheck(dtype, hidden, bn, bv, last=0):
     """One-time (per config, per process) on-device check of the fused
     dW aliased-accumulator backward against the hazard-free per-token-
-    tile path (the flash_attention.py guard applied to the CE kernel)."""
+    tile path (the flash_attention.py guard applied to the CE kernel).
+    `last`: the rows of a partial last vocab tile (0: whole tiles); the
+    check's head ends in a tile of as many, so its boundary block is the
+    caller's."""
     from ...utils import flags as _flags
 
-    key = (str(dtype), hidden, bn, bv)
+    key = (str(dtype), hidden, bn, bv, last)
     if key in _alias_checked or not _flags.get_flag(
             "FLAGS_pallas_alias_selfcheck"):
         return
-    n, vocab = 2 * bn, bv * _REVISIT_MIN
+    n, vocab = 2 * bn, bv * (_REVISIT_MIN - 1) + (last or bv)
 
     def _run():
         rng = np.random.default_rng(0)
@@ -284,26 +314,28 @@ def _alias_selfcheck(dtype, hidden, bn, bv):
     _alias_checked.add(key)   # only memoize a PASSING check
 
 
-def _bwd_pallas(h, w, lbl_b, lse_b, g_b, bn, bv, interpret):
+def _bwd_pallas(h, w, lbl_b, lse_b, g_b, bn, bv, interpret, vocab=None):
     n = h.shape[0]
-    vocab, hidden = w.shape
-    dw_acc = jnp.zeros((vocab, hidden), jnp.float32)
+    hidden = w.shape[1]
+    dw_acc = jnp.zeros(w.shape, jnp.float32)
+    vocab = w.shape[0] if vocab is None else vocab
     nt = n // bn
     # the aliased dW blocks are revisited once per token tile, a full
     # vocab sweep apart; below _REVISIT_MIN (or in interpret mode, which
     # replays revisited aliased blocks from the original input) fall
     # back to one hazard-free call per token tile
-    if not interpret and (nt == 1 or vocab // bv >= _REVISIT_MIN):
+    if not interpret and (nt == 1 or pl.cdiv(vocab, bv) >= _REVISIT_MIN):
         if nt > 1:
-            _alias_selfcheck(h.dtype, hidden, bn, bv)
+            _alias_selfcheck(h.dtype, hidden, bn, bv, vocab % bv)
         return _bwd_call(h, w, lbl_b, lse_b, g_b, dw_acc, bn, bv,
-                         interpret)
+                         interpret, vocab)
     dh_rows = []
     for ti in range(nt):
         sl = functools.partial(jax.lax.dynamic_slice_in_dim,
                                start_index=ti * bn, slice_size=bn, axis=0)
         dh_row, dw_acc = _bwd_call(sl(h), w, sl(lbl_b), sl(lse_b),
-                                   sl(g_b), dw_acc, bn, bv, interpret)
+                                   sl(g_b), dw_acc, bn, bv, interpret,
+                                   vocab)
         dh_rows.append(dh_row)
     return jnp.concatenate(dh_rows, axis=0), dw_acc
 

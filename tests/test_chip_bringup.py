@@ -59,11 +59,13 @@ def test_build_mesh_raises_rather_than_borrowing_devices():
 
 def test_unsupported_kernel_geometry_is_visible():
     rng = np.random.default_rng(0)
-    h = jnp.asarray(rng.standard_normal((8, 16)), jnp.float32)
-    w = jnp.asarray(rng.standard_normal((100, 16)), jnp.float32)  # 100 % 128
+    # float64 operands: the fused-CE kernels take 32-bit floats and
+    # narrower (a vocabulary of 100 = no whole tile they take since PR 40)
+    h = jnp.asarray(rng.standard_normal((8, 16)), jnp.float64)
+    w = jnp.asarray(rng.standard_normal((100, 16)), jnp.float64)
     lbl = jnp.asarray(rng.integers(0, 100, (8,)), jnp.int32)
     key = ("fused_cross_entropy",
-           ("vocab=100", "hidden=16", "float32"))
+           ("vocab=100", "hidden=16", "float64"))
     n0 = routing.xla_fallbacks[key]
     # on the CPU with no interpret request the XLA path IS the path: quiet
     want = fce.fused_cross_entropy(h, w, lbl)

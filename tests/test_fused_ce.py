@@ -152,6 +152,82 @@ def test_vocab_tiled_kernel_parity(n, vocab, ii):
         assert float(jnp.max(jnp.abs(a - c))) < 2e-4
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("n,vocab,block_v", [
+    (64, 200, None),       # one 256-column tile, 56 of them beyond the head
+    (100, 53, None),       # a head narrower than a tile; padded token rows
+    (96, 432, 128),        # 3 x 128 + 48: keye's last tile (18,992 % 128)
+    (96, 432, None),       # the same head as one 512-column tile
+])
+def test_a_last_partial_vocab_tile_is_masked_inside_the_kernel(n, vocab,
+                                                               block_v,
+                                                               dtype):
+    """A vocabulary that is not whole tiles (a vocabulary-parallel slice
+    of 18,992 rows): interpret kernel vs XLA tiles vs dense, loss, dh and
+    dW each; labels inside the partial tile, the label `vocab - 1`,
+    `ignore_index` rows. Then the same through the internal calls with W
+    (and the dW accumulator) stored PADDED to whole tiles and NaN planted
+    in the rows beyond `vocab`: a boundary block's out-of-range rows may
+    hold anything, and none of it may reach loss, lse, dh or dW's rows."""
+    dtype = jnp.dtype(dtype)
+    tol = 2e-4 if dtype == jnp.float32 else 2e-2   # of the largest entry
+    rng = np.random.default_rng(vocab)
+    hidden = 32
+    h = jnp.asarray(rng.standard_normal((n, hidden)), dtype)
+    w = jnp.asarray(rng.standard_normal((vocab, hidden)) * 0.1, dtype)
+    lbl = rng.integers(0, vocab, (n,))
+    lbl[::5] = -100
+    lbl[1], lbl[2], lbl[3] = vocab - 1, vocab - 2, vocab - vocab % 128
+    lbl = jnp.asarray(lbl, jnp.int32)
+    seed = jnp.asarray(rng.standard_normal((n,)), jnp.float32)
+
+    def through(loss_fn):
+        def total(h, w):
+            losses = loss_fn(h, w)
+            return jnp.sum(losses * seed), losses
+        (_, losses), (dh, dw) = jax.value_and_grad(
+            total, (0, 1), has_aux=True)(h, w)
+        return losses, dh.astype(jnp.float32), dw.astype(jnp.float32)
+
+    kern = through(lambda h, w: fce.fused_cross_entropy(
+        h, w, lbl, block_v=block_v, interpret=True))
+    xla = through(lambda h, w: fce.fused_cross_entropy(
+        h, w, lbl, block_v=block_v, use_kernel=False))
+    dense = through(lambda h, w: _dense_ref(h, w, lbl, -100))
+    assert kern[2].shape == (vocab, hidden)
+    for got, a, b in zip(kern, xla, dense):
+        assert not bool(jnp.isnan(got).any())
+        for want in (a, b):
+            assert float(jnp.max(jnp.abs(got - want))) \
+                < tol * max(1.0, float(jnp.max(jnp.abs(want))))
+
+    # the internal calls on storage padded to whole tiles, NaN beyond
+    bn = fce._pick_block_n(n)
+    bv = block_v or fce._pick_block_v(vocab, hidden, dtype.itemsize, bn)
+    pad_n, pad_v = (-n) % bn, (-vocab) % bv
+    assert pad_v                     # every case has a partial last tile
+    hp = fce._pad_rows(h, pad_n, 0)
+    lblp = fce._lane_bcast(fce._pad_rows(lbl, pad_n, -100), jnp.int32)
+    w_nan = fce._pad_rows(w, pad_v, jnp.nan)
+    assert bool(jnp.isnan(w_nan[vocab:]).all())
+    losses, lse = fce._fwd_pallas(hp, w_nan, lblp, bn, bv, -100, True,
+                                  vocab=vocab)
+    np.testing.assert_array_equal(np.asarray(losses[:n]),
+                                  np.asarray(kern[0]))
+    assert not bool(jnp.isnan(lse).any())
+    g = jnp.where(lbl != -100, seed, 0.0)
+    dh, dw = fce._bwd_pallas(
+        hp, w_nan, lblp, fce._lane_bcast(lse, jnp.float32),
+        fce._lane_bcast(fce._pad_rows(g, pad_n, 0), jnp.float32), bn, bv,
+        True, vocab=vocab)
+    assert dw.shape == w_nan.shape   # the accumulator has the storage's rows
+    np.testing.assert_array_equal(
+        np.asarray(dh[:n].astype(jnp.float32)), np.asarray(kern[1]))
+    np.testing.assert_array_equal(
+        np.asarray(dw[:vocab].astype(dtype).astype(jnp.float32)),
+        np.asarray(kern[2]))
+
+
 def test_vocab_tiled_ignored_rows_zero_grads():
     """An all-ignored batch must yield exactly zero dh/dw (the masked
     cotangent can't leak the recomputed softmax term)."""
@@ -179,7 +255,8 @@ def test_vocab_tiled_bf16():
 def test_supports_gate():
     assert fce.supports(50304, 2048, jnp.bfloat16)   # the bench vocab
     assert fce.supports(384, 32, jnp.float32)
-    assert not fce.supports(53, 32, jnp.float32)     # vocab % 128 != 0
+    assert fce.supports(53, 32, jnp.float32)    # a partial tile is masked
+    assert fce.supports(18992, 2048, jnp.bfloat16)   # keye's sliced head
     assert not fce.supports(256, 32, jnp.int32)
 
 
@@ -189,14 +266,19 @@ def test_supports_gate():
     (24576, 1024, 2, 512),      # narrow rows: the widest tile fits
     (24576, 2304, 2, 128),      # 512 rows: 25.5 MiB of a v5e's 16 (PR 35)
     (24576, 2304, 4, 128),      # nothing fits: the narrowest
-    (1000, 64, 4, None),
+    (16384, 2688, 2, 128),      # nothing fits: the narrowest (PR 39)
+    (18992, 2048, 2, 128),      # keye's slice: 19,072 = 149 x 128
+    (1000, 64, 4, 512),         # 1,024 columns in two tiles, 24 beyond
+    (53, 32, 4, 128),
 ])
 def test_the_vocab_tile_fits_the_backwards_vmem(vocab, hidden, itemsize,
                                                 want):
+    """The tile divides the vocabulary ROUNDED UP to whole lanes: a head
+    of whole lanes keeps the tile it had."""
     assert fce._pick_block_v(vocab, hidden, itemsize) == want
-    if want is not None:        # a caller that gives no width: as before
-        assert fce._pick_block_v(vocab) == max(
-            bv for bv in (512, 256, 128) if vocab % bv == 0)
+    whole = -(-vocab // 128) * 128
+    assert fce._pick_block_v(vocab) == max(      # no width given: as before
+        bv for bv in (512, 256, 128) if whole % bv == 0)
 
 
 def test_cross_entropy_soft_label_ignore_index_raises():

@@ -552,8 +552,8 @@ def v5e_chip():
 
 @pytest.mark.parametrize("kernel", ["attn_probs", "splash_selection",
                                     "indexer_scores", "splash_window",
-                                    "fused_ce_2304", "moe_rows_2048",
-                                    "moe_rows_2304"])
+                                    "fused_ce_2304", "fused_ce_18992",
+                                    "moe_rows_2048", "moe_rows_2304"])
 def test_the_new_kernels_compile_for_a_v5e_at_published_widths(v5e_chip,
                                                                kernel):
     """`splash_window` and `fused_ce_2304` are the window + mixture
@@ -561,6 +561,10 @@ def test_the_new_kernels_compile_for_a_v5e_at_published_widths(v5e_chip,
     describes the chip): the banded splash kernels at its geometry, and
     fused CE at hidden 2304 over a 24,576-row head, where a 512-row
     vocabulary tile's backward asked for 25.5 MiB of a v5e's 16.
+    `fused_ce_18992`: this block's own head, 151,936 / 8 rows = 148 x 128
+    + 48: value and both gradients through the kernels, the last
+    vocabulary tile a boundary block on the unpadded [18992, 2048] head
+    and float32 accumulator, and no loop of the XLA tiles left.
     `moe_rows_*`: one dropless layer's forward and backward tile loops at
     both blocks' widths, 32,768 tokens in 512-row tiles: `moe_add_rows`
     asks its two float32 tiles and 2 MiB of VMEM (10.0 and 11.0 MiB)."""
@@ -600,11 +604,15 @@ def test_the_new_kernels_compile_for_a_v5e_at_published_widths(v5e_chip,
                       spec((4, 8192, 4, 128), bf16),
                       spec((4, 8192, 4, 128), bf16)), {"splash_fwd",
                                                        "splash_bwd"}
-    elif kernel == "fused_ce_2304":
+    elif kernel.startswith("fused_ce"):
+        vocab, hidden = {"fused_ce_2304": (24576, 2304),
+                         "fused_ce_18992": (18992, 2048)}[kernel]
+
         def fn(h, w, labels):
-            return jax.grad(lambda h, w: jnp.sum(fused_cross_entropy(
-                h, w, labels)), argnums=(0, 1))(h, w)
-        args, want = (spec((32768, 2304), bf16), spec((24576, 2304), bf16),
+            return jax.value_and_grad(lambda h, w: jnp.sum(
+                fused_cross_entropy(h, w, labels)), argnums=(0, 1))(h, w)
+        args, want = (spec((32768, hidden), bf16),
+                      spec((vocab, hidden), bf16),
                       spec((32768,), jnp.int32)), {"fused_ce_fwd",
                                                    "fused_ce_bwd"}
     elif kernel.startswith("moe_rows"):
@@ -645,9 +653,72 @@ def test_the_new_kernels_compile_for_a_v5e_at_published_widths(v5e_chip,
     assert set(routing.mosaic_kernels(text)) == want
     # no per-head score tensor outside the kernels
     assert "f32[512,16,8192]" not in text
+    if kernel == "fused_ce_18992":
+        assert routing.mosaic_kernels(text) == {"fused_ce_fwd": 1,
+                                                "fused_ce_bwd": 1}
+        assert " while(" not in text            # XLA's 149 tiles are gone
+        assert "f32[32768,2048]" not in text    # and their dx accumulator
+        assert not [k for k in routing.xla_fallbacks
+                    if k[0] == "fused_cross_entropy"
+                    and "vocab=18992" in k[1]]
+        # dW leaves as the head's rows, no padded copy of it or of W
+        assert "[19072,2048]" not in text
     if kernel.startswith("moe_rows"):
         # the forward loop's add-back and the backward's, at the VMEM
         # asked, in XLA's scatter-add's place
         assert routing.mosaic_kernels(text) == {"moe_add_rows": 2}
         assert str(vmem) in text
         assert "moe/route/scatter-add" not in text
+
+
+@pytest.mark.parametrize("rows,hidden,vocab,eqns", [
+    (8192, 2048, 50304, (41, 37)),      # the GPT cells' head
+    (32768, 2304, 24576, (41, 37)),     # mellum2's slice
+    (32768, 2688, 16384, (41, 37)),     # nemotron3's slice
+    (32768, 2048, 18992, (43, 45)),     # this block's: the masked tile
+])
+def test_a_head_of_whole_tiles_traces_the_kernels_without_the_mask(
+        rows, hidden, vocab, eqns):
+    """The partial last tile is a Python test on the head's shape: a
+    vocabulary of whole tiles traces the two kernel bodies PR 39 traced
+    (41 and 37 equations, no compare against the vocabulary), so keye's
+    mask cannot leak into the other cells' steps; keye's own bodies carry
+    it (a compare + select on the logits; backward that and W's rows)."""
+    from paddle_tpu.ops.pallas.fused_cross_entropy import fused_cross_entropy
+    from paddle_tpu.utils import flags
+
+    def fn(h, w, labels):
+        return jax.value_and_grad(lambda h, w: jnp.sum(fused_cross_entropy(
+            h, w, labels, use_kernel=True, interpret=False)), (0, 1))(h, w)
+
+    selfcheck = flags.get_flag("FLAGS_pallas_alias_selfcheck")
+    flags.set_flags({"FLAGS_pallas_alias_selfcheck": False})
+    try:
+        jaxpr = jax.make_jaxpr(fn)(
+            jax.ShapeDtypeStruct((rows, hidden), jnp.bfloat16),
+            jax.ShapeDtypeStruct((vocab, hidden), jnp.bfloat16),
+            jax.ShapeDtypeStruct((rows,), jnp.int32))
+    finally:
+        flags.set_flags({"FLAGS_pallas_alias_selfcheck": selfcheck})
+    bodies = {}
+
+    def walk(j):
+        for e in j.eqns:
+            if e.primitive.name == "pallas_call":
+                bodies[e.params["name"]] = e.params
+            for v in e.params.values():
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    walk(inner)
+
+    walk(jaxpr.jaxpr)
+    assert sorted(bodies) == ["fused_ce_bwd", "fused_ce_fwd"]
+    got = tuple(len(bodies[k]["jaxpr"].eqns)
+                for k in ("fused_ce_fwd", "fused_ce_bwd"))
+    assert got == eqns
+    masked = vocab % 128 != 0
+    for params in bodies.values():
+        assert params["grid_mapping"].grid[1] == -(-vocab // 128)
+        compares = [e for e in params["jaxpr"].eqns
+                    if e.primitive.name == "lt"]
+        assert bool(compares) == masked
