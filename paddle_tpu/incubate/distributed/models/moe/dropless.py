@@ -16,7 +16,10 @@ The layer is told the router's score and the experts' form, two of each:
   score "sigmoid": p = sigmoid(logits); E_t = the top k of p + `bias` (a
       buffer no gradient reaches; it moves the selection only);
       g = p[E_t] / (sum + 1e-20) when `renormalise`, times `scale`; the
-      balance term reads p / sum_e p
+      balance term reads p / sum_e p. With `n_group` > 1 the picks are
+      limited to groups: the experts lie in `n_group` contiguous groups, a
+      group's score is the sum of its two largest p + bias, and only the
+      `topk_group` best groups' experts can be picked (DeepSeek-V3's)
   gated (three weights):   FFN_e(h) = Wd_e (silu(Wg_e h) * Wu_e h)
   ungated (`wg` is None):  FFN_e(h) = Wd_e relu(Wu_e h)^2
 
@@ -90,10 +93,10 @@ I32 = jnp.int32
 
 
 def route_topk(logits, top_k, renormalise=True, score="softmax", bias=None,
-               scale=1.0):
+               scale=1.0, n_group=1, topk_group=1):
     """-> (p [T, E] float32 (what the balance term reads), experts [T, k]
-    int32, gates [T, k] float32). `score`, `bias`, `scale`: module
-    docstring."""
+    int32, gates [T, k] float32). `score`, `bias`, `scale`, `n_group`,
+    `topk_group`: module docstring."""
     if score == "softmax":
         p = jax.nn.softmax(logits.astype(F32), axis=-1)
         top, experts = jax.lax.top_k(p, top_k)     # ties: lower index
@@ -105,6 +108,14 @@ def route_topk(logits, top_k, renormalise=True, score="softmax", bias=None,
     s = jax.nn.sigmoid(logits.astype(F32))
     chosen = s if bias is None else s + jax.lax.stop_gradient(
         bias.astype(F32))
+    if n_group > 1:
+        by_group = chosen.reshape(chosen.shape[0], n_group, -1)
+        best = jax.lax.top_k(jnp.sum(jax.lax.top_k(by_group, 2)[0], -1),
+                             topk_group)[1]
+        kept = jnp.any(best[:, :, None] == jnp.arange(n_group, dtype=I32),
+                       axis=1)
+        chosen = jnp.where(kept[:, :, None], by_group,
+                           -jnp.inf).reshape(chosen.shape)
     experts = jax.lax.top_k(chosen, top_k)[1]      # ties: lower index
     top = jnp.take_along_axis(s, experts, axis=-1)
     if renormalise:
@@ -311,11 +322,11 @@ grouped_ffn.defvjp(_grouped_ffn_fwd, _grouped_ffn_bwd)
 
 def dropless_moe(h, wr, wg, wu, wd, *, top_k, held, tile_rows,
                  renormalise=True, balance_coef=0.0, score="softmax",
-                 bias=None, scale=1.0):
+                 bias=None, scale=1.0, n_group=1, topk_group=1):
     """The layer on arrays: h [T, K] -> (y [T, K] in h's type, the
     load-balancing term, stats float32 [3], the picked experts int32
-    [T, top_k]). `wg` None: ungated experts; `score`, `bias`, `scale`:
-    `route_topk`'s.
+    [T, top_k]). `wg` None: ungated experts; `score`, `bias`, `scale`,
+    `n_group`, `topk_group`: `route_topk`'s.
 
     stats = (pairs routed to held experts, rows the grouped product
     computed, the held experts' largest load). The
@@ -326,7 +337,7 @@ def dropless_moe(h, wr, wg, wu, wd, *, top_k, held, tile_rows,
     with jax.named_scope("moe/route/router"):
         p, experts, gates = route_topk(
             _dot(h, wr, ((1,), (0,))), top_k, renormalise, score, bias,
-            scale)
+            scale, n_group, topk_group)
         picked = _count(experts, n_experts).astype(F32)
         balance = balance_coef * n_experts * jnp.sum(
             jax.lax.stop_gradient(picked / h.shape[0]) * jnp.mean(p, 0))
@@ -360,6 +371,8 @@ class DroplessMoE(nn.Layer):
       score: "softmax" or "sigmoid"; with "sigmoid" the layer holds the
         buffer `score_bias` [num_experts] (zeros; selection only) and
         multiplies the gates by `gate_scale`.
+      n_group, topk_group: the group limit of the picks (sigmoid scores;
+        1, 1: none).
 
     forward(x [..., d_model]) -> (y, balance term, stats [3], picks int32
     [tokens, top_k]); `stats` is `dropless_moe`'s.
@@ -368,7 +381,7 @@ class DroplessMoE(nn.Layer):
     def __init__(self, d_model, d_expert, num_experts, top_k,
                  held_experts=None, renormalise=True, balance_coef=0.0,
                  tile_rows=512, gated=True, score="softmax",
-                 gate_scale=1.0):
+                 gate_scale=1.0, n_group=1, topk_group=1):
         super().__init__()
         lo, hi = held_experts or (0, num_experts)
         if not 0 <= lo < hi <= num_experts:
@@ -378,6 +391,10 @@ class DroplessMoE(nn.Layer):
         self.top_k, self.tile_rows = int(top_k), int(tile_rows)
         self.renormalise, self.balance_coef = renormalise, balance_coef
         self.score, self.gate_scale = score, float(gate_scale)
+        if num_experts % n_group or not 1 <= topk_group <= n_group:
+            raise ValueError(f"DroplessMoE: {num_experts} experts in "
+                             f"{n_group} groups, {topk_group} kept")
+        self.n_group, self.topk_group = int(n_group), int(topk_group)
         held = hi - lo
         self.router = self.create_parameter([d_model, num_experts])
         if score == "sigmoid":
@@ -404,7 +421,8 @@ class DroplessMoE(nn.Layer):
                 held=self.held_experts, tile_rows=self.tile_rows,
                 renormalise=self.renormalise,
                 balance_coef=self.balance_coef, score=self.score,
-                bias=rest[-1] if biased else None, scale=self.gate_scale)
+                bias=rest[-1] if biased else None, scale=self.gate_scale,
+                n_group=self.n_group, topk_group=self.topk_group)
             return y.reshape(shape), balance, stats, picks
 
         ins = [x, self.router, self.gate_proj, self.up_proj, self.down_proj,

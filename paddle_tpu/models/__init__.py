@@ -46,3 +46,8 @@ from .nemotron_h import (  # noqa: F401
     NemotronHModel,
     NemotronHForCausalLM,
 )
+from .ling3 import (  # noqa: F401
+    Ling3Config,
+    Ling3Model,
+    Ling3ForCausalLM,
+)

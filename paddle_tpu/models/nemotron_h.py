@@ -125,15 +125,15 @@ class NemotronHConfig:
         return self.mamba_inner + 2 * self.n_groups * self.ssm_state_size
 
 
-def causal_conv(x, w, b):
+def causal_conv(x, w, b=None):
     """Depthwise causal convolution over the sequence and silu: x [b, s, c],
-    w [taps, c] (tap `taps - 1` weighs the step itself), b [c]."""
+    w [taps, c] (tap `taps - 1` weighs the step itself), b [c] or None."""
     taps, s = w.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    out = b.astype(F32) + sum(
-        padded[:, k:k + s].astype(F32) * w[k].astype(F32)
-        for k in range(taps))
-    return jax.nn.silu(out).astype(x.dtype)
+    bias = None if b is None else b.astype(F32)
+    out = sum(padded[:, k:k + s].astype(F32) * w[k].astype(F32)
+              for k in range(taps))
+    return jax.nn.silu(out if b is None else bias + out).astype(x.dtype)
 
 
 def gated_group_norm(y, z, w, groups, eps):

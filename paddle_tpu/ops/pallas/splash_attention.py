@@ -77,12 +77,14 @@ __all__ = ["splash_attention", "splash_attention_xla", "supports",
 _SUB = 8  # sublane replication of the kv-side segment-id plane
 
 
-def supports(q_shape, num_kv_heads, dtype, sk=None) -> bool:
-    """Whether the Pallas kernel can take this problem (else XLA)."""
+def supports(q_shape, num_kv_heads, dtype, sk=None, d_v=None) -> bool:
+    """Whether the Pallas kernel can take this problem (else XLA). `d_v`:
+    the values' width where it is not the keys' (latent attention's
+    expanded form: 192 / 128)."""
     if dtype not in (jnp.float32, jnp.bfloat16, jnp.float16):
         return False
     b, sq, h, d = q_shape
-    if d > 256 or h % num_kv_heads:
+    if max(d, d_v or d) > 256 or h % num_kv_heads:
         return False
     if sk is None:
         sk = sq
@@ -126,7 +128,7 @@ def splash_attention_xla(q, k, v, causal=True, segment_ids=None,
     p = jnp.where(any_valid, p, 0.0)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return out.reshape(b, sq, h, d).astype(q.dtype)
+    return out.reshape(b, sq, h, v.shape[-1]).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -422,8 +424,10 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, nqs, num_k, strips,
 
 
 def _specs(bq, bk, d, nqs, kvh, with_seg, with_sel, causal, num_k,
-           qi_base=0, window=None):
-    """Block specs shared by forward and fused backward. q-side tiles
+           qi_base=0, window=None, dv=None):
+    """Block specs shared by forward and fused backward; the last three
+    are spec_q, spec_k and spec_acc again at the values' width `dv` (o and
+    do, v, dV's accumulator). q-side tiles
     (q/do/o/lse) index the [bh, grp*sq, ...] layout by grid dim 1; the
     segment planes recover (batch, seq-position) as (g // kvh,
     qi % nqs) — q tiles never straddle a head boundary. A step above the
@@ -461,9 +465,12 @@ def _specs(bq, bk, d, nqs, kvh, with_seg, with_sel, causal, num_k,
                 return j
             return jax.lax.select(jax.lax.gt(j, last(i)), np.int32(num_k), j)
 
-    spec_q = pl.BlockSpec((1, bq, d), lambda g, i, j: (g, i, _Z))
-    spec_k = pl.BlockSpec((1, bk, d), lambda g, i, j: (g, kv(i, j), _Z))
-    spec_acc = pl.BlockSpec((1, bk, d), lambda g, i, j: (g, acc(i, j), _Z))
+    def wide(d):
+        return (pl.BlockSpec((1, bq, d), lambda g, i, j: (g, i, _Z)),
+                pl.BlockSpec((1, bk, d), lambda g, i, j: (g, kv(i, j), _Z)),
+                pl.BlockSpec((1, bk, d), lambda g, i, j: (g, acc(i, j), _Z)))
+
+    spec_q, spec_k, spec_acc = wide(d)
     spec_lse = pl.BlockSpec((1, bq, _LANES), lambda g, i, j: (g, i, _Z))
     seg = []
     if with_seg:
@@ -477,7 +484,7 @@ def _specs(bq, bk, d, nqs, kvh, with_seg, with_sel, causal, num_k,
         seg.append(pl.BlockSpec(
             (1, bq, bk),
             lambda g, i, j: (_div(g, kvh), _rem(i, nqs), kv(i, j))))
-    return spec_q, spec_k, spec_acc, spec_lse, seg
+    return (spec_q, spec_k, spec_acc, spec_lse, seg) + wide(dv or d)
 
 
 def _acc_zeros(bh, sk, bk, d, causal, planes=1):
@@ -498,12 +505,12 @@ def _acc_sum(acc, sk, planes, dtype):
 def _fwd(q, k, v, segq, segk, scale, causal, bq, bk, sq, kvh, with_seg,
          interpret, sel=None, window=None):
     bh, sq_all, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[2]
     nqs, num_k = sq // bq, sk // bk
     with_sel = sel is not None
-    spec_q, spec_k, _, spec_lse, seg_specs = _specs(
+    spec_q, spec_k, _, spec_lse, seg_specs, spec_o, spec_v, _ = _specs(
         bq, bk, d, nqs, kvh, with_seg, with_sel, causal, num_k,
-        window=window)
+        window=window, dv=dv)
     strips = _strips(bq, bk, causal, num_k, window)
     kern = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
@@ -515,14 +522,14 @@ def _fwd(q, k, v, segq, segk, scale, causal, bq, bk, sq, kvh, with_seg,
         kern,
         name="splash_fwd",
         grid=(bh, sq_all // bq, _k_steps(window, bq, nqs, num_k)),
-        in_specs=[spec_q, spec_k, spec_k] + seg_specs,
-        out_specs=[spec_q, spec_lse],
+        in_specs=[spec_q, spec_k, spec_v] + seg_specs,
+        out_specs=[spec_o, spec_lse],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq_all, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, sq_all, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, sq_all, _LANES), jnp.float32),
         ],
         scratch_shapes=[] if strips > 1 else [
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
         ],
@@ -629,7 +636,7 @@ def _bwd_call(q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
               causal, bq, bk, sq, kvh, with_seg, num_q, qi_base,
               interpret, sel=None, window=None):
     bh, _, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[2]
     nqs, num_k = sq // bq, sk // bk
     # q-side operands arrive pre-sliced to the processed rows (the
     # rowloop passes one q-row per call), so q-side specs index from 0
@@ -640,9 +647,9 @@ def _bwd_call(q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
     # so dK/dV have nothing to add to and leave in k's dtype (`once`).
     once = dk_acc is None
     with_sel = sel is not None
-    spec_q, spec_k, spec_acc, spec_lse, seg_specs = _specs(
-        bq, bk, d, nqs, kvh, with_seg, with_sel, causal, num_k, qi_base,
-        window)
+    (spec_q, spec_k, spec_acc, spec_lse, seg_specs, spec_o, spec_v,
+     spec_vacc) = _specs(bq, bk, d, nqs, kvh, with_seg, with_sel, causal,
+                         num_k, qi_base, window, dv)
     kern = functools.partial(
         _bwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
         nqs=nqs, num_k=num_k,
@@ -660,13 +667,13 @@ def _bwd_call(q, k, v, do, out, lse, segq, segk, dk_acc, dv_acc, scale,
         kern,
         name="splash_bwd",
         grid=(bh, num_q, _k_steps(window, bq, nqs, num_k)),
-        in_specs=[spec_q, spec_k, spec_k, spec_q, spec_q, spec_lse]
-        + seg_specs + ([] if once else [spec_acc, spec_acc]),
-        out_specs=[spec_q, spec_acc, spec_acc],
+        in_specs=[spec_q, spec_k, spec_v, spec_o, spec_o, spec_lse]
+        + seg_specs + ([] if once else [spec_acc, spec_vacc]),
+        out_specs=[spec_q, spec_acc, spec_vacc],
         out_shape=[
             jax.ShapeDtypeStruct((bh, num_q * bq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, acc_rows, d), acc_dtype),
-            jax.ShapeDtypeStruct((bh, acc_rows, d), acc_dtype),
+            jax.ShapeDtypeStruct((bh, acc_rows, dv), acc_dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
@@ -797,7 +804,7 @@ def _bwd(q, k, v, out, lse, do, segq, segk, scale, causal, bq, bk, sq,
         fused = not interpret and (
             num_q == sq // bq or sq // bq * planes >= _REVISIT_MIN)
     dk_acc = _acc_zeros(bh, sk, bk, d, causal, planes)
-    dv_acc = _acc_zeros(bh, sk, bk, d, causal, planes)
+    dv_acc = _acc_zeros(bh, sk, bk, v.shape[2], causal, planes)
     if fused:
         _alias_selfcheck(q.dtype, d, scale, causal, bq, bk, sk, window)
         dq, dk_acc, dv_acc = _bwd_call(
@@ -865,7 +872,7 @@ def splash_attention(q, k, v, causal=True, segment_ids=None, scale=None,
     `use_kernel` overrides the routing outright. Differentiable
     (custom tiled backward) in q/k/v."""
     b, sq, h, d = q.shape
-    sk, kvh = k.shape[1], k.shape[2]
+    sk, kvh, d_v = k.shape[1], k.shape[2], v.shape[3]
     if causal and sq != sk:
         raise ValueError("causal splash attention needs equal seq lens")
     if h % kvh:
@@ -880,26 +887,38 @@ def splash_attention(q, k, v, causal=True, segment_ids=None, scale=None,
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     use_kernel, interpret = routing.route(
-        "splash_attention", supports((b, sq, h, d), kvh, q.dtype, sk=sk),
-        (f"q{(b, sq, h, d)}", f"kv_heads={kvh}", f"sk={sk}", str(q.dtype)),
+        "splash_attention",
+        supports((b, sq, h, d), kvh, q.dtype, sk=sk, d_v=d_v),
+        (f"q{(b, sq, h, d)}", f"kv_heads={kvh}", f"sk={sk}", str(q.dtype))
+        + ((f"d_v={d_v}",) if d_v != d else ()),
         interpret, use_kernel)
     if not use_kernel:
         return splash_attention_xla(q, k, v, causal=causal,
                                     segment_ids=segment_ids, scale=scale,
                                     selection=selection, window=window)
     grp = h // kvh
+    if d != d_v and d % _LANES:
+        # query / key rows of one and a half lane tiles (192 beside values
+        # of 128): zeros fill them to whole tiles, which moves no score
+        q, k = (jnp.pad(x, ((0, 0),) * 3 + ((0, -d % _LANES),))
+                for x in (q, k))
+        d = q.shape[3]
     if window is not None:      # square tiles (`_band_back`)
         block_q = block_k = block_q or block_k or _window_block(sq)
     if block_q is None:
         block_q = _pick_block(sq)
     if block_k is None:
         block_k = _pick_block(sk)
+        if d > _LANES:
+            # rows of two lane tiles: a 1,024-key tile's backward asks for
+            # 18.6 MiB of a v5e's 16 (its float32 dK accumulator doubles)
+            block_k = min(block_k, 512)
 
     # fold the group dim into the q-row axis: kv head kh serves q rows
     # [kh*grp*sq, (kh+1)*grp*sq) — q head index = row // sq within them
     q2 = jnp.transpose(q, (0, 2, 1, 3)).reshape(b * kvh, grp * sq, d)
     k2 = jnp.transpose(k, (0, 2, 1, 3)).reshape(b * kvh, sk, d)
-    v2 = jnp.transpose(v, (0, 2, 1, 3)).reshape(b * kvh, sk, d)
+    v2 = jnp.transpose(v, (0, 2, 1, 3)).reshape(b * kvh, sk, d_v)
     segq = segk = None
     with_seg = segment_ids is not None
     if with_seg:
@@ -913,4 +932,4 @@ def splash_attention(q, k, v, causal=True, segment_ids=None, scale=None,
     out2 = _splash(q2, k2, v2, segq, segk, sel, float(scale), bool(causal),
                    int(block_q), int(block_k), int(sq), int(kvh),
                    with_seg, bool(interpret), window)
-    return jnp.transpose(out2.reshape(b, kvh * grp, sq, d), (0, 2, 1, 3))
+    return jnp.transpose(out2.reshape(b, kvh * grp, sq, d_v), (0, 2, 1, 3))

@@ -214,6 +214,18 @@ DEVICE_SCOPES = (
                                 # scan's two kernels and D x
     "ssm/gate_norm",            # y * silu(z) and the grouped RMSNorm
     "ssm/out",                  # W_out and the residual
+    "kda/project",              # a KDA layer's input norm, W_q, W_k, W_v,
+                                # W_f, W_b and W_g
+    "kda/conv",                 # the three depthwise causal convolutions
+    "kda/gate",                 # the decay a, beta, the L2 norms and q's scale
+    "kda/scan",                 # beta's products, the running sums and the
+                                # chunked delta rule's two kernels
+    "kda/gate_norm",            # the head's RMSNorm and its sigmoid gate
+    "kda/out",                  # W_o and the residual
+    "mla/project",              # latent attention's norm, W_q, W_kva, W_kvb,
+                                # q/k norm, rotary, gate, W_o and residual
+    "mla_attention",            # attention at 192 / 128 head widths
+    "mlp",                      # a leading layer's norm, dense SwiGLU, residual
     "moe/norm",                 # the post-attention norm
     "moe/route",                # bare: what XLA adds between the leaves
     "moe/route/router",         # router product, softmax, top-k, balance

@@ -1,0 +1,122 @@
+"""`ops/pallas/kda.py` (the chunked gated delta rule with a decay a channel)
+against the token-by-token recurrence of the plain reference
+(benchmark/reference/ling3.py `kda_recurrence`): `kda_xla` and the two
+kernels in interpret mode, values and all five cotangents."""
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu  # noqa: F401
+from paddle_tpu.ops.pallas import kda as K
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                "..", "benchmark"))
+from reference import ling3 as ref  # noqa: E402
+
+NAMES = ("o", "dq", "dk", "dv", "da", "dbeta")
+
+
+def operands(seed, b, seq, heads, d, lo, hi, dtype=jnp.float32):
+    """q, k as they enter the recurrence (unit rows that lean the same way,
+    as rows that left a silu do; q scaled), v, a in (lo, hi), beta."""
+    rng = np.random.default_rng(seed)
+
+    def unit(x):
+        return x / np.linalg.norm(x, axis=-1, keepdims=True)
+
+    q = unit(rng.standard_normal((b, seq, heads, d))) * d ** -0.5
+    k = unit(rng.standard_normal((b, seq, heads, d)) + 0.5)
+    return (jnp.asarray(q, dtype), jnp.asarray(k, dtype),
+            jnp.asarray(rng.standard_normal((b, seq, heads, d)), dtype),
+            jnp.asarray(rng.uniform(lo, hi, (b, seq, heads, d)), jnp.float32),
+            jnp.asarray(rng.uniform(0.05, 0.95, (b, seq, heads)),
+                        jnp.float32))
+
+
+def pulled_back(f, args, seed=9):
+    do = jnp.asarray(np.random.default_rng(seed).standard_normal(
+        args[2].shape), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        o, pull = jax.vjp(f, *args)
+        return (o,) + pull(do.astype(o.dtype))
+
+
+def worst(got, want):
+    return {n: float(jnp.max(jnp.abs(g.astype(jnp.float32) - w))
+                     / jnp.max(jnp.abs(w)))
+            for n, g, w in zip(NAMES, got, want)}
+
+
+PATHS = {"xla": lambda *a, **kw: K.kda_xla(*a, **kw),
+         "kernels-interpreted": lambda *a, **kw: K.kda(*a, interpret=True,
+                                                       **kw)}
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+@pytest.mark.parametrize("lo,hi", [(-5.0, -4.5), (-0.5, 0.0), (-5.0, 0.0)],
+                         ids=["fast-decay", "slow-decay", "whole-range"])
+def test_kda_matches_the_recurrence_forward_and_all_five_cotangents(
+        path, lo, hi):
+    # three chunks of 64; two grid steps' worth for the kernels (a block of
+    # two chunks, then one of one would not divide: 192 = 3 blocks of 1)
+    args = operands(0, 1, 192, 2, 128, lo, hi)
+    want = pulled_back(ref.kda_recurrence, args)
+    got = pulled_back(PATHS[path], args)
+    assert max(worst(got, want).values()) < 5e-4, worst(got, want)
+
+
+@pytest.mark.parametrize("chunk,seq", [(16, 64), (32, 128), (64, 512)])
+def test_kda_kernels_at_other_chunks_and_blocks_of_several(chunk, seq):
+    args = operands(1, 2, seq, 1, 128, -5.0, 0.0)
+    want = pulled_back(ref.kda_recurrence, args)
+    got = pulled_back(lambda *a: K.kda(*a, chunk=chunk, interpret=True), args)
+    assert max(worst(got, want).values()) < 5e-4, worst(got, want)
+    assert K._block(seq, chunk) == 4 * chunk
+
+
+def test_kda_with_bfloat16_operands_stays_near_the_recurrence():
+    args = operands(2, 1, 128, 2, 128, -5.0, 0.0, jnp.bfloat16)
+    want = pulled_back(ref.kda_recurrence,
+                       tuple(a.astype(jnp.float32) for a in args))
+    for path in PATHS.values():
+        assert max(worst(pulled_back(path, args), want).values()) < 3e-2
+
+
+def test_kda_interpreted_runs_the_kernels_not_the_xla_path(monkeypatch):
+    monkeypatch.setattr(K, "kda_xla", None)
+    args = operands(3, 1, 64, 1, 128, -5.0, 0.0)
+    assert K.kda(*args, interpret=True).shape == args[2].shape
+
+
+def test_the_triangular_inverse_is_exact_where_plain_doubling_is_not():
+    """Rows that all lean one way: I + N has entries near 1 below the
+    diagonal, N's powers grow as binomials and the two-level inverse keeps
+    float32's digits."""
+    n = 64
+    a = np.tril(np.full((n, n), 0.9, np.float32), -1)
+    got = np.asarray(K._tri_inverse(jnp.asarray(a)))
+    want = np.linalg.inv(np.eye(n) + a.astype(np.float64))
+    np.testing.assert_allclose(got, want, atol=5e-4)
+    small = np.asarray(K._tri_inverse(jnp.asarray(a[:16, :16])))
+    np.testing.assert_allclose(small, want[:16, :16], atol=5e-4)
+    plain, power = np.eye(n, dtype=np.float32) - a, a
+    for _ in range(5):          # (I - N)(I + N^2) .. (I + N^32) in float32
+        power = power @ power
+        plain = plain @ (np.eye(n, dtype=np.float32) + power)
+    assert np.abs(plain - want).max() > 1e3
+
+
+def test_what_the_kernels_do_not_take_goes_to_xla_or_is_refused_by_name():
+    assert K.supports((2, 8192, 32, 128), (2, 8192, 32, 128), 64,
+                      jnp.bfloat16)
+    assert not K.supports((1, 64, 2, 64), (1, 64, 2, 64), 64, jnp.float32)
+    assert not K.supports((1, 64, 2, 128), (1, 64, 2, 128), 64, jnp.float16)
+    args = operands(4, 1, 96, 1, 128, -5.0, 0.0)
+    with pytest.raises(ValueError, match="not a multiple of chunk"):
+        K.kda(*args)
+    with pytest.raises(ValueError, match="does not support"):
+        K.kda(*operands(4, 1, 64, 1, 64, -5.0, 0.0), use_kernel=True)
