@@ -78,6 +78,31 @@ def test_kda_kernels_at_other_chunks_and_blocks_of_several(chunk, seq):
     assert K._block(seq, chunk) == 4 * chunk
 
 
+@pytest.mark.parametrize("seq,per,systems", [
+    (64, 1, [1]), (128, 2, [2]), (192, 1, [1]), (512, 4, [2, 2]),
+    (1024, 4, [2, 2])])
+def test_kda_kernels_at_grid_steps_of_one_two_and_four_chunks(seq, per,
+                                                              systems):
+    """A grid step of one chunk runs the [64, 64] system, one of two or four
+    chunks its pairs as [128, 128] systems; 512 and 1024 are two and four
+    grid steps of four chunks (the state and dS through scratch, the pair
+    loop run again)."""
+    assert K._block(seq, 64) == per * 64
+    assert K._systems(per * 64, 64) == systems
+    args = operands(5, 1, seq, 2, 128, -5.0, 0.0)
+    want = pulled_back(ref.kda_recurrence, args)
+    got = pulled_back(lambda *a: K.kda(*a, interpret=True), args)
+    assert max(worst(got, want).values()) < 5e-4, worst(got, want)
+
+
+def test_at_the_cells_geometry_a_grid_step_pairs_its_chunks():
+    b, seq, heads, d = 2, 8192, 32, 128
+    assert K.supports((b, seq, heads, d), (b, seq, heads, d), 64,
+                      jnp.bfloat16)
+    block = K._block(seq, 64)
+    assert block == 256 and K._systems(block, 64) == [2, 2]
+
+
 def test_kda_with_bfloat16_operands_stays_near_the_recurrence():
     args = operands(2, 1, 128, 2, 128, -5.0, 0.0, jnp.bfloat16)
     want = pulled_back(ref.kda_recurrence,
@@ -108,6 +133,37 @@ def test_the_triangular_inverse_is_exact_where_plain_doubling_is_not():
         power = power @ power
         plain = plain @ (np.eye(n, dtype=np.float32) + power)
     assert np.abs(plain - want).max() > 1e3
+
+
+@pytest.mark.parametrize("chunk", [16, 32, 64])
+def test_the_inverse_of_a_pair_as_one_system_is_the_two_chunks_own(chunk):
+    """Two chunks as ONE block-diagonal [2 C, 2 C] system, rows that lean
+    one way: the two inverses come back side by side, each that chunk's own
+    to float32's rounding, and nothing of one chunk reaches the other's (the
+    blocks beside the diagonal are exact zeros, not small numbers)."""
+    rng = np.random.default_rng(chunk)
+    blocks = [np.tril(np.full((chunk, chunk), 0.9, np.float32)
+                      + 0.05 * rng.standard_normal((chunk, chunk)).astype(
+                          np.float32), -1) for _ in range(2)]
+
+    def paired(first, second):
+        pair = np.zeros((2 * chunk, 2 * chunk), np.float32)
+        pair[:chunk, :chunk], pair[chunk:, chunk:] = first, second
+        return np.asarray(K._tri_inverse(jnp.asarray(pair), chunk))
+
+    got = paired(*blocks)
+    assert got.shape == (chunk, 2 * chunk)
+    for i, a in enumerate(blocks):
+        at = slice(i * chunk, (i + 1) * chunk)
+        own = np.asarray(K._tri_inverse(jnp.asarray(a)))
+        want = np.linalg.inv(np.eye(chunk) + a.astype(np.float64))
+        # float32's rounding of the powers on the way (entries near 1e3),
+        # as far as either lies from the float64 inverse
+        np.testing.assert_allclose(got[:, at], own, atol=1e-4)
+        np.testing.assert_allclose(got[:, at], want, atol=5e-4)
+    other = paired(blocks[0], 0 * blocks[1])
+    np.testing.assert_array_equal(other[:, :chunk], got[:, :chunk])
+    np.testing.assert_array_equal(other[:, chunk:], np.eye(chunk))
 
 
 def test_what_the_kernels_do_not_take_goes_to_xla_or_is_refused_by_name():

@@ -401,12 +401,15 @@ def test_the_new_scopes_are_device_scopes():
 from test_keye_vl2 import v5e_chip  # noqa: E402,F401  (the fixture)
 
 
-@pytest.mark.parametrize("what", ["kda", "mla_attention", "step"])
+@pytest.mark.parametrize("what", ["kda", "kda-one-chunk", "mla_attention",
+                                  "step"])
 def test_the_kernels_and_the_step_compile_for_a_v5e_at_published_widths(
         v5e_chip, what):
     """`kda`: both kernels at the cell's shapes (2 x 8192 tokens, 32 heads of
-    128 keys and values). `mla_attention`: the attention kernels at 192 / 128
-    head widths over 8,192 tokens. `step`: loss and every gradient of a
+    128 keys and values: grid steps of four chunks, paired); `kda-one-chunk`:
+    at 192 tokens, grid steps of one chunk, the [64, 64] system.
+    `mla_attention`: the attention kernels at 192 / 128 head widths over
+    8,192 tokens. `step`: loss and every gradient of a
     three-layer model at the published widths on one 1,024-token sequence
     (KDA + dense, KDA + mixture, MLA + mixture): every kernel of the step
     lowers, and every `DEVICE_SCOPES` path the model names reaches the
@@ -427,11 +430,12 @@ def test_the_kernels_and_the_step_compile_for_a_v5e_at_published_widths(
         return fn
 
     scopes = ()
-    if what == "kda":
+    if what.startswith("kda"):
+        shape = (2, 8192, 32, 128) if what == "kda" else (1, 192, 2, 128)
         fn = both(lambda *v: K.kda(*v))
-        wide = spec((2, 8192, 32, 128), bf16)
-        args = (wide, wide, wide, spec((2, 8192, 32, 128), jnp.float32),
-                spec((2, 8192, 32), jnp.float32))
+        wide = spec(shape, bf16)
+        args = (wide, wide, wide, spec(shape, jnp.float32),
+                spec(shape[:3], jnp.float32))
         want = {"kda_fwd", "kda_bwd"}
     elif what == "mla_attention":
         fn = both(lambda *v: splash_attention(*v, causal=True,

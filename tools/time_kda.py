@@ -4,10 +4,13 @@ against the token-by-token recurrence there.
 
     python3 tools/time_kda.py [--batch 2 --seq 8192 --blocks 1,2,4]
 
-Prints one line `kda: {...}`: milliseconds of the forward kernel and of
-forward + backward (the custom VJP's two kernels and XLA's share: beta's
-products, the running sums) at the Ling-3.0 cell's shapes, for each number
-of chunks a grid step takes, and the largest error of o and of each
+Prints one line `kda: {...}`: milliseconds of the forward kernel (alone, and
+as the VJP runs it, saving each chunk's state), of the backward kernel and
+of forward + backward (the custom VJP's two kernels and XLA's share: beta's
+products) at the Ling-3.0 cell's shapes, nanoseconds a chunk-head of either
+kernel, for each number of chunks a grid step takes (one chunk: the
+[64, 64] system; two or four: pairs as [128, 128] systems), and the largest
+error of o and of each
 cotangent against the recurrence on one short sequence, relative to the
 cotangent's largest entry, with float32 and with bfloat16 operands. With
 `--mla`, the attention kernels at 192 / 128 head widths too: their error
@@ -98,6 +101,7 @@ def main(argv=None):
                 do, *(v.astype(jnp.float32) for v in a))
         for name in args.inverse.split(","):
             K._mm = inverses[name]
+            jax.clear_caches()      # `kda_fwd` / `kda_bwd` are jitted
             try:
                 got = both(scanner())(do, *a)
             except Exception as e:      # a precision Mosaic does not lower
@@ -121,15 +125,27 @@ def main(argv=None):
 
     flat = [x.reshape(args.batch, args.seq, -1)
             for x in K._operands(*a, args.chunk)]
+    chunk_heads = args.batch * heads * (args.seq // args.chunk)
     for name in args.inverse.split(","):
         K._mm = inverses[name]
         for n in [int(x) for x in args.blocks.split(",")]:
             K._block = lambda seq, chunk, n=n: n * chunk
+            jax.clear_caches()
             tag = f"{n}" + ("" if name == "float32" else "." + name)
             try:
+                saving = jax.jit(lambda *v: K.kda_fwd(
+                    *v, args.chunk, save=True, interpret=interpret))
                 out[f"fwd_kernel_ms.{tag}"] = timed(jax.jit(
                     lambda *v: K.kda_fwd(*v, args.chunk,
                                          interpret=interpret)), *flat)
+                out[f"fwd_save_kernel_ms.{tag}"] = timed(saving, *flat)
+                out[f"bwd_kernel_ms.{tag}"] = timed(jax.jit(
+                    lambda *v: K.kda_bwd(*v, args.chunk,
+                                         interpret=interpret)),
+                    *flat, saving(*flat)[1], flat[3])
+                for k in ("fwd", "bwd"):
+                    out[f"{k}_ns_a_chunk_head.{tag}"] = (
+                        1e6 * out[f"{k}_kernel_ms.{tag}"] / chunk_heads)
                 out[f"fwd_ms.{tag}"] = timed(jax.jit(scanner()), *a)
                 out[f"fwd_bwd_ms.{tag}"] = timed(both(scanner()), do, *a)
             except Exception as e:
