@@ -149,11 +149,6 @@ def _product_f32_bwd(res, g):
 _product_f32.defvjp(_product_f32_fwd, _product_f32_bwd)
 
 
-def _unit(x32):
-    return x32 * jax.lax.rsqrt(jnp.sum(jnp.square(x32), -1, keepdims=True)
-                               + 1e-6)
-
-
 def _turn(x, theta):
     """Rotate-half turn of x [b, s, heads, r] by the positions 0..s-1."""
     return apply_rotary_pos_emb(
@@ -259,14 +254,12 @@ class Ling3Layer(nn.Layer):
 
         def run(x, ln, wq, wk, wv, cq, ck, cv, wf, a_log, dt_bias, wb, wg,
                 gn, wo):
-            from ..ops.pallas.kda import kda
+            from ..ops.pallas.kda import kda_flat
+            from ..ops.pallas.kda_rows import kda_gated_norm, kda_inputs
 
-            b, s, _ = x.shape
-            heads, d = c.num_attention_heads, c.head_dim
-
-            def cut(v):
-                return v.reshape(b, s, heads, d)
-
+            # flat [b, s, heads d] rows from the convolutions to `kda/out`:
+            # what works on one head's channels runs on column blocks of
+            # them (ops/pallas/kda_rows.py)
             with jax.named_scope("kda/project"):
                 h = _rms(x, ln, c.rms_norm_eps)
                 q, k, v, f = h @ wq, h @ wk, h @ wv, _product_f32(h, wf)
@@ -275,19 +268,14 @@ class Ling3Layer(nn.Layer):
                 q, k, v = (causal_conv(q, cq), causal_conv(k, ck),
                            causal_conv(v, cv))
             with jax.named_scope("kda/gate"):
-                q = (_unit(cut(q).astype(F32)) * d ** -0.5).astype(x.dtype)
-                k = _unit(cut(k).astype(F32)).astype(x.dtype)
-                # A_log a head, dt_bias a channel, on [b, s, heads d] rows
-                a = cut(c.kda_lower_bound * jax.nn.sigmoid(
-                    jnp.repeat(jnp.exp(a_log.astype(F32)), d)
-                    * (f + dt_bias.astype(F32))))
-                beta = jax.nn.sigmoid(beta.astype(F32))
+                q, k, kb, vb, a = kda_inputs(
+                    q, k, v, f, beta, a_log, dt_bias,
+                    lower_bound=c.kda_lower_bound)
             with jax.named_scope("kda/scan"):
-                o = kda(q, k, cut(v), a, beta, chunk=c.kda_chunk_size)
+                o = kda_flat(q, k, kb, vb, a, c.num_attention_heads,
+                             chunk=c.kda_chunk_size)
             with jax.named_scope("kda/gate_norm"):
-                o = _rms(o, gn, c.rms_norm_eps).astype(F32) \
-                    * jax.nn.sigmoid(gate.astype(F32))[..., None]
-                o = o.astype(x.dtype).reshape(b, s, heads * d)
+                o = kda_gated_norm(o, gate, gn, eps=c.rms_norm_eps)
             with jax.named_scope("kda/out"):
                 return x + o @ wo
 

@@ -81,8 +81,10 @@ scheduler does not overlap them.
 
 G is formed inside the kernels too, as a product with a triangle of ones
 (XLA's running sum over a [b, L, H 128] float32 table and its pull-back cost
-a fifth of the forward kernel's time beside it). What stays XLA's: beta's
-products with k and v and their pull-backs.
+a fifth of the forward kernel's time beside it). Beta's products with k and
+v are formed beside the kernels: by `kda_rows.kda_inputs` for `kda_flat`, the
+model's entry, which takes the five operands as flat [b, L, H 128] rows;
+by XLA (`_operands`) for `kda`, which takes [b, L, H, d] tables.
 """
 from __future__ import annotations
 
@@ -96,7 +98,8 @@ import numpy as np
 from . import routing
 from .flash_attention import _LANES, _Z, _dot, pl, pltpu
 
-__all__ = ["kda", "kda_xla", "kda_fwd", "kda_bwd", "supports"]
+__all__ = ["kda", "kda_flat", "kda_xla", "kda_fwd", "kda_bwd",
+           "supports"]
 
 F32 = jnp.float32
 SUB = 16            # rows of a sub-block: SUB x 5 < 88.7 (module docstring)
@@ -540,3 +543,23 @@ def kda(q, k, v, a, beta, chunk=64, interpret=None, use_kernel=None):
     flat = [x.reshape(b, seq, -1)
             for x in _operands(q, k, v, a, beta, chunk)]
     return _core(*flat, chunk, interpret).reshape(v.shape).astype(q.dtype)
+
+
+def kda_flat(q, k, kb, vb, a, heads, chunk=64, interpret=None,
+             use_kernel=None):
+    """`kda` on the kernels' own operands, flat rows in and out: q, k,
+    kb = beta k, vb = beta v [b, L, heads d] in one type, a [b, L, heads d]
+    float32 (`kda_rows.kda_inputs` makes the five) -> o [b, L, heads d] in
+    q's type. Kernels and fallback as `kda`; no [b, L, heads, d] table is
+    formed on the kernels' path."""
+    b, seq, width = q.shape
+    if seq % chunk:
+        raise ValueError(f"kda: seq {seq} is not a multiple of chunk {chunk}")
+    cut = (b, seq, heads, width // heads)
+    use_kernel, interpret = routing.route(
+        "kda", supports(cut, cut, chunk, q.dtype),
+        (cut, cut, chunk, str(q.dtype)), interpret, use_kernel)
+    if use_kernel:
+        return _core(q, k, kb, vb, a, chunk, interpret)
+    o = _core_xla(*(x.reshape(cut) for x in (q, k, kb, vb, a)), chunk)
+    return o.reshape(q.shape).astype(q.dtype)

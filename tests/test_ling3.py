@@ -396,6 +396,88 @@ def test_the_new_scopes_are_device_scopes():
         assert scope in DEVICE_SCOPES
 
 
+# -- a KDA layer's tables stay flat rows --------------------------------------
+
+def _kda_layer_loss_and_grads(head_dim, interpret):
+    """-> (lowered text, loss, gradients) of a one-layer model (KDA + dense,
+    two heads of `head_dim`, 2 x 128 tokens) with the kernels interpreted or
+    on the CPU's XLA path."""
+    import dataclasses
+
+    import jax
+    from paddle_tpu.utils import flags
+
+    c = dataclasses.replace(config(layers=1), head_dim=head_dim,
+                            kda_chunk_size=64)
+    model = build(c)
+    params = list(model.parameters())
+    rng = np.random.default_rng(7)
+    ids, labels = (jnp.asarray(rng.integers(0, VOCAB, (2, 128)))
+                   for _ in range(2))
+
+    def fn(pvals):
+        def loss(pvals):
+            for p, v in zip(params, pvals):
+                p._data = v
+            with paddle.no_grad():
+                return model.loss(paddle.Tensor._wrap(ids),
+                                  paddle.Tensor._wrap(labels))._data
+        return jax.value_and_grad(loss)(pvals)
+
+    pvals = [p._data for p in params]
+    flags.set_flags({"FLAGS_pallas_force_interpret": interpret})
+    try:
+        lowered = jax.jit(fn).lower(pvals)
+        loss, grads = lowered.compile()(pvals)
+    finally:
+        flags.set_flags({"FLAGS_pallas_force_interpret": False})
+        for p, v in zip(params, pvals):
+            p._data = v
+    return lowered.as_text(), float(loss), [np.asarray(g) for g in grads]
+
+
+def test_a_kda_layer_holds_no_heads_by_128_table_and_64_wide_heads_fall_back():
+    """At 128-wide heads, kernels interpreted: between the convolutions and
+    `kda/out` every table is [b, s, heads 128] rows or a kernel's [rows, 128]
+    block; no [2, 128, 2, 128] tensor of any type is in the lowered loss +
+    gradients, and nothing fell back. At 64-wide heads the row kernels and
+    the scan are counted in `routing.xla_fallbacks`, a geometry each, the
+    [2, 128, 2, 64] tables of the `jnp` forms ARE there (the expression
+    finds what it looks for), and loss and gradients are the CPU path's."""
+    import re
+
+    from paddle_tpu.ops.pallas import routing
+
+    def tables(text, d):
+        return re.findall(rf"tensor<2x128x2x{d}x\w+>", text)
+
+    def fell_back():
+        return {k: n for k, n in routing.xla_fallbacks.items()
+                if k[0].startswith("kda")}
+
+    before = fell_back()
+    text, loss, grads = _kda_layer_loss_and_grads(128, interpret=True)
+    assert not tables(text, 128), sorted(set(tables(text, 128)))
+    assert fell_back() == before
+    cpu_text, want_loss, want = _kda_layer_loss_and_grads(128,
+                                                          interpret=False)
+    assert tables(cpu_text, 128)        # the `jnp` forms' own, found
+    assert abs(loss - want_loss) < 1e-5 * abs(want_loss)
+    for g, w in zip(grads, want):
+        assert np.abs(g - w).max() <= 2e-4 * max(np.abs(w).max(), 1e-6)
+
+    text, loss, grads = _kda_layer_loss_and_grads(64, interpret=True)
+    assert tables(text, 64)
+    new = {k: n - before.get(k, 0) for k, n in fell_back().items()
+           if n != before.get(k, 0)}
+    assert sorted(k[0] for k in new) == ["kda", "kda_gated_norm",
+                                         "kda_inputs"], new
+    _, want_loss, want = _kda_layer_loss_and_grads(64, interpret=False)
+    assert loss == want_loss
+    for g, w in zip(grads, want):
+        np.testing.assert_array_equal(g, w)
+
+
 # -- the chip's compiler, with no chip ---------------------------------------
 
 from test_keye_vl2 import v5e_chip  # noqa: E402,F401  (the fixture)
@@ -419,6 +501,7 @@ def test_the_kernels_and_the_step_compile_for_a_v5e_at_published_widths(
     from paddle_tpu.ops.pallas import routing
 
     bf16 = jnp.bfloat16
+    fell_back = dict(routing.xla_fallbacks)
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
@@ -468,7 +551,9 @@ def test_the_kernels_and_the_step_compile_for_a_v5e_at_published_widths(
         args = (spec((1, 1024), jnp.int32), spec((1, 1024), jnp.int32),
                 [spec(p._data.shape, p._data.dtype) for p in params],
                 [spec(b._data.shape, b._data.dtype) for b in buffers])
-        want = {"kda_fwd", "kda_bwd", "splash_fwd", "splash_bwd"}
+        want = {"kda_fwd", "kda_bwd", "splash_fwd", "splash_bwd",
+                "kda_inputs_fwd", "kda_inputs_bwd", "kda_gated_norm_fwd",
+                "kda_gated_norm_bwd"}
         scopes = ("kda/project", "kda/conv", "kda/gate", "kda/scan",
                   "kda/gate_norm", "kda/out", "mla/project", "mla_attention",
                   "mlp", "moe/shared", "moe/experts", "moe/route/router",
@@ -477,7 +562,8 @@ def test_the_kernels_and_the_step_compile_for_a_v5e_at_published_widths(
         lowering_platforms=("tpu",)).compile().as_text()
     assert want <= set(routing.mosaic_kernels(text)), \
         routing.mosaic_kernels(text)
-    assert not [k for k in routing.xla_fallbacks if k[0] == "kda"]
+    assert not [k for k, n in routing.xla_fallbacks.items()
+                if k[0].startswith("kda") and n != fell_back.get(k, 0)]
     for scope in scopes:
         assert scope in DEVICE_SCOPES
         assert f"/{scope}/" in text or f"{scope})" in text, scope

@@ -2,7 +2,8 @@
 """Time `ops/pallas/kda.py`'s pieces alone on the chip, and hold the kernels
 against the token-by-token recurrence there.
 
-    python3 tools/time_kda.py [--batch 2 --seq 8192 --blocks 1,2,4]
+    python3 tools/time_kda.py [--batch 2 --seq 8192 --blocks 1,2,4 --mla
+                               --rows 512,1024,2048]
 
 Prints one line `kda: {...}`: milliseconds of the forward kernel (alone, and
 as the VJP runs it, saving each chunk's state), of the backward kernel and
@@ -15,6 +16,10 @@ cotangent against the recurrence on one short sequence, relative to the
 cotangent's largest entry, with float32 and with bfloat16 operands. With
 `--mla`, the attention kernels at 192 / 128 head widths too: their error
 against the XLA form on 1,024 tokens and their times at the cell's shapes.
+With `--rows`, the four row kernels of `ops/pallas/kda_rows.py` alone at the
+cell's shapes, for each number of rows a grid step's block takes: ms, the
+bytes each has to move, GB/s against the chip's 819, forward + pull-back
+through the VJP, and the same function's XLA form beside them.
 """
 from __future__ import annotations
 
@@ -41,6 +46,8 @@ def main(argv=None):
                     "held against the recurrence")
     ap.add_argument("--mla", action="store_true", help="also the attention "
                     "kernels at latent attention's 192 / 128 head widths")
+    ap.add_argument("--rows", default="", help="also the row kernels "
+                    "(kda_rows.py), at each of these rows a block")
     args = ap.parse_args(argv)
 
     import jax
@@ -174,7 +181,82 @@ def main(argv=None):
         v = qkv(args.batch, args.seq, 5)
         out["mla_fwd_ms"] = timed(jax.jit(attend), *v)
         out["mla_fwd_bwd_ms"] = timed(both(attend), v[2], *v)
+    if args.rows:
+        out.update(time_rows(args, interpret, timed))
     print("kda: " + json.dumps(out), flush=True)
+
+
+def time_rows(args, interpret, timed):
+    """-> the `rows_*` keys of the line: `kda_rows.py`'s two entries at
+    (batch, seq, 32 x 128) bfloat16, kernels and XLA form."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.ops.pallas import kda_rows as R
+
+    heads, d, bf16, f32 = 32, 128, jnp.bfloat16, jnp.float32
+    rng = np.random.default_rng(6)
+    n, width = args.batch * args.seq, heads * d
+
+    def table(dtype, *shape):
+        return jnp.asarray(rng.standard_normal((args.batch, args.seq)
+                                               + shape), dtype)
+
+    ins = ([table(bf16, width) for _ in range(3)]
+           + [table(f32, width), table(bf16, heads),
+              jnp.asarray(np.log(rng.uniform(1, 16, heads)), bf16),
+              jnp.asarray(0.3 * rng.standard_normal(width), bf16)])
+    gated = [table(bf16, width), table(bf16, heads),
+             jnp.asarray(1 + 0.1 * rng.standard_normal(d), bf16)]
+    wide, wide32, logits = 2 * n * width, 4 * n * width, 2 * n * heads
+    moved = {   # bytes in + out, every table once
+        "inputs_fwd": 3 * wide + wide32 + logits + 4 * wide + wide32,
+        "inputs_bwd": (7 * wide + 2 * wide32 + logits + 3 * wide + wide32
+                       + 2 * logits),
+        "gated_norm_fwd": wide + logits + wide,
+        "gated_norm_bwd": 2 * wide + logits + wide + 2 * logits}
+    kw = dict(interpret=interpret or None)
+    entries = {
+        "inputs": (lambda *v: R.kda_inputs(*v, lower_bound=-5.0, **kw),
+                   lambda *v: R.kda_inputs_xla(*v, lower_bound=-5.0), ins),
+        "gated_norm": (lambda *v: R.kda_gated_norm(*v, eps=1e-6, **kw),
+                       lambda *v: R.kda_gated_norm_xla(*v, eps=1e-6), gated)}
+
+    def through_vjp(f):
+        def run(*v):
+            o, pull = jax.vjp(f, *v)
+            return pull(o)
+        return jax.jit(run)
+
+    out = {}
+    for name, (_, xla, v) in entries.items():
+        out[f"rows_{name}_fwd_ms.xla"] = timed(jax.jit(xla), *v)
+        out[f"rows_{name}_fwd_bwd_ms.xla"] = timed(through_vjp(xla), *v)
+    for rows in [int(x) for x in args.rows.split(",")]:
+        R.ROWS = rows
+        jax.clear_caches()          # the four wrappers are jitted
+        scale, bias = R._channel_rows(ins[5], ins[6], d)
+        made = R.kda_inputs_fwd(*ins[:5], scale, bias, -5.0, interpret)
+        alone = {
+            "inputs_fwd": (lambda *v: R.kda_inputs_fwd(
+                *v, -5.0, interpret), (*ins[:5], scale, bias)),
+            "inputs_bwd": (lambda *v: R.kda_inputs_bwd(
+                v[:5], *v[5:], -5.0, interpret),
+                (*made, *ins[:5], scale, bias)),
+            "gated_norm_fwd": (lambda *v: R.kda_gated_norm_fwd(
+                *v, 1e-6, interpret), gated),
+            "gated_norm_bwd": (lambda *v: R.kda_gated_norm_bwd(
+                *v, 1e-6, interpret), (gated[0], *gated))}
+        for name, (f, v) in alone.items():
+            ms = timed(jax.jit(f), *v)
+            out[f"rows_{name}_ms.{rows}"] = ms
+            out[f"rows_{name}_gb_s.{rows}"] = moved[name] / ms / 1e6
+        for name, (kernel, _, v) in entries.items():
+            out[f"rows_{name}_fwd_bwd_ms.{rows}"] = timed(
+                through_vjp(kernel), *v)
+    out["rows_bytes"] = moved
+    out["rows_peak_gb_s"] = 819
+    return out
 
 
 if __name__ == "__main__":
