@@ -48,11 +48,11 @@ whole. The clamped SwiGLU of the published layers 35..41
 Initialisation: matrices normal(0, `initializer_range`), the convolutions
 uniform(+-taps^-1/2), A_log the logarithm of uniform(1, 16) and dt_bias the
 inverse softplus of a step log-uniform in [0.001, 0.1], as
-flash-linear-attention's KDA layer draws them.
+flash-linear-attention's KDA layer draws them. The mixture's wiring, the
+stack and the causal LM are `decoder_parts.py`'s.
 """
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass
 
 import jax
@@ -60,13 +60,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import nn
-from ..framework.autograd import op_scope
-from ..framework.tensor import Tensor
-from ..incubate.distributed.models.moe.dropless import DroplessMoE
 from ..ops._dispatch import nary
-from .keye_vl2 import _rms, routing_totals
+from .decoder_parts import (DecoderStack, MixtureCausalLM, causal_conv,
+                            dropless_experts, mixture, recomputed, rms,
+                            state_space_leaves, swiglu)
 from .llama import LlamaRMSNorm, _rope_tables, apply_rotary_pos_emb
-from .nemotron_h import NemotronHForCausalLM, causal_conv
 
 __all__ = ["Ling3Config", "Ling3Model", "Ling3ForCausalLM"]
 
@@ -220,21 +218,14 @@ class Ling3MLP(nn.Layer):
 class Ling3Mixture(nn.Layer):
     def __init__(self, c: Ling3Config):
         super().__init__()
-        self.experts = DroplessMoE(
-            c.hidden_size, c.moe_intermediate_size, c.num_experts,
-            c.num_experts_per_tok, held_experts=c.held_experts,
-            renormalise=c.norm_topk_prob,
-            balance_coef=c.router_aux_loss_coef, tile_rows=c.moe_tile_rows,
-            gated=True, score="sigmoid", gate_scale=c.routed_scaling_factor,
-            n_group=c.n_group, topk_group=c.topk_group)
+        self.experts = dropless_experts(
+            c, gated=True, score="sigmoid",
+            gate_scale=c.routed_scaling_factor, n_group=c.n_group,
+            topk_group=c.topk_group)
         width = c.moe_shared_expert_intermediate_size
         self.shared_gate = nn.Linear(c.hidden_size, width, bias_attr=False)
         self.shared_up = nn.Linear(c.hidden_size, width, bias_attr=False)
         self.shared_down = nn.Linear(width, c.hidden_size, bias_attr=False)
-
-
-def _swiglu(h, gate, up, down):
-    return (jax.nn.silu(h @ gate) * (h @ up)) @ down
 
 
 class Ling3Layer(nn.Layer):
@@ -261,7 +252,7 @@ class Ling3Layer(nn.Layer):
             # what works on one head's channels runs on column blocks of
             # them (ops/pallas/kda_rows.py)
             with jax.named_scope("kda/project"):
-                h = _rms(x, ln, c.rms_norm_eps)
+                h = rms(x, ln, c.rms_norm_eps)
                 q, k, v, f = h @ wq, h @ wk, h @ wv, _product_f32(h, wf)
                 beta, gate = h @ wb, h @ wg
             with jax.named_scope("kda/conv"):
@@ -294,15 +285,15 @@ class Ling3Layer(nn.Layer):
                                        c.qk_rope_head_dim, c.kv_lora_rank)
             eps = c.rms_norm_eps
             with jax.named_scope("mla/project"):
-                h = _rms(x, ln, eps)
+                h = rms(x, ln, eps)
                 q = (h @ wq).reshape(b, s, heads, nope + rope)
                 kva = h @ wkva
-                kvb = (_rms(kva[..., :rank], gc, eps) @ wkvb).reshape(
+                kvb = (rms(kva[..., :rank], gc, eps) @ wkvb).reshape(
                     b, s, heads, nope + c.v_head_dim)
                 k = jnp.concatenate([kvb[..., :nope], jnp.broadcast_to(
                     kva[:, :, None, rank:], (b, s, heads, rope))], -1)
                 v = kvb[..., nope:]
-                q, k = _rms(q, gq, eps), _rms(k, gk, eps)
+                q, k = rms(q, gq, eps), rms(k, gk, eps)
                 q = jnp.concatenate([q[..., :nope], _turn(q[..., nope:],
                                                           c.rope_theta)], -1)
                 k = jnp.concatenate([k[..., :nope], _turn(k[..., nope:],
@@ -323,7 +314,7 @@ class Ling3Layer(nn.Layer):
 
         def run(x, ln, gate, up, down):
             with jax.named_scope("mlp"):
-                return x + _swiglu(_rms(x, ln, c.rms_norm_eps), gate, up,
+                return x + swiglu(rms(x, ln, c.rms_norm_eps), gate, up,
                                    down)
 
         return nary(run, [x, self.post_norm.weight, m.gate_proj.weight,
@@ -331,18 +322,9 @@ class Ling3Layer(nn.Layer):
 
     def _mixture(self, x):
         m = self.ffn
-        with op_scope("moe/norm"):
-            h = self.post_norm(x)
-        y, balance, stats, picks = m.experts(h)
-
-        def shared(h, gate, up, down):
-            with jax.named_scope("moe/shared"):
-                return _swiglu(h, gate, up, down)
-
-        y_shared = nary(shared, [h, m.shared_gate.weight, m.shared_up.weight,
-                                 m.shared_down.weight], "shared_expert")
-        with op_scope("moe/residual"):
-            return x + y + y_shared, balance, stats, picks
+        return mixture(x, self.post_norm, m.experts, swiglu,
+                       [m.shared_gate.weight, m.shared_up.weight,
+                        m.shared_down.weight])
 
     def _whole(self, x):
         x = (self._kda if self.kind == KDA else self._mla)(x)
@@ -352,133 +334,46 @@ class Ling3Layer(nn.Layer):
         """-> x for a dense layer; for a mixture layer (x, balance term, the
         mixture's stats float32 [3], the experts picked int32 [b * s, k])
         (`dropless_moe`)."""
-        if self.config.use_recompute and self.training:
-            from ..distributed.fleet import recompute
-
-            # one segment a layer. A KDA mixer a sequence at a time (as
-            # nemotron_h.py's Mamba layers run) holds no less here, 5.78
-            # against 5.83 GiB of temporaries compiled for a described v5e,
-            # and its twelve more segments compile a third longer
-            return recompute(self._whole, x)
-        return self._whole(x)
+        # one segment a layer. A KDA mixer a sequence at a time (as
+        # nemotron_h.py's Mamba layers run) holds no less here, 5.78
+        # against 5.83 GiB of temporaries compiled for a described v5e,
+        # and its twelve more segments compile a third longer
+        return recomputed(self, self._whole, x)
 
 
-class Ling3Model(nn.Layer):
-    def __init__(self, config: Ling3Config):
-        super().__init__()
-        self.config = config
-        self.embed_tokens = nn.Embedding(config.vocab_size,
-                                         config.hidden_size)
-        self.layers = nn.LayerList([Ling3Layer(config, i) for i in range(
-            config.num_hidden_layers)])
-        self.norm = LlamaRMSNorm(config.hidden_size, config.rms_norm_eps)
-        self._init_weights(config)
-
-    def _init_weights(self, c):
-        from ..framework.random import host_normal, host_rng
-        from ..nn.initializer import get_global_initializer
-
-        if get_global_initializer() is not None:
-            return      # the caller's initializer overrides the model's own
-        rng = host_rng() or np.random.default_rng(0)
-        for name, p in self.named_parameters():
-            shape = tuple(p._data.shape)
-            if name.endswith("_conv"):
-                bound = c.short_conv_kernel_size ** -0.5
-                p._data = jnp.asarray(rng.uniform(-bound, bound, shape), F32)
-            elif p.ndim >= 2:
-                p._data = host_normal(shape, c.initializer_range)
-            elif name.endswith("A_log"):
-                p._data = jnp.asarray(np.log(rng.uniform(1, 16, shape)), F32)
-            elif name.endswith("dt_bias"):
-                dt = np.exp(rng.uniform(math.log(1e-3), math.log(1e-1),
-                                        shape))
-                p._data = jnp.asarray(dt + np.log(-np.expm1(-dt)), F32)
-
-    def forward(self, input_ids):
-        """-> (hidden [b, s, h], per mixture layer: [balance terms],
-        [stats], [picked experts])."""
-        with op_scope("embed"):
-            x = self.embed_tokens(input_ids)
-        balance, stats, picks = [], [], []
-        for layer in self.layers:
-            if layer.dense:
-                x = layer(x)
-            else:
-                x, bal, st, picked = layer(x)
-                balance.append(bal)
-                stats.append(st)
-                picks.append(picked)
-        with op_scope("head"):
-            return self.norm(x), balance, stats, picks
+class Ling3Model(DecoderStack):
+    def __init__(self, c: Ling3Config):
+        layers = range(c.num_hidden_layers)
+        super().__init__(c, c.rms_norm_eps,
+                         (Ling3Layer(c, i) for i in layers),
+                         mixes=[i >= c.first_k_dense_replace for i in layers],
+                         special=state_space_leaves(
+                             "_conv", c.short_conv_kernel_size, 1e-3, 1e-1))
 
 
-class Ling3ForCausalLM(nn.Layer):
-    """The language model with its untied head [vocab, hidden].
-
-    `loss(ids, labels)` is the training loss (module docstring);
-    `routing_counters()` reads what the last step's routing counted; after
-    `record_picks(batch, seq)` the steps also keep WHICH experts they picked
-    (`picks()`)."""
+class Ling3ForCausalLM(MixtureCausalLM):
+    """The language model with its untied head [vocab, hidden], its
+    counters and picks a mixture layer (`decoder_parts.MixtureCausalLM`),
+    which also counts the tokens with a pick in the held experts' group:
+    `loss(ids, labels)` is the module docstring's training loss."""
 
     def __init__(self, config: Ling3Config):
-        super().__init__()
-        from ..framework.random import host_normal
-        from ..nn.initializer import get_global_initializer
+        super().__init__(config, Ling3Model(config), counters=4)
 
-        self.config = config
-        self.model = Ling3Model(config)
-        self.lm_head = self.create_parameter(
-            [config.vocab_size, config.hidden_size])
-        if get_global_initializer() is None:
-            self.lm_head._data = host_normal(self.lm_head._data.shape,
-                                             config.initializer_range)
-        self.mixtures = sum(not layer.dense for layer in self.model.layers)
-        # per mixture layer: pairs routed to held experts, rows computed,
-        # the fullest held expert's pairs, tokens with a pick in the held
-        # experts' group: the last step's
-        self.register_buffer("routing", Tensor._wrap(
-            jnp.zeros((max(self.mixtures, 1), 4), jnp.int32)))
-
-    record_picks = NemotronHForCausalLM.record_picks
-    picks = NemotronHForCausalLM.picks
-    forward = NemotronHForCausalLM.forward
-    loss = NemotronHForCausalLM.loss
-
-    def _group_hits(self, picks):
-        """Tokens with at least one pick in the group of experts that the
-        held ones lie in, int32 [1]."""
-        c = self.config
+    def counter_row(self, stats, picks):
+        """The mixture's three counters, then the tokens with at least one
+        pick in the group of experts that the held ones lie in."""
+        c, row = self.config, super().counter_row(stats, picks)
         size = c.num_experts // c.n_group
         mine = (c.held_experts or (0, c.num_experts))[0] // size
-        return jnp.sum(jnp.any(picks // size == mine, axis=-1),
-                       dtype=jnp.int32)[None]
-
-    def loss_terms(self, input_ids, labels):
-        """-> (language-model loss, mean balance term)."""
-        from .gpt import fused_lm_loss
-
-        hidden, balance, stats, picks = self.model(input_ids)
-        with jax.named_scope("picks"):
-            if stats:
-                self.routing._data = jnp.stack([jnp.concatenate(
-                    [s._data.astype(jnp.int32), self._group_hits(e._data)])
-                    for s, e in zip(stats, picks)])
-            if "expert_picks" in self._buffers and picks:
-                self.expert_picks._data = jnp.stack(
-                    [e._data for e in picks])
-        with op_scope("head"):
-            lm = fused_lm_loss(hidden, self.lm_head, True, labels)
-        if not balance:
-            return lm, lm * 0.0
-        return lm, sum(balance[1:], balance[0]) / float(len(balance))
+        hits = jnp.sum(jnp.any(picks._data // size == mine, axis=-1),
+                       dtype=jnp.int32)
+        return jnp.concatenate([row, hits[None]])
 
     def routing_counters(self) -> dict:
-        """Totals over the mixture layers of the last step (`routed_pairs`,
-        `computed_rows`, `max_load_over_mean`: keye_vl2 `routing_totals`)
+        """The mixture's totals over the mixture layers of the last step
         and `group_hit_tokens`: tokens a layer with at least one pick in
         the held experts' group, summed over the layers (a kept group
         nearly always holds a pick: half the tokens at 4 groups of 8)."""
-        rows = np.asarray(self.routing._data, np.int64)
-        return dict(routing_totals(rows, self.config),
-                    group_hit_tokens=int(rows[:, 3].sum()))
+        hits = np.asarray(self.routing._data, np.int64)[:, 3].sum()
+        return dict(super().routing_counters(), group_hit_tokens=int(hits))

@@ -15,8 +15,9 @@ Layer l of kind `layer_types[l]`, x [b, s, hidden], positions int [b, s]:
 
   loss = CE(head(RMSNorm(x_L))) + mean_l balance_l
 
-Built from what `keye_vl2.py` is built from: its attention projections
-with per-head q/k norm, `DroplessMoE(held_experts=)` and its counters.
+Built from `decoder_parts.py`: the attention projections with per-head
+q/k norm, the mixture wiring round `DroplessMoE(held_experts=)`, the stack
+and the causal LM with its counters.
 `held_experts=(lo, hi)` builds the layer's share of an expert-parallel
 deployment: the weights of experts lo..hi-1 only, the router whole.
 """
@@ -30,12 +31,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import nn
-from ..framework.autograd import op_scope
 from ..framework.tensor import Tensor
-from ..incubate.distributed.models.moe.dropless import DroplessMoE
 from ..ops._dispatch import nary
-from .keye_vl2 import (KeyeAttention, KeyeVL2Model, _mixture, _queries_keys,
-                       _rms, routing_totals)
+from .decoder_parts import (DecoderStack, GQAProjections, MixtureCausalLM,
+                            dropless_experts, mixture, queries_keys,
+                            recomputed, rms)
 from .llama import LlamaRMSNorm
 
 __all__ = ["Mellum2Config", "Mellum2Model", "Mellum2ForCausalLM",
@@ -124,15 +124,10 @@ class Mellum2DecoderLayer(nn.Layer):
         super().__init__()
         self.config, self.kind = c, kind
         self.input_layernorm = LlamaRMSNorm(c.hidden_size, c.rms_norm_eps)
-        self.self_attn = KeyeAttention(c)
+        self.self_attn = GQAProjections(c, qk_norm_eps=c.rms_norm_eps)
         self.post_attention_layernorm = LlamaRMSNorm(c.hidden_size,
                                                      c.rms_norm_eps)
-        self.mlp = DroplessMoE(
-            c.hidden_size, c.moe_intermediate_size, c.num_experts,
-            c.num_experts_per_tok, held_experts=c.held_experts,
-            renormalise=c.norm_topk_prob,
-            balance_coef=c.router_aux_loss_coef,
-            tile_rows=c.moe_tile_rows)
+        self.mlp = dropless_experts(c)
 
     def _attend(self, x, positions):
         c, a, kind = self.config, self.self_attn, self.kind
@@ -144,9 +139,9 @@ class Mellum2DecoderLayer(nn.Layer):
 
             b, s, _ = x.shape
             with jax.named_scope("attention/projections"):
-                h = _rms(x, ln, c.rms_norm_eps)
+                h = rms(x, ln, c.rms_norm_eps)
                 cos, sin = rotary_table(c, kind, positions)
-                q, k = _queries_keys(c, h, wq, wk, qn, kn, cos, sin)
+                q, k = queries_keys(c, h, wq, wk, qn, kn, cos, sin)
                 v = (h @ wv).reshape(b, s, c.num_key_value_heads,
                                      c.head_dim)
             with jax.named_scope(scope):
@@ -160,29 +155,24 @@ class Mellum2DecoderLayer(nn.Layer):
                     "mellum2_attention")
 
     def _whole(self, x, positions):
-        return _mixture(self, self._attend(x, positions))
+        return mixture(self._attend(x, positions),
+                       self.post_attention_layernorm, self.mlp)
 
     def forward(self, x, positions):
         """-> (x, balance term, the mixture's stats float32 [3] (pairs
         routed to held experts, rows the grouped product computed, the
         fullest held expert's pairs), the experts picked int32 [b * s, k])."""
-        if self.config.use_recompute and self.training:
-            from ..distributed.fleet import recompute
-
-            return recompute(self._whole, x, positions)
-        return self._whole(x, positions)
+        return recomputed(self, self._whole, x, positions)
 
 
-class Mellum2Model(nn.Layer):
+class Mellum2Model(DecoderStack):
     def __init__(self, config: Mellum2Config):
-        super().__init__()
-        self.config = config
-        self.embed_tokens = nn.Embedding(config.vocab_size,
-                                         config.hidden_size)
-        self.layers = nn.LayerList([Mellum2DecoderLayer(config, kind)
-                                    for kind in config.layer_types])
-        self.norm = LlamaRMSNorm(config.hidden_size, config.rms_norm_eps)
-        KeyeVL2Model._init_weights(self, config)
+        super().__init__(config, config.rms_norm_eps,
+                         (Mellum2DecoderLayer(config, kind)
+                          for kind in config.layer_types),
+                         mixes=(True,) * config.num_layers,
+                         scaled=("o_proj.weight", "mlp.down_proj"),
+                         factor=math.sqrt(2.0 * config.num_layers))
 
     def forward(self, input_ids, position_ids=None):
         """-> (hidden [b, s, h], [per-layer balance terms], [per-layer
@@ -191,82 +181,13 @@ class Mellum2Model(nn.Layer):
             b, s = input_ids.shape
             position_ids = Tensor._wrap(jnp.broadcast_to(
                 jnp.arange(s, dtype=jnp.int32), (b, s)))
-        with op_scope("embed"):
-            x = self.embed_tokens(input_ids)
-        balance, stats, picks = [], [], []
-        for layer in self.layers:
-            x, bal, st, picked = layer(x, position_ids)
-            balance.append(bal)
-            stats.append(st)
-            picks.append(picked)
-        with op_scope("head"):
-            return self.norm(x), balance, stats, picks
+        return super().forward(input_ids, position_ids)
 
 
-class Mellum2ForCausalLM(nn.Layer):
-    """The language model with its untied head [vocab, hidden].
-
-    `loss(ids, labels, position_ids=None)` is the training loss (module
-    docstring); `routing_counters()` reads what the last step's routing
-    counted; after `record_picks(batch, seq)` the steps also keep WHICH
-    experts they picked (`picks()`)."""
+class Mellum2ForCausalLM(MixtureCausalLM):
+    """The language model with its untied head [vocab, hidden], its
+    counters and picks (`decoder_parts.MixtureCausalLM`): `loss(ids,
+    labels, position_ids=None)` is the module docstring's training loss."""
 
     def __init__(self, config: Mellum2Config):
-        super().__init__()
-        from ..framework.random import host_normal
-        from ..nn.initializer import get_global_initializer
-
-        self.config = config
-        self.model = Mellum2Model(config)
-        self.lm_head = self.create_parameter(
-            [config.vocab_size, config.hidden_size])
-        if get_global_initializer() is None:
-            self.lm_head._data = host_normal(self.lm_head._data.shape,
-                                             config.initializer_range)
-        # per layer: pairs routed to held experts, rows computed, the
-        # fullest held expert's pairs; the last step's
-        self.register_buffer("routing", Tensor._wrap(
-            jnp.zeros((config.num_layers, 3), jnp.int32)))
-
-    def record_picks(self, batch, seq):
-        """Keep every step's expert picks in one more buffer of the
-        model, int32 [layers, batch * seq, top_k]. Changes nothing of
-        what a step computes."""
-        c = self.config
-        self.register_buffer("expert_picks", Tensor._wrap(jnp.zeros(
-            (c.num_layers, batch * seq, c.num_experts_per_tok), jnp.int32)))
-
-    def picks(self):
-        """-> experts int32 [layers, batch * seq, top_k] of the last step."""
-        return np.asarray(self.expert_picks._data)
-
-    def forward(self, input_ids, position_ids=None):
-        from .. import ops
-
-        hidden = self.model(input_ids, position_ids)[0]
-        return ops.matmul(hidden, self.lm_head, transpose_y=True)
-
-    def loss_terms(self, input_ids, labels, position_ids=None):
-        """-> (language-model loss, mean balance term)."""
-        from .gpt import fused_lm_loss
-
-        hidden, balance, stats, picks = self.model(input_ids, position_ids)
-        with jax.named_scope("picks"):
-            self.routing._data = jnp.stack(
-                [s._data.astype(jnp.int32) for s in stats])
-            if "expert_picks" in self._buffers:
-                self.expert_picks._data = jnp.stack(
-                    [e._data for e in picks])
-        with op_scope("head"):
-            lm = fused_lm_loss(hidden, self.lm_head, True, labels)
-        return lm, sum(balance[1:], balance[0]) / float(len(balance))
-
-    def loss(self, input_ids, labels, position_ids=None):
-        lm, balance = self.loss_terms(input_ids, labels, position_ids)
-        return lm + balance
-
-    def routing_counters(self) -> dict:
-        """Totals over the layers of the last step: `routed_pairs`,
-        `computed_rows`, `max_load_over_mean` (keye_vl2 `routing_totals`)."""
-        return routing_totals(np.asarray(self.routing._data, np.int64),
-                              self.config)
+        super().__init__(config, Mellum2Model(config))

@@ -41,7 +41,8 @@ matrices normal(0, `initializer_range`), and out_proj, o_proj and the
 experts' down products divided by sqrt(num_layers)
 (`rescale_prenorm_residual`). No parameter is exempt from the optimizer's
 weight decay here (`mamba_ssm` marks A_log, D and dt_bias `_no_weight_decay`;
-a caller that wants that passes AdamW's `apply_decay_param_fun`).
+a caller that wants that passes AdamW's `apply_decay_param_fun`). The
+mixture's wiring, the stack and the causal LM are `decoder_parts.py`'s.
 """
 from __future__ import annotations
 
@@ -50,16 +51,14 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from .. import nn
 from ..framework.autograd import op_scope
-from ..framework.tensor import Tensor
-from ..incubate.distributed.models.moe.dropless import DroplessMoE
 from ..ops._dispatch import nary
-from .keye_vl2 import _rms, routing_totals
+from .decoder_parts import (DecoderStack, GQAProjections, MixtureCausalLM,
+                            causal_conv, dropless_experts, mixture,
+                            recomputed, recomputes, rms, state_space_leaves)
 from .llama import LlamaRMSNorm
-from .mellum2 import Mellum2ForCausalLM
 
 __all__ = ["NemotronHConfig", "NemotronHModel", "NemotronHForCausalLM"]
 
@@ -113,7 +112,7 @@ class NemotronHConfig:
                 f"does not name a kind for each of {self.num_layers} layers")
 
     @property
-    def num_experts(self):          # the name `routing_totals` reads
+    def num_experts(self):          # the name `decoder_parts.py` reads
         return self.n_routed_experts
 
     @property
@@ -123,17 +122,6 @@ class NemotronHConfig:
     @property
     def conv_dim(self):
         return self.mamba_inner + 2 * self.n_groups * self.ssm_state_size
-
-
-def causal_conv(x, w, b=None):
-    """Depthwise causal convolution over the sequence and silu: x [b, s, c],
-    w [taps, c] (tap `taps - 1` weighs the step itself), b [c] or None."""
-    taps, s = w.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    bias = None if b is None else b.astype(F32)
-    out = sum(padded[:, k:k + s].astype(F32) * w[k].astype(F32)
-              for k in range(taps))
-    return jax.nn.silu(out if b is None else bias + out).astype(x.dtype)
 
 
 def gated_group_norm(y, z, w, groups, eps):
@@ -167,35 +155,21 @@ class Mamba2Mixer(nn.Layer):
                 self.out_proj.weight]
 
 
-class NemotronHAttention(nn.Layer):
-    def __init__(self, c: NemotronHConfig):
-        super().__init__()
-        h, d = c.hidden_size, c.head_dim
-        self.q_proj = nn.Linear(h, c.num_attention_heads * d,
-                                bias_attr=False)
-        self.k_proj = nn.Linear(h, c.num_key_value_heads * d,
-                                bias_attr=False)
-        self.v_proj = nn.Linear(h, c.num_key_value_heads * d,
-                                bias_attr=False)
-        self.o_proj = nn.Linear(c.num_attention_heads * d, h,
-                                bias_attr=False)
-
-
 class NemotronHMixture(nn.Layer):
     def __init__(self, c: NemotronHConfig):
         super().__init__()
-        self.experts = DroplessMoE(
-            c.hidden_size, c.moe_intermediate_size, c.n_routed_experts,
-            c.num_experts_per_tok, held_experts=c.held_experts,
-            renormalise=c.norm_topk_prob,
-            balance_coef=c.router_aux_loss_coef, tile_rows=c.moe_tile_rows,
-            gated=False, score="sigmoid",
+        self.experts = dropless_experts(
+            c, gated=False, score="sigmoid",
             gate_scale=c.routed_scaling_factor)
         self.shared_up = nn.Linear(c.hidden_size,
                                    c.moe_shared_expert_intermediate_size,
                                    bias_attr=False)
         self.shared_down = nn.Linear(c.moe_shared_expert_intermediate_size,
                                      c.hidden_size, bias_attr=False)
+
+
+def _relu2_expert(h, up, down):
+    return jnp.square(jnp.maximum(h @ up, 0)) @ down
 
 
 class NemotronHLayer(nn.Layer):
@@ -205,7 +179,7 @@ class NemotronHLayer(nn.Layer):
         super().__init__()
         self.config, self.kind = c, kind
         self.norm = LlamaRMSNorm(c.hidden_size, c.layer_norm_epsilon)
-        self.mixer = {MAMBA: Mamba2Mixer, ATTENTION: NemotronHAttention,
+        self.mixer = {MAMBA: Mamba2Mixer, ATTENTION: GQAProjections,
                       MIXTURE: NemotronHMixture}[kind](c)
 
     def _mamba(self, x):
@@ -217,7 +191,7 @@ class NemotronHLayer(nn.Layer):
             b, s, _ = x.shape
             inner, gn_ = c.mamba_inner, c.n_groups * c.ssm_state_size
             with jax.named_scope("ssm/project"):
-                h = _rms(x, ln, c.layer_norm_epsilon) @ w_in
+                h = rms(x, ln, c.layer_norm_epsilon) @ w_in
                 z, xbc, dt = (h[..., :inner],
                               h[..., inner:inner + c.conv_dim],
                               h[..., inner + c.conv_dim:])
@@ -251,7 +225,7 @@ class NemotronHLayer(nn.Layer):
 
             b, s, _ = x.shape
             with jax.named_scope("attention/projections"):
-                h = _rms(x, ln, c.layer_norm_epsilon)
+                h = rms(x, ln, c.layer_norm_epsilon)
                 q = (h @ wq).reshape(b, s, c.num_attention_heads, c.head_dim)
                 k = (h @ wk).reshape(b, s, c.num_key_value_heads, c.head_dim)
                 v = (h @ wv).reshape(b, s, c.num_key_value_heads, c.head_dim)
@@ -266,19 +240,8 @@ class NemotronHLayer(nn.Layer):
 
     def _mixture(self, x):
         m = self.mixer
-        with op_scope("moe/norm"):
-            h = self.norm(x)
-        y, balance, stats, picks = m.experts(h)
-
-        def shared(h, up, down):
-            with jax.named_scope("moe/shared"):
-                a = jnp.square(jnp.maximum(h @ up, 0))
-                return a @ down
-
-        y_shared = nary(shared, [h, m.shared_up.weight, m.shared_down.weight],
-                        "shared_expert")
-        with op_scope("moe/residual"):
-            return x + y + y_shared, balance, stats, picks
+        return mixture(x, self.norm, m.experts, _relu2_expert,
+                       [m.shared_up.weight, m.shared_down.weight])
 
     def forward(self, x):
         """-> x for an `M` or `*` layer; for an `E` layer (x, balance
@@ -286,146 +249,40 @@ class NemotronHLayer(nn.Layer):
         [b * s, k]) (`dropless_moe`)."""
         whole = {MAMBA: self._mamba, ATTENTION: self._attention,
                  MIXTURE: self._mixture}[self.kind]
-        if self.config.use_recompute and self.training:
+        if self.kind == MAMBA and x.shape[0] > 1 and recomputes(self):
             from .. import ops
-            from ..distributed.fleet import recompute
 
-            if self.kind == MAMBA and x.shape[0] > 1:
-                # a sequence at a time, each its own segment: one layer's
-                # backward at 4 x 8,192 tokens holds 6.4 GiB of
-                # temporaries whole and 3.2 this way (one segment that
-                # loops over the sequences with a checkpointed body
-                # compiles 9 % sooner and holds 1.0 GiB more)
-                with op_scope("ssm/project"):
-                    parts = ops.split(x, x.shape[0], axis=0)
-                parts = [recompute(whole, part) for part in parts]
-                with op_scope("ssm/out"):
-                    return ops.concat(parts, axis=0)
-            return recompute(whole, x)
-        return whole(x)
+            # a sequence at a time, each its own segment: one layer's
+            # backward at 4 x 8,192 tokens holds 6.4 GiB of temporaries
+            # whole and 3.2 this way (one segment that loops over the
+            # sequences with a checkpointed body compiles 9 % sooner and
+            # holds 1.0 GiB more)
+            with op_scope("ssm/project"):
+                parts = ops.split(x, x.shape[0], axis=0)
+            parts = [recomputed(self, whole, part) for part in parts]
+            with op_scope("ssm/out"):
+                return ops.concat(parts, axis=0)
+        return recomputed(self, whole, x)
 
 
-class NemotronHModel(nn.Layer):
-    def __init__(self, config: NemotronHConfig):
-        super().__init__()
-        self.config = config
-        self.embed_tokens = nn.Embedding(config.vocab_size,
-                                         config.hidden_size)
-        self.layers = nn.LayerList([NemotronHLayer(config, kind)
-                                    for kind in config.kinds])
-        self.norm = LlamaRMSNorm(config.hidden_size,
-                                 config.layer_norm_epsilon)
-        self._init_weights(config)
-
-    def _init_weights(self, c):
-        from ..framework.random import host_normal, host_rng
-        from ..nn.initializer import get_global_initializer
-
-        if get_global_initializer() is not None:
-            return      # the caller's initializer overrides the model's own
-        rng = host_rng() or np.random.default_rng(0)
-        for name, p in self.named_parameters():
-            shape = tuple(p._data.shape)
-            if p.ndim >= 2 and not name.endswith("conv_weight"):
-                p._data = host_normal(shape, c.initializer_range)
-                if name.endswith(("out_proj.weight", "o_proj.weight",
-                                  "down_proj", "shared_down.weight")):
-                    p._data = p._data / math.sqrt(c.num_layers)
-            elif name.endswith(("conv_weight", "conv_bias")):
-                bound = c.conv_kernel ** -0.5
-                p._data = jnp.asarray(rng.uniform(-bound, bound, shape), F32)
-            elif name.endswith("A_log"):
-                p._data = jnp.asarray(np.log(rng.uniform(1, 16, shape)), F32)
-            elif name.endswith("dt_bias"):
-                dt = np.maximum(np.exp(rng.uniform(
-                    math.log(c.time_step_min), math.log(c.time_step_max),
-                    shape)), c.time_step_floor)
-                p._data = jnp.asarray(dt + np.log(-np.expm1(-dt)), F32)
-
-    def forward(self, input_ids):
-        """-> (hidden [b, s, h], per `E` layer: [balance terms], [stats],
-        [picked experts])."""
-        with op_scope("embed"):
-            x = self.embed_tokens(input_ids)
-        balance, stats, picks = [], [], []
-        for layer in self.layers:
-            if layer.kind == MIXTURE:
-                x, bal, st, picked = layer(x)
-                balance.append(bal)
-                stats.append(st)
-                picks.append(picked)
-            else:
-                x = layer(x)
-        with op_scope("head"):
-            return self.norm(x), balance, stats, picks
+class NemotronHModel(DecoderStack):
+    def __init__(self, c: NemotronHConfig):
+        super().__init__(c, c.layer_norm_epsilon,
+                         (NemotronHLayer(c, kind) for kind in c.kinds),
+                         mixes=[kind == MIXTURE for kind in c.kinds],
+                         scaled=("out_proj.weight", "o_proj.weight",
+                                 "down_proj", "shared_down.weight"),
+                         factor=math.sqrt(c.num_layers),
+                         special=state_space_leaves(
+                             ("conv_weight", "conv_bias"), c.conv_kernel,
+                             c.time_step_min, c.time_step_max,
+                             c.time_step_floor))
 
 
-class NemotronHForCausalLM(nn.Layer):
-    """The language model with its untied head [vocab, hidden].
-
-    `loss(ids, labels)` is the training loss (module docstring);
-    `routing_counters()` reads what the last step's routing counted;
-    after `record_picks(batch, seq)` the steps also keep WHICH
-    experts they picked (`picks()`)."""
+class NemotronHForCausalLM(MixtureCausalLM):
+    """The language model with its untied head [vocab, hidden], its
+    counters and picks a mixture layer (`decoder_parts.MixtureCausalLM`):
+    `loss(ids, labels)` is the module docstring's training loss."""
 
     def __init__(self, config: NemotronHConfig):
-        super().__init__()
-        from ..framework.random import host_normal
-        from ..nn.initializer import get_global_initializer
-
-        self.config = config
-        self.model = NemotronHModel(config)
-        self.lm_head = self.create_parameter(
-            [config.vocab_size, config.hidden_size])
-        if get_global_initializer() is None:
-            self.lm_head._data = host_normal(self.lm_head._data.shape,
-                                             config.initializer_range)
-        self.mixtures = config.kinds.count(MIXTURE)
-        # per mixture layer: pairs routed to held experts, rows computed,
-        # the fullest held expert's pairs: the last step's
-        self.register_buffer("routing", Tensor._wrap(
-            jnp.zeros((max(self.mixtures, 1), 3), jnp.int32)))
-
-    def record_picks(self, batch, seq):
-        """Keep every step's expert picks in one more buffer of the
-        model, int32 [mixture layers, batch * seq, top_k]. Changes nothing
-        of what a step computes."""
-        self.register_buffer("expert_picks", Tensor._wrap(jnp.zeros(
-            (self.mixtures, batch * seq, self.config.num_experts_per_tok),
-            jnp.int32)))
-
-    picks = Mellum2ForCausalLM.picks
-
-    def forward(self, input_ids):
-        from .. import ops
-
-        return ops.matmul(self.model(input_ids)[0], self.lm_head,
-                          transpose_y=True)
-
-    def loss_terms(self, input_ids, labels):
-        """-> (language-model loss, mean balance term)."""
-        from .gpt import fused_lm_loss
-
-        hidden, balance, stats, picks = self.model(input_ids)
-        with jax.named_scope("picks"):
-            if stats:
-                self.routing._data = jnp.stack(
-                    [s._data.astype(jnp.int32) for s in stats])
-            if "expert_picks" in self._buffers and picks:
-                self.expert_picks._data = jnp.stack(
-                    [e._data for e in picks])
-        with op_scope("head"):
-            lm = fused_lm_loss(hidden, self.lm_head, True, labels)
-        if not balance:
-            return lm, lm * 0.0
-        return lm, sum(balance[1:], balance[0]) / float(len(balance))
-
-    def loss(self, input_ids, labels):
-        lm, balance = self.loss_terms(input_ids, labels)
-        return lm + balance
-
-    def routing_counters(self) -> dict:
-        """Totals over the mixture layers of the last step (`routed_pairs`,
-        `computed_rows`, `max_load_over_mean`: keye_vl2 `routing_totals`)."""
-        return routing_totals(np.asarray(self.routing._data, np.int64),
-                              self.config)
+        super().__init__(config, NemotronHModel(config))

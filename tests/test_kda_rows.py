@@ -1,6 +1,6 @@
 """`ops/pallas/kda_rows.py` (a KDA layer's head-wise row work on column blocks
 of flat [b, s, heads 128] rows) against the `jnp` expressions the model held
-before it (`models/ling3.py` `_kda` at PR 42: `_unit`, `_rms`,
+before it (`models/ling3.py` `_kda` at PR 42: `_unit`, `rms`,
 `kda._operands`, on [b, s, heads, 128] tables), written out here: the four
 kernels in interpret mode, values and every cotangent, and the three entries
 chained against that composition through `kda()`."""
@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu  # noqa: F401
-from paddle_tpu.models.keye_vl2 import _rms
+from paddle_tpu.models.decoder_parts import rms
 from paddle_tpu.ops.pallas import kda as K
 from paddle_tpu.ops.pallas import kda_rows as R
 from paddle_tpu.ops.pallas import routing
@@ -60,7 +60,7 @@ def old_gated_norm(heads):
     def f(o, gate_logits, gn):
         b, s, width = o.shape
         o4 = o.reshape(b, s, heads, width // heads)
-        out = _rms(o4, gn, EPS).astype(F32) \
+        out = rms(o4, gn, EPS).astype(F32) \
             * jax.nn.sigmoid(gate_logits.astype(F32))[..., None]
         return out.astype(o.dtype).reshape(b, s, width)
     return f

@@ -62,8 +62,8 @@ def step_text(step, batch):
     return {"mosaic_kernels": dict(routing.mosaic_kernels(text)),
             "xla_fallbacks": {f"{k} {g}": n for (k, g), n
                               in routing.xla_fallbacks.items()},
-            "widest_f32": sorted(wide, key=lambda shape: math.prod(
-                map(int, re.findall(r"\d+", shape[3:]))))[-6:]}
+            "widest_f32": sorted(wide, key=lambda shape: (math.prod(
+                map(int, re.findall(r"\d+", shape[3:]))), shape))[-6:]}
 
 
 def main(argv=None):
