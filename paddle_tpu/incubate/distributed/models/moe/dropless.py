@@ -15,7 +15,7 @@ The layer is told the router's score and the experts' form, two of each:
       when `renormalise`
   score "sigmoid": p = sigmoid(logits); E_t = the top k of p + `bias` (a
       buffer no gradient reaches; it moves the selection only);
-      g = p[E_t] / (sum + 1e-20) when `renormalise`, times `scale`; the
+      g = p[E_t] / (sum + eps) when `renormalise`, times `scale`; the
       balance term reads p / sum_e p. With `n_group` > 1 the picks are
       limited to groups: the experts lie in `n_group` contiguous groups, a
       group's score is the sum of its two largest p + bias, and only the
@@ -93,10 +93,11 @@ I32 = jnp.int32
 
 
 def route_topk(logits, top_k, renormalise=True, score="softmax", bias=None,
-               scale=1.0, n_group=1, topk_group=1):
+               scale=1.0, n_group=1, topk_group=1, eps=1e-20):
     """-> (p [T, E] float32 (what the balance term reads), experts [T, k]
     int32, gates [T, k] float32). `score`, `bias`, `scale`, `n_group`,
-    `topk_group`: module docstring."""
+    `topk_group`: module docstring; `eps` is added to the picked sigmoid
+    scores' sum before the renormalisation divides by it."""
     if score == "softmax":
         p = jax.nn.softmax(logits.astype(F32), axis=-1)
         top, experts = jax.lax.top_k(p, top_k)     # ties: lower index
@@ -119,7 +120,7 @@ def route_topk(logits, top_k, renormalise=True, score="softmax", bias=None,
     experts = jax.lax.top_k(chosen, top_k)[1]      # ties: lower index
     top = jnp.take_along_axis(s, experts, axis=-1)
     if renormalise:
-        top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+        top = top / (jnp.sum(top, axis=-1, keepdims=True) + eps)
     return (s / jnp.sum(s, axis=-1, keepdims=True), experts.astype(I32),
             top * scale)
 
@@ -322,11 +323,11 @@ grouped_ffn.defvjp(_grouped_ffn_fwd, _grouped_ffn_bwd)
 
 def dropless_moe(h, wr, wg, wu, wd, *, top_k, held, tile_rows,
                  renormalise=True, balance_coef=0.0, score="softmax",
-                 bias=None, scale=1.0, n_group=1, topk_group=1):
+                 bias=None, scale=1.0, n_group=1, topk_group=1, eps=1e-20):
     """The layer on arrays: h [T, K] -> (y [T, K] in h's type, the
     load-balancing term, stats float32 [3], the picked experts int32
     [T, top_k]). `wg` None: ungated experts; `score`, `bias`, `scale`,
-    `n_group`, `topk_group`: `route_topk`'s.
+    `n_group`, `topk_group`, `eps`: `route_topk`'s.
 
     stats = (pairs routed to held experts, rows the grouped product
     computed, the held experts' largest load). The
@@ -337,7 +338,7 @@ def dropless_moe(h, wr, wg, wu, wd, *, top_k, held, tile_rows,
     with jax.named_scope("moe/route/router"):
         p, experts, gates = route_topk(
             _dot(h, wr, ((1,), (0,))), top_k, renormalise, score, bias,
-            scale, n_group, topk_group)
+            scale, n_group, topk_group, eps)
         picked = _count(experts, n_experts).astype(F32)
         balance = balance_coef * n_experts * jnp.sum(
             jax.lax.stop_gradient(picked / h.shape[0]) * jnp.mean(p, 0))
@@ -373,6 +374,8 @@ class DroplessMoE(nn.Layer):
         multiplies the gates by `gate_scale`.
       n_group, topk_group: the group limit of the picks (sigmoid scores;
         1, 1: none).
+      renorm_eps: added to the picked sigmoid scores' sum before the
+        renormalisation divides by it.
 
     forward(x [..., d_model]) -> (y, balance term, stats [3], picks int32
     [tokens, top_k]); `stats` is `dropless_moe`'s.
@@ -381,7 +384,7 @@ class DroplessMoE(nn.Layer):
     def __init__(self, d_model, d_expert, num_experts, top_k,
                  held_experts=None, renormalise=True, balance_coef=0.0,
                  tile_rows=512, gated=True, score="softmax",
-                 gate_scale=1.0, n_group=1, topk_group=1):
+                 gate_scale=1.0, n_group=1, topk_group=1, renorm_eps=1e-20):
         super().__init__()
         lo, hi = held_experts or (0, num_experts)
         if not 0 <= lo < hi <= num_experts:
@@ -395,6 +398,7 @@ class DroplessMoE(nn.Layer):
             raise ValueError(f"DroplessMoE: {num_experts} experts in "
                              f"{n_group} groups, {topk_group} kept")
         self.n_group, self.topk_group = int(n_group), int(topk_group)
+        self.renorm_eps = float(renorm_eps)
         held = hi - lo
         self.router = self.create_parameter([d_model, num_experts])
         if score == "sigmoid":
@@ -422,7 +426,8 @@ class DroplessMoE(nn.Layer):
                 renormalise=self.renormalise,
                 balance_coef=self.balance_coef, score=self.score,
                 bias=rest[-1] if biased else None, scale=self.gate_scale,
-                n_group=self.n_group, topk_group=self.topk_group)
+                n_group=self.n_group, topk_group=self.topk_group,
+                eps=self.renorm_eps)
             return y.reshape(shape), balance, stats, picks
 
         ins = [x, self.router, self.gate_proj, self.up_proj, self.down_proj,

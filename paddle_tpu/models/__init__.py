@@ -51,3 +51,8 @@ from .ling3 import (  # noqa: F401
     Ling3Model,
     Ling3ForCausalLM,
 )
+from .lfm2 import (  # noqa: F401
+    Lfm2MoeConfig,
+    Lfm2MoeModel,
+    Lfm2MoeForCausalLM,
+)

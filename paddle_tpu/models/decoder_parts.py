@@ -227,8 +227,10 @@ def _layer_mean(terms, like):
 
 
 class MixtureCausalLM(nn.Layer):
-    """The language model `model` (a `DecoderStack`) with its untied head
-    [vocab, hidden].
+    """The language model `model` (a `DecoderStack`) with its head [vocab,
+    hidden]: a leaf of its own, `lm_head`, or with `tied` the embedding
+    itself (no `lm_head` leaf: the embedding's gradient is then the
+    gather's scatter plus the cross entropy's dW).
 
     `loss(ids, labels, *positions)` is the training loss;
     `routing_counters()` reads what the last step's routing counted, from
@@ -237,15 +239,17 @@ class MixtureCausalLM(nn.Layer):
     pairs, then the model's own); after `record_picks(batch, seq)` the
     steps also keep WHICH experts they picked (`picks()`)."""
 
-    def __init__(self, config, model, counters=3):
+    def __init__(self, config, model, counters=3, tied=False):
         super().__init__()
         self.config = config
         self.model = model
-        self.lm_head = self.create_parameter(
-            [config.vocab_size, config.hidden_size])
-        if get_global_initializer() is None:
-            self.lm_head._data = host_normal(self.lm_head._data.shape,
-                                             config.initializer_range)
+        self.tied = tied
+        if not tied:
+            self.lm_head = self.create_parameter(
+                [config.vocab_size, config.hidden_size])
+            if get_global_initializer() is None:
+                self.lm_head._data = host_normal(self.lm_head._data.shape,
+                                                 config.initializer_range)
         self.mixtures = sum(model.mixes)
         self.register_buffer("routing", Tensor._wrap(
             jnp.zeros((max(self.mixtures, 1), counters), jnp.int32)))
@@ -263,8 +267,13 @@ class MixtureCausalLM(nn.Layer):
         last step."""
         return np.asarray(self.expert_picks._data)
 
+    @property
+    def head(self):
+        """The head's weight [vocab, hidden]."""
+        return self.model.embed_tokens.weight if self.tied else self.lm_head
+
     def forward(self, input_ids, *positions):
-        return ops.matmul(self.model(input_ids, *positions)[0], self.lm_head,
+        return ops.matmul(self.model(input_ids, *positions)[0], self.head,
                           transpose_y=True)
 
     def counter_row(self, stats, picks):
@@ -285,7 +294,7 @@ class MixtureCausalLM(nn.Layer):
             if "expert_picks" in self._buffers and picks:
                 self.keep_picks(picks)
         with op_scope("head"):
-            lm = fused_lm_loss(hidden, self.lm_head, True, labels)
+            lm = fused_lm_loss(hidden, self.head, True, labels)
         return (lm, *(_layer_mean(t, lm) for t in terms))
 
     def loss(self, input_ids, labels, *positions):

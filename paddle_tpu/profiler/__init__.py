@@ -222,6 +222,9 @@ DEVICE_SCOPES = (
                                 # chunked delta rule's two kernels
     "kda/gate_norm",            # the head's RMSNorm and its sigmoid gate
     "kda/out",                  # W_o and the residual
+    "conv/project",             # a gated-convolution layer's input norm, W_in
+    "conv/gate_conv",           # y = C * conv3(B * X), one operator
+    "conv/out",                 # W_out and the residual
     "mla/project",              # latent attention's norm, W_q, W_kva, W_kvb,
                                 # q/k norm, rotary, gate, W_o and residual
     "mla_attention",            # attention at 192 / 128 head widths

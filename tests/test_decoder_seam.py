@@ -1,4 +1,4 @@
-"""The seam between the four mixture models and what they share
+"""The seam between the five mixture models and what they share
 (`paddle_tpu/models/decoder_parts.py`, ISSUE 44): no model's file imports
 another's or builds its classes out of another's, the shared module
 imports none of them, nothing below `models/` imports it, and the names
@@ -14,7 +14,7 @@ import pytest
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
-MODELS = ("keye_vl2", "mellum2", "nemotron_h", "ling3")
+MODELS = ("keye_vl2", "mellum2", "nemotron_h", "ling3", "lfm2")
 SHARED = "decoder_parts"
 
 
@@ -59,7 +59,7 @@ def test_the_shared_module_imports_no_model_file_and_names_no_model():
         tree = ast.parse(f.read())
     tree.body = tree.body[1:]       # the docstring may say who uses it
     code = ast.unparse(tree).lower()
-    for word in ("keye", "mellum", "nemotron", "ling3", "ling-3",
+    for word in ("keye", "mellum", "nemotron", "ling3", "ling-3", "lfm",
                  "isinstance", "hasattr", "model_type"):
         assert word not in code, word
 
@@ -122,6 +122,13 @@ LING3_FFN = {
         "shared_gate.weight", "shared_up.weight", "shared_down.weight"]}
 
 
+LFM2_MIXER = {
+    "conv": ["conv.conv_weight", "conv.in_proj.weight",
+             "conv.out_proj.weight"],
+    "full_attention": [f"self_attn.{n}" for n in GQA + ["q_norm.weight",
+                                                        "k_norm.weight"]]}
+
+
 def _keye():
     import test_keye_vl2 as t
     c = t.config()
@@ -156,14 +163,31 @@ def _ling3():
     return t, c, layers, bias, []
 
 
-@pytest.mark.parametrize("case", [_keye, _mellum2, _nemotron_h, _ling3])
+def _lfm2():
+    import test_lfm2 as t
+    c = t.config()
+    layers = [["operator_norm.weight"] + LFM2_MIXER[kind]
+              + ["ffn_norm.weight"] + [
+                  f"feed_forward.{n}" for n in (LING3_FFN[True] if i < 1
+                                                else EXPERTS)]
+              for i, kind in enumerate(t.KINDS)]
+    bias = [f"model.layers.{i}.feed_forward.score_bias"
+            for i in range(1, len(t.KINDS))]
+    return t, c, layers, bias, []
+
+
+@pytest.mark.parametrize("case", [_keye, _mellum2, _nemotron_h, _ling3,
+                                  _lfm2])
 def test_the_names_the_weights_files_set_and_their_order(case):
-    """Parameters: the head, the embedding, each layer's leaves, the final
-    norm. Buffers: `routing`, after `record_picks` the picks' (the
-    selection's first), then the layers' own."""
+    """Parameters: the head (a leaf of its own unless the model ties it to
+    the embedding), the embedding, each layer's leaves, the final norm.
+    Buffers: `routing`, after `record_picks` the picks' (the selection's
+    first), then the layers' own."""
     t, c, layers, layer_buffers, own_picks = case()
     model = t.build(c)
-    want = ["lm_head", "model.embed_tokens.weight"] + [
+    assert model.tied == (case is _lfm2)
+    want = ([] if model.tied else ["lm_head"]) + [
+        "model.embed_tokens.weight"] + [
         f"model.layers.{i}.{n}" for i, names in enumerate(layers)
         for n in names] + ["model.norm.weight"]
     assert [n for n, _ in model.named_parameters()] == want
@@ -173,3 +197,43 @@ def test_the_names_the_weights_files_set_and_their_order(case):
     assert [n for n, _ in model.named_parameters()] == want
     assert [n for n, _ in model.named_buffers()] == ["routing"] \
         + own_picks + ["expert_picks"] + layer_buffers
+
+
+def test_a_tied_head_is_the_embedding_and_creates_no_leaf():
+    """`MixtureCausalLM(..., tied=True)`: the head's weight IS the
+    embedding's parameter, the logits are its product with the final hidden
+    state, and one backward pass sends the embedding both gradients."""
+    import numpy as np
+
+    import paddle_tpu as paddle
+    import test_mellum2 as t
+    from paddle_tpu.models.decoder_parts import MixtureCausalLM
+    from paddle_tpu.models.mellum2 import Mellum2Model
+
+    c = t.config()
+    paddle.seed(0)
+    tied = MixtureCausalLM(c, Mellum2Model(c), tied=True)
+    paddle.seed(0)
+    untied = MixtureCausalLM(c, Mellum2Model(c))
+    assert tied.head is tied.model.embed_tokens.weight
+    assert untied.head is untied.lm_head
+    assert "lm_head" not in dict(tied.named_parameters())
+    assert len(list(tied.parameters())) == len(list(untied.parameters())) - 1
+    # the untied model with its head set to the embedding computes the same
+    # loss; its two leaves' gradients add up to the tied leaf's
+    untied.lm_head._data = untied.model.embed_tokens.weight._data
+    rng = np.random.default_rng(0)
+    ids = paddle.to_tensor(rng.integers(0, c.vocab_size, (t.B, t.S)))
+    labels = paddle.to_tensor(rng.integers(0, c.vocab_size, (t.B, t.S)))
+    grads = []
+    for model in (tied, untied):
+        loss = model.loss(ids, labels)
+        loss.backward()
+        grads.append((float(loss), {k: np.asarray(p.grad._data)
+                                    for k, p in model.named_parameters()}))
+    (loss_t, g_t), (loss_u, g_u) = grads
+    np.testing.assert_allclose(loss_t, loss_u, rtol=1e-6)
+    whole = g_u["model.embed_tokens.weight"] + g_u["lm_head"]
+    np.testing.assert_allclose(g_t["model.embed_tokens.weight"], whole,
+                               atol=1e-6 * np.abs(whole).max() + 1e-9)
+    assert np.abs(g_u["lm_head"]).max() > 0

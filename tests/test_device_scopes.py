@@ -185,7 +185,8 @@ def _step(model):
     return TrainStep(model, lambda m, a, b: m.loss(a, b), opt)
 
 
-@pytest.mark.parametrize("block", ["keye", "mellum2", "nemotron_h", "ling3"])
+@pytest.mark.parametrize("block", ["keye", "mellum2", "nemotron_h", "ling3",
+                                   "lfm2"])
 def test_a_lowered_step_carries_every_name_of_its_block(block):
     """Forward and backward, plain and wrapped by a transformation, with
     per-layer recompute as the cells run it; the matcher finds each."""
@@ -198,31 +199,43 @@ def test_a_lowered_step_carries_every_name_of_its_block(block):
     kda = {n for n in DEVICE_SCOPES if n.startswith("kda/")} | {
         "mla/project", "mla_attention", "mlp"}
     indexer = {n for n in DEVICE_SCOPES if n.startswith("indexer")}
+    # the gated-convolution block's own: a convolution layer's leaves
+    conv = {n for n in DEVICE_SCOPES if n.startswith("conv/")}
     if block == "nemotron_h":
         import test_nemotron_h as t
 
         model = t.build(t.config((2, 6), use_recompute=True))
-        absent = indexer | {"sparse_attention", "window_attention"} | kda
+        absent = indexer | {"sparse_attention", "window_attention"} | kda \
+            | conv
         forward_only = set()
     elif block == "ling3":
         import test_ling3 as t
 
         model = t.build(t.config((4, 12), use_recompute=True))
-        absent = indexer | ssm | {"sparse_attention", "window_attention",
-                                  "full_attention", "attention/projections"}
+        absent = indexer | ssm | conv | {
+            "sparse_attention", "window_attention", "full_attention",
+            "attention/projections"}
+        forward_only = set()
+    elif block == "lfm2":
+        import test_lfm2 as t
+
+        model = t.build(t.config((4, 12), use_recompute=True))
+        absent = indexer | ssm | (kda - {"mlp"}) | {
+            "sparse_attention", "window_attention", "moe/shared"}
         forward_only = set()
     elif block == "keye":
         import test_keye_vl2 as t
 
         model = t.build(t.config((2, 6), layers=2, use_recompute=True))
         absent = {"window_attention", "full_attention", "moe/shared"} \
-            | ssm | kda
+            | ssm | kda | conv
         forward_only = {n for n in DEVICE_SCOPES if n.startswith("indexer/")}
     else:
         import test_mellum2 as t
 
         model = t.build(t.config((2, 6), periods=1, use_recompute=True))
-        absent = indexer | {"sparse_attention", "moe/shared"} | ssm | kda
+        absent = indexer | {"sparse_attention", "moe/shared"} | ssm | kda \
+            | conv
         forward_only = set()
     model.bfloat16()            # as the cells run it (AMP O2)
     model.record_picks(t.B, t.S)

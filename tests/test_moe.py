@@ -1279,3 +1279,50 @@ class TestNoIndexedMoveOverThePairs:
         assert over_the_pairs(d.dropless_moe) == []
         # what the guard is there to see: the parent's form has nine
         assert len(over_the_pairs(_parents_layer)) == 9
+
+
+@pytest.mark.parametrize("eps", [None, 1e-20, 1e-6, 0.5],
+                         ids=["default", "1e-20", "1e-6", "0.5"])
+def test_route_topk_renormalises_over_the_sum_plus_eps(eps):
+    """`route_topk(..., eps=)`: the picked sigmoid scores over (their sum +
+    eps); left out it is 1e-20, bit for bit what it was; the picks and p do
+    not move with it; a softmax router takes no notice of it."""
+    from paddle_tpu.incubate.distributed.models.moe import dropless as d
+    rng = np.random.default_rng(7)
+    logits = jnp.asarray(rng.standard_normal((64, 32)), jnp.float32)
+    bias = jnp.asarray(0.01 * rng.standard_normal(32), jnp.float32)
+    kw = {} if eps is None else {"eps": eps}
+    p, experts, gates = d.route_topk(logits, 4, True, "sigmoid", bias, 2.5,
+                                     **kw)
+    was = d.route_topk(logits, 4, True, "sigmoid", bias, 2.5)
+    np.testing.assert_array_equal(np.asarray(experts), np.asarray(was[1]))
+    np.testing.assert_array_equal(np.asarray(p), np.asarray(was[0]))
+    s = 1 / (1 + np.exp(-np.asarray(logits, np.float64)))
+    top = np.take_along_axis(s, np.asarray(experts), 1)
+    np.testing.assert_allclose(
+        gates, top / (top.sum(-1, keepdims=True) + (eps or 1e-20)) * 2.5,
+        rtol=1e-5)
+    if eps in (None, 1e-20):
+        np.testing.assert_array_equal(np.asarray(gates), np.asarray(was[2]))
+    soft = d.route_topk(logits, 4, True, "softmax", **kw)
+    for a, b in zip(soft, d.route_topk(logits, 4, True, "softmax")):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_the_dropless_layer_hands_its_renorm_eps_to_the_router():
+    from paddle_tpu.incubate.distributed.models.moe.dropless import \
+        DroplessMoE
+    rng = np.random.default_rng(3)
+    x = paddle.to_tensor(rng.standard_normal((2, 16, 32)).astype(np.float32))
+    outs = []
+    for eps in (None, 0.5):
+        paddle.seed(0)
+        kw = {} if eps is None else {"renorm_eps": eps}
+        layer = DroplessMoE(32, 16, 8, 2, tile_rows=8, score="sigmoid", **kw)
+        assert layer.renorm_eps == (eps or 1e-20)
+        with paddle.no_grad():
+            outs.append([np.asarray(t._data) for t in layer(x)])
+    np.testing.assert_array_equal(outs[0][3], outs[1][3])      # the picks
+    # sum of two sigmoids near 1: over (sum + 0.5) the output shrinks
+    ratio = np.abs(outs[1][0]).sum() / np.abs(outs[0][0]).sum()
+    assert 0.55 < ratio < 0.8, ratio
